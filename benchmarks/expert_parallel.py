@@ -24,6 +24,7 @@ import numpy as np
 from benchmarks.common import Table, fmt
 from repro.configs import get_config
 from repro.core.dag_builder import Plan
+from repro.launch.mesh import make_debug_mesh
 from repro.models import model as M
 from repro.serving.scheduler import Request, serve_dataset
 from repro.sharding.specs import ShardCtx
@@ -47,7 +48,7 @@ def expert_parallel() -> Table:
         ep = 1                      # degraded: no mesh to shard over
     sctx = None
     if ep > 1:
-        sctx = ShardCtx(mesh=jax.make_mesh((1, ep), ("data", "model")),
+        sctx = ShardCtx(mesh=make_debug_mesh(1, ep),
                         batch_axes=("data",), model_axis="model",
                         moe_dispatch="a2a")
     modes = [

@@ -18,6 +18,7 @@ def main() -> None:
     from benchmarks import (engine_walltime, expert_parallel,
                             expert_prefetch, kernels, kv_paging,
                             paper_tables)
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("only", nargs="?", default=None,
@@ -25,6 +26,7 @@ def main() -> None:
     ap.add_argument("--json", dest="json_path", default=None, metavar="PATH",
                     help="write the selected tables as JSON to PATH")
     args = ap.parse_args()
+    enable_compile_cache()
 
     suites = (list(paper_tables.ALL) + list(engine_walltime.ALL)
               + list(kernels.ALL) + list(kv_paging.ALL)

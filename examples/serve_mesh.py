@@ -1,36 +1,34 @@
-"""Expert-parallel + data-parallel serving on 8 virtual CPU devices.
+"""Expert-parallel + data-parallel serving over the visible devices.
 
-Demonstrates the ``repro.distributed`` subsystem end-to-end without any
-accelerator hardware: ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-(set below, BEFORE jax imports) splits the host CPU into 8 XLA devices, a
-``(1, ep)`` mesh shards every MoE layer's expert stacks across its ``model``
-axis (pipelined all-to-all dispatch), and ``ReplicaServer`` fans one arrival
-queue over ``dp`` data-parallel replicas of that engine.
+Demonstrates the ``repro.distributed`` subsystem end-to-end: a ``(1, ep)``
+mesh shards every MoE layer's expert stacks across its ``model`` axis
+(pipelined all-to-all dispatch), and ``ReplicaServer`` fans one arrival
+queue over ``dp`` data-parallel replicas of that engine, each on its own
+group of ``ep`` devices.
 
 The run serves the same requests twice — single-device and on the mesh —
 and checks the generated tokens match token-for-token (the subsystem's
 standing contract: distribution changes WHERE experts run, never WHICH
 tokens come out).
 
-    PYTHONPATH=src python examples/serve_mesh.py [--dp 2] [--ep 2]
+The backend is whatever JAX finds.  Without accelerators, split the host
+CPU into 8 XLA devices before launch:
+
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
+        PYTHONPATH=src python examples/serve_mesh.py [--dp 2] [--ep 2]
 """
 import argparse
-import os
 
-# must precede the first jax import: device count locks at backend init
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import jax
 
-import jax  # noqa: E402
-
-from repro.configs import get_config                       # noqa: E402
-from repro.core.dag_builder import Plan                    # noqa: E402
-from repro.data.datasets import DatasetSpec, synthetic_requests  # noqa: E402
-from repro.distributed import ReplicaServer                # noqa: E402
-from repro.launch.mesh import make_debug_mesh              # noqa: E402
-from repro.models import model as M                        # noqa: E402
-from repro.serving.server import ServeConfig, Server       # noqa: E402
-from repro.sharding.specs import ShardCtx                  # noqa: E402
+from repro.configs import get_config
+from repro.core.dag_builder import Plan
+from repro.data.datasets import DatasetSpec, synthetic_requests
+from repro.distributed import ReplicaServer
+from repro.launch.mesh import make_debug_mesh
+from repro.models import model as M
+from repro.serving.server import ServeConfig, Server
+from repro.sharding.specs import ShardCtx
 
 
 def serve(cfg, params, requests, plan, serve_cfg, dp):
@@ -59,8 +57,11 @@ def main() -> None:
     ap.add_argument("--decode-len", type=int, default=8)
     args = ap.parse_args()
 
-    assert len(jax.devices()) >= args.ep, (
-        f"need {args.ep} devices, have {len(jax.devices())}")
+    if len(jax.devices()) < args.ep:
+        raise SystemExit(
+            f"--ep {args.ep} needs {args.ep} devices, {len(jax.devices())} "
+            "visible (on CPU: XLA_FLAGS=--xla_force_host_platform_device_"
+            "count=8)")
     cfg = get_config(args.arch, smoke=True)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     spec = DatasetSpec("mesh-demo", args.requests, args.prompt_len,

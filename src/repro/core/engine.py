@@ -6,8 +6,10 @@ batched computations —
 
 * the attention module consumes micro-batches of ``b_a`` sequences; outputs
   accumulate in host memory until all ``B`` sequences are ready;
-* a fraction ``ω`` of each attention batch is computed on the *host* path
-  (``core.host_attention``), where the offloaded KV-cache lives;
+* a fraction ``ω`` of each attention batch takes the *host-path* mechanism
+  (``core.host_attention``: the paper's BF16-consistent FP32 arithmetic).
+  Like every module it is a jit on the engine's device, so on a TPU those
+  rows run on the chip; moving them to the host CPU is not built yet;
 * the sparse-MoE stage runs as ONE **grouped dispatch**: routed tokens are
   gathered on device into an ``(E, C, D)`` capacity buffer (``C`` = the
   plan's per-expert token budget ``b_e``), pushed through a single grouped
@@ -157,9 +159,10 @@ def _attn_decode_module(cfg, lo, p, x_mb, k, v, pos):
 @functools.partial(jax.jit, static_argnames=("cfg", "lo"),
                    donate_argnames=("k", "v"))
 def _attn_decode_host_module(cfg, lo, p, x_mb, k, v, pos):
-    """Host-path attention: projections on device, mechanism on host CPU
-    with the paper's BF16-consistent arithmetic (§B).  Same donated
-    row-block cache contract as ``_attn_decode_module``."""
+    """Host-path attention: the paper's BF16-consistent mechanism (§B)
+    for the ω rows.  The whole module — projections and mechanism — is a
+    jit on the engine's device (the chip, on a TPU), not on the host CPU.
+    Same donated row-block cache contract as ``_attn_decode_module``."""
     from repro.models.layers import apply_rope
 
     B = x_mb.shape[0]
@@ -643,8 +646,10 @@ class EngineStats:
     expert_launches: int = 0             # grouped: one per MoE layer per step
     expert_tokens: int = 0               # routed token-copies processed
     expert_tokens_dropped: int = 0       # routed copies over the b_e capacity
-    host_attn_tokens: int = 0
-    device_attn_tokens: int = 0
+    host_attn_tokens: int = 0            # ω rows through the host-path
+    #                                      mechanism (core.host_attention);
+    #                                      it runs on the engine's device
+    device_attn_tokens: int = 0          # rows through attn_decode
     weight_htod_bytes: int = 0           # streamed weight bytes copied htod
     prefetch_wait_s: float = 0.0         # stall waiting on weight transfers
     fused_dispatches: int = 0            # fused decode launches issued
@@ -771,6 +776,17 @@ class ModuleBatchingEngine:
                     "stream_weights does not compose with a mesh ShardCtx: "
                     "the collective stage needs resident expert shards"
                 )
+            if not grouped_prefill:
+                raise ValueError(
+                    "a mesh ShardCtx prefills through the collective MoE "
+                    "stage; grouped_prefill=False (the dense-combine "
+                    "reference) is single-device only"
+                )
+            if cache_config is not None and cache_config.prefix_cache:
+                raise ValueError(
+                    "prefix-cache hits prefill the suffix single-device; "
+                    "they do not compose with a mesh ShardCtx"
+                )
         # KV paging (serving.cache): None / disabled keeps the legacy
         # contiguous buffers; the table is (re)built per init_cache batch
         self.cache_config = cache_config
@@ -779,6 +795,7 @@ class ModuleBatchingEngine:
             store = ParamStore.build(
                 cfg, params, plan, stream_weights=stream_weights,
                 resident_bytes=resident_bytes, prefetch=prefetch,
+                sctx=self.sctx,
             )
         self.store = store
         if self.sctx is not None and not store.fully_resident:
@@ -1124,9 +1141,13 @@ class ModuleBatchingEngine:
                     )
                     with sanitizer.allowed("prefill-capacity-probe"):
                         cap = W.next_pow2(int(np.asarray(max_load)))
-                    y, _ = _prefill_moe_ffn_module(
-                        cfg, cap, p["moe"], x_mid, xt, gates, idx
-                    )
+                    if self.sctx is not None:
+                        y = x_mid + self._mesh_moe(li, p, x_mid, cap,
+                                                   (xt, gates, idx))
+                    else:
+                        y, _ = _prefill_moe_ffn_module(
+                            cfg, cap, p["moe"], x_mid, xt, gates, idx
+                        )
                 else:
                     sctx = self._prefill_sctx((hi - lo) * S)
                     y, entry, _ = _prefill_layer_module(
@@ -1441,6 +1462,23 @@ class ModuleBatchingEngine:
         if self.expert_path == "grouped":
             return self._expert_stage_grouped(li, p, x)
         return self._expert_stage_loop(p, x)
+
+    def _mesh_moe(self, li, p, x, capacity: int, routed) -> jax.Array:
+        """A prefill micro-batch's MoE stage on a mesh engine: the
+        collective dispatch of the mixer launch's ``routed=(h, gates,
+        idx)`` at ``capacity`` (the pow2 bucket over the measured max load,
+        so nothing drops).  The expert stacks live sharded over the mesh,
+        so prefill cannot take the single-device grouped launch."""
+        from repro.distributed.ep_engine import ep_expert_stage
+
+        B, S, D = x.shape
+        y, _, _, _, nbytes = ep_expert_stage(
+            self, li, p, x.reshape(B * S, D), capacity=capacity,
+            routed=routed,
+        )
+        self.stats.a2a_bytes += nbytes
+        self.stats.collective_dispatches += 1
+        return y.reshape(B, S, D)
 
     def _expert_stage_grouped(self, li, p, x) -> jax.Array:
         """One grouped-dispatch launch for the whole MoE stage: routing,

@@ -130,3 +130,23 @@ TPU_V5E = HardwareProfile(
 )
 
 PROFILES = {p.name: p for p in (A5000_C1, A5000_C2, A6000_C3, TPU_V5E)}
+
+# ``device_kind`` as JAX reports it -> profile.  v5e reports "TPU v5 lite".
+DEVICE_KIND_PROFILES = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5e": TPU_V5E,
+}
+
+
+def profile_for_device(device) -> HardwareProfile:
+    """The profile of an accelerator, looked up by its ``device_kind``.
+    A kind missing from the table is an error, never a default: planning a
+    v6e with v5e constants would be silently wrong."""
+    kind = device.device_kind
+    if kind not in DEVICE_KIND_PROFILES:
+        raise ValueError(
+            f"no hardware profile for device_kind {kind!r}; known kinds: "
+            f"{sorted(DEVICE_KIND_PROFILES)} (add one to "
+            "core/hardware.py DEVICE_KIND_PROFILES)"
+        )
+    return DEVICE_KIND_PROFILES[kind]

@@ -50,7 +50,7 @@ from repro.analysis.registry import register_jit
 from repro.configs.base import ModelConfig
 from repro.models import moe as moe_mod
 from repro.models.layers import rms_norm
-from repro.sharding.specs import ShardCtx, shard_map
+from repro.sharding.specs import ShardCtx
 
 
 # ---------------------------------------------------------------------------
@@ -114,31 +114,44 @@ def validate_ep_shard(cfg: ModelConfig, sctx: ShardCtx) -> int:
 @register_jit("distributed.ep_a2a_expert")
 @functools.partial(
     jax.jit,
-    static_argnames=("cfg", "mesh", "axis", "chunks", "capacity", "serial"),
+    static_argnames=("cfg", "mesh", "axis", "chunks", "capacity", "serial",
+                     "n_real"),
 )
-def _ep_a2a_expert_module(cfg, mesh, axis, chunks, capacity, serial,
-                          norm2_w, router_w, wg, wu, wd, x):
+def _ep_a2a_expert_module(cfg, mesh, axis, chunks, capacity, serial, n_real,
+                          norm2_w, router_w, wg, wu, wd, x, gates=None,
+                          idx=None):
     """The whole mesh MoE stage in one launch; returns ``(y, kept, dropped,
-    load)`` with the same meaning as ``engine._grouped_expert_math``.
+    load)`` with the same meaning as ``engine._grouped_expert_math``, for
+    the first ``n_real`` rows of ``y``.
 
-    ``x`` is the (T, D) accumulated decode batch with T divisible by the
-    model-axis size times nothing — T % n == 0 is the caller's contract
-    (the engine falls back to the single-device stage otherwise).  Each
-    rank owns T/n tokens and E/n experts; ``capacity`` is the per-expert
-    local buffer depth (the plan's b_e, shared with the single-device
-    path)."""
+    With ``gates``/``idx`` given, ``x`` is already normalized and routed
+    (grouped prefill routes in its mixer launch); the stage then dispatches
+    exactly that routing, as the single-device prefill FFN launch does.
+
+    ``x`` is the (T, D) batch with T divisible by the model-axis size; rows
+    from ``n_real`` on are padding the caller added to get there.  Their
+    copies are never sent, so they take no capacity slot and enter no
+    counter, and a padded batch keeps the single-device stage's drops and
+    values.  Each rank owns T/n tokens and E/n experts; ``capacity`` is the
+    per-expert local buffer depth (the plan's b_e, shared with the
+    single-device path)."""
     n = mesh.shape[axis]
     E = cfg.num_experts
     e_loc = E // n
     k = cfg.experts_per_token
     T, D = x.shape
 
-    def body(xl, norm2_w, router_w, wg, wu, wd):
+    def body(xl, norm2_w, router_w, wg, wu, wd, *routed):
         T_r = xl.shape[0]
-        # identical per-token math to the single-device stage: rms_norm and
-        # routing are row-wise, so sharding the batch never changes a row
-        h = rms_norm(xl, norm2_w, cfg.norm_eps)
-        gates, idx, _ = moe_mod.route(cfg, router_w, h)
+        if routed:
+            h, (gates, idx) = xl, routed
+        else:
+            # identical per-token math to the single-device stage: rms_norm
+            # and routing are row-wise, so sharding the batch never
+            # changes a row
+            h = rms_norm(xl, norm2_w, cfg.norm_eps)
+            gates, idx, _ = moe_mod.route(cfg, router_w, h)
+        real = lax.axis_index(axis) * T_r + jnp.arange(T_r) < n_real
         t_c = T_r // chunks
         ys, kepts = [], []
         load = jnp.zeros((E,), jnp.int32)
@@ -154,15 +167,20 @@ def _ep_a2a_expert_module(cfg, mesh, axis, chunks, capacity, serial,
                 # stays bitwise equal to the pipelined one.
                 hc, _ = lax.optimization_barrier((hc, prev))
             tok = jnp.arange(t_c * k) // k
+            sent = real[c * t_c:(c + 1) * t_c][tok]            # (t_c*k,)
             dst = ic // e_loc                                  # owner rank
             # dispatch a2a: one page per destination rank, sized so the
             # send stage never drops (capacity acts at the expert owner)
             cap_s = t_c * k
-            slot = moe_mod._arrival_slots(dst, n)
+            slot = moe_mod._arrival_slots(dst, n, mask=sent)
             send = jnp.zeros((n, cap_s, D), hc.dtype)
-            send = send.at[dst, slot].add(hc[tok])
+            send = send.at[dst, slot].add(
+                hc[tok] * sent[:, None].astype(hc.dtype)
+            )
             meta = jnp.zeros((n, cap_s), jnp.int32)
-            meta = meta.at[dst, slot].add(ic % e_loc + 1)      # 0 = empty
+            meta = meta.at[dst, slot].add(
+                jnp.where(sent, ic % e_loc + 1, 0)             # 0 = empty
+            )
             recv = lax.all_to_all(send, axis, 0, 0, tiled=True)
             meta_r = lax.all_to_all(meta, axis, 0, 0, tiled=True)
             # local expert bucketing under the shared capacity b_e: the
@@ -195,26 +213,30 @@ def _ep_a2a_expert_module(cfg, mesh, axis, chunks, capacity, serial,
             prev = y_c
             ys.append(y_c)
             kepts.append(jnp.sum(keep.astype(jnp.int32)))
-            load = load + jnp.zeros((E,), jnp.int32).at[ic].add(1)
+            load = load + jnp.zeros((E,), jnp.int32).at[ic].add(
+                sent.astype(jnp.int32)
+            )
         y = jnp.concatenate(ys, axis=0) if len(ys) > 1 else ys[0]
         # each copy is counted once at its expert owner; the psums fold the
         # per-rank partials into the single-device counter semantics
         kept = lax.psum(sum(kepts), axis)
         load = lax.psum(load, axis)
-        dropped = jnp.int32(T * k) - kept
+        dropped = jnp.int32(n_real * k) - kept
         return y, kept, dropped, load
 
     x_spec = P(axis, None)
     rep = P()
     e_spec = P(axis, None, None)
-    y, kept, dropped, load = shard_map(
+    routed = () if gates is None else (gates, idx)
+    y, kept, dropped, load = jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(x_spec, rep, rep, e_spec, e_spec, e_spec),
+        in_specs=(x_spec, rep, rep, e_spec, e_spec, e_spec)
+        + (x_spec,) * len(routed),
         out_specs=(x_spec, rep, rep, rep),
         check_vma=False,
-    )(x, norm2_w, router_w, wg, wu, wd)
-    return y.astype(x.dtype), kept, dropped, load
+    )(x, norm2_w, router_w, wg, wu, wd, *routed)
+    return y[:n_real].astype(x.dtype), kept, dropped, load
 
 
 # ---------------------------------------------------------------------------
@@ -268,7 +290,7 @@ def _ep_psum_expert_module(cfg, mesh, axis, capacity,
 
     rep = P()
     e_spec = P(axis, None, None)
-    y, kept, dropped, load = shard_map(
+    y, kept, dropped, load = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(rep, rep, rep, e_spec, e_spec, e_spec),
@@ -308,9 +330,11 @@ class ExpertParallelEngine:
 # ---------------------------------------------------------------------------
 def _mesh_placed(engine, li: int, p) -> Tuple:
     """The layer's MoE params placed for the mesh launch, cached per layer:
-    expert stacks sharded over the model axis, norm2/router replicated.
-    Explicit ``device_put`` — a planned, once-per-layer d2d placement, so
-    repeated launches move no bytes and trip no transfer guard."""
+    expert stacks sharded over the model axis (where the engine's
+    ``ParamStore`` already built them, so this is a no-op for them),
+    norm2/router replicated.  Explicit ``device_put`` — a planned,
+    once-per-layer placement, so repeated launches move no bytes and trip
+    no transfer guard."""
     cache = engine._ep_params
     ent = cache.get(li)
     moe = p["moe"]
@@ -331,61 +355,73 @@ def _mesh_placed(engine, li: int, p) -> Tuple:
     return placed
 
 
-def ep_expert_stage(engine, li: int, p, x):
+def ep_expert_stage(engine, li: int, p, x, capacity=None, routed=None):
     """Run one MoE layer's collective stage for a mesh engine; returns
     ``(y, kept, dropped, load, a2a_bytes)``.
 
-    Path selection (the ROADMAP mesh contract): ``a2a`` needs the batch
-    divisible by the model-axis size — when it is not (odd live batch), the
-    stage falls back to the SINGLE-DEVICE grouped launch, which is
-    bit-identical anyway, so the fallback is invisible except in the a2a
-    byte accounting.  ``psum`` replicates tokens and has no divisibility
-    constraint."""
+    ``capacity`` is the per-expert buffer depth (default: the engine's
+    decode capacity, the plan's b_e).  ``routed=(h, gates, idx)`` hands in
+    ``x``'s normalized rows and their routing, computed already (grouped
+    prefill routes in its mixer launch): the a2a stage then dispatches
+    exactly that, as the single-device prefill FFN launch does, so the two
+    stay bit-identical.  Path selection (the ROADMAP mesh
+    contract): ``a2a`` needs the batch divisible by the model-axis size —
+    when it is not (odd live batch, a prefill micro-batch), the batch is
+    padded with rows the module never sends, so values, drops and counters
+    are those of the unpadded batch.  ``psum`` replicates tokens and has no
+    divisibility constraint.  A one-rank mesh runs the single-device
+    grouped launch, which is the reference both paths must match."""
     from repro.analysis import runtime as sanitizer
     from repro.core import engine as engine_mod
 
     sctx = engine.sctx
     n = sctx.model_size
     T = x.shape[0]
-    cap = engine._expert_capacity(T)
-    home = x.sharding
-    out = None
-    if n > 1 and sctx.moe_dispatch in ("a2a", "psum"):
-        norm2_w, router_w, wg, wu, wd = _mesh_placed(engine, li, p)
-        if sctx.moe_dispatch == "a2a" and T % n == 0:
-            # the engine's buffers are single-device committed arrays; the
-            # mesh launch needs its batch sharded over the model axis and
-            # hands back mesh-committed outputs — both hops are explicit,
-            # planned d2d placements, tagged for the sanitizer report
-            with sanitizer.allowed("ep-a2a-batch"):
-                x_m = jax.device_put(      # lint: allow[MG105] planned per-launch d2d batch placement onto the mesh, tagged ep-a2a-batch
-                    x, NamedSharding(sctx.mesh, P(sctx.model_axis, None))
-                )
-            chunks = pipeline_chunks(T // n, engine.ep_chunks)
-            out = _ep_a2a_expert_module(
-                engine.cfg, sctx.mesh, sctx.model_axis, chunks, cap,
-                engine.ep_serial, norm2_w, router_w, wg, wu, wd, x_m,
+    cap = engine._expert_capacity(T) if capacity is None else capacity
+    if n == 1:
+        if routed is not None:
+            y, kept, dropped, load = engine_mod._grouped_ffn_module(
+                engine.cfg, cap, *routed, p["moe"]["experts_w_gate"],
+                p["moe"]["experts_w_up"], p["moe"]["experts_w_down"],
             )
-            nbytes = a2a_bytes_per_stage(
-                engine.cfg, T, n, itemsize=x.dtype.itemsize
+        else:
+            y, kept, dropped, load = engine_mod._grouped_expert_module(
+                engine.cfg, p, x, cap
             )
-        elif sctx.moe_dispatch == "psum":
-            with sanitizer.allowed("ep-a2a-batch"):
-                x_m = jax.device_put(      # lint: allow[MG105] planned per-launch d2d batch replication onto the mesh, tagged ep-a2a-batch
-                    x, NamedSharding(sctx.mesh, P())
-                )
-            out = _ep_psum_expert_module(
-                engine.cfg, sctx.mesh, sctx.model_axis, cap,
-                norm2_w, router_w, wg, wu, wd, x_m,
-            )
-            nbytes = 0
-    if out is None:
-        # n == 1 mesh or indivisible a2a batch: the single-device grouped
-        # stage IS the reference this path must match — run it directly
-        y, kept, dropped, load = engine_mod._grouped_expert_module(
-            engine.cfg, p, x, cap
-        )
         return y, kept, dropped, load, 0
+    home = x.sharding
+    norm2_w, router_w, wg, wu, wd = _mesh_placed(engine, li, p)
+    if sctx.moe_dispatch == "a2a":
+        pad = -T % n
+        # the engine's buffers are single-device committed arrays; the
+        # mesh launch needs its batch sharded over the model axis and
+        # hands back mesh-committed outputs — both hops are explicit,
+        # planned d2d placements, tagged for the sanitizer report
+        rows = NamedSharding(sctx.mesh, P(sctx.model_axis, None))
+        batch = (x,) if routed is None else routed
+        with sanitizer.allowed("ep-a2a-batch"):
+            placed = jax.device_put(  # lint: allow[MG105] planned per-launch d2d batch placement onto the mesh, tagged ep-a2a-batch
+                [jnp.pad(a, ((0, pad), (0, 0))) if pad else a
+                 for a in batch], rows
+            )
+        chunks = pipeline_chunks((T + pad) // n, engine.ep_chunks)
+        out = _ep_a2a_expert_module(
+            engine.cfg, sctx.mesh, sctx.model_axis, chunks, cap,
+            engine.ep_serial, T, norm2_w, router_w, wg, wu, wd, *placed,
+        )
+        nbytes = a2a_bytes_per_stage(
+            engine.cfg, T, n, itemsize=x.dtype.itemsize
+        )
+    else:
+        with sanitizer.allowed("ep-a2a-batch"):
+            x_m = jax.device_put(      # lint: allow[MG105] planned per-launch d2d batch replication onto the mesh, tagged ep-a2a-batch
+                x, NamedSharding(sctx.mesh, P())
+            )
+        out = _ep_psum_expert_module(
+            engine.cfg, sctx.mesh, sctx.model_axis, cap,
+            norm2_w, router_w, wg, wu, wd, x_m,
+        )
+        nbytes = 0
     with sanitizer.allowed("ep-a2a-combine"):
         y = jax.device_put(out[0], home)   # lint: allow[MG105] planned d2d return of the mesh stage's output to the engine's home device, tagged ep-a2a-combine
         dev = next(iter(home.device_set))

@@ -12,6 +12,12 @@ Routing is pluggable: ``'round-robin'``, ``'least-loaded'`` (fewest
 outstanding decode tokens, the default), or any callable
 ``(servers, request) -> replica index``.
 
+Placement: ``jax.devices()`` is cut into groups of ``ep`` — the
+expert-parallel degree of ``ServeConfig.sctx``, 1 without a mesh — and
+replica *i* takes group *i* (wrapping when there are fewer groups than
+replicas).  Its weights, KV and launches live there, and an
+expert-parallel replica's mesh spans exactly its group.
+
 The merged report sums work counters across replicas and takes the
 parallel wall-clock (max of the per-replica phase times) — replicas run
 concurrently in a real deployment, sequentially interleaved here on one
@@ -21,8 +27,9 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, replace
-from typing import Callable, List, Optional, Union
+from typing import Callable, List, Union
 
+import jax
 import numpy as np
 
 from repro import faults
@@ -74,8 +81,8 @@ class ReplicaServer:
         if self._faults is not None:
             serve = replace(serve, faults=self._faults)
         self.servers = [
-            Server(cfg, params, plan, serve, stream)
-            for _ in range(n_replicas)
+            Server(cfg, params, plan, cfg_i, stream)
+            for cfg_i in _placed_configs(serve, n_replicas)
         ]
         # shared prefix keys, per-replica KV: every replica consults one
         # PrefixStore (host page rows), so replica 1 hits what replica 0
@@ -282,3 +289,27 @@ class ReplicaServer:
         m.failovers = self.failovers
         m.requeued_requests = self.requeued
         return m
+
+
+def _placed_configs(serve: ServeConfig, n: int) -> List[ServeConfig]:
+    """One ``ServeConfig`` per replica, each on its own device group (see
+    the module docstring)."""
+    from repro.launch.mesh import make_mesh
+
+    devices = jax.devices()
+    mesh = None if serve.sctx is None else serve.sctx.mesh
+    ep = 1 if mesh is None else mesh.size
+    if len(devices) < ep:
+        raise ValueError(
+            f"an ep={ep} replica needs {ep} devices; {len(devices)} visible")
+    groups = len(devices) // ep
+    out = []
+    for i in range(n):
+        g = i % groups
+        devs = devices[g * ep:(g + 1) * ep]
+        sctx = serve.sctx
+        if mesh is not None:
+            sctx = replace(sctx, mesh=make_mesh(
+                mesh.devices.shape, mesh.axis_names, devs))
+        out.append(replace(serve, device=devs[0], sctx=sctx))
+    return out

@@ -1,0 +1,28 @@
+"""JAX's persistent compilation cache, switched on by the entry points.
+
+Only entry points call ``enable_compile_cache`` (``chip_smoke.py``,
+``repro.launch.serve``, ``benchmarks/run.py``); importing a library module
+or running the tests never turns the cache on.
+"""
+from __future__ import annotations
+
+import os
+from pathlib import Path
+
+import jax
+
+# <repo>/.jax_cache: a fixed path, since a cache whose directory moves
+# between runs never hits.  Listed in .gitignore.
+DEFAULT_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn the persistent cache on and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here; otherwise the cache lives in ``DEFAULT_DIR``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_DIR))
+    return str(DEFAULT_DIR)

@@ -1,7 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove every (architecture x input shape x mesh)
 combination lowers, compiles, and fits — with no real hardware.
 
@@ -16,25 +12,26 @@ Usage:
     python -m repro.launch.dryrun --arch olmoe-1b-7b --shape train_4k
     python -m repro.launch.dryrun --all [--mesh single|multi|both]
 """
-import argparse  # noqa: E402
-import json  # noqa: E402
-import time  # noqa: E402
-import traceback  # noqa: E402
-from typing import Optional  # noqa: E402
+import argparse
+import json
+import os
+import time
+import traceback
+from typing import Optional
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs import SHAPES, get_config, list_archs  # noqa: E402
-from repro.configs.base import ModelConfig, ShapeSpec  # noqa: E402
-from repro.launch import hlo_analysis  # noqa: E402
-from repro.launch.mesh import make_ctx, make_production_mesh  # noqa: E402
-from repro.models import model as model_mod  # noqa: E402
-from repro.models.frontends import frontend_spec  # noqa: E402
-from repro.sharding.specs import ShardCtx, cache_shardings, param_shardings  # noqa: E402
-from repro.train.optimizer import adamw_init  # noqa: E402
-from repro.train.train_loop import make_train_step  # noqa: E402
+from repro.configs import SHAPES, get_config, list_archs
+from repro.configs.base import ModelConfig, ShapeSpec
+from repro.launch import hlo_analysis
+from repro.launch.mesh import make_ctx, make_production_mesh
+from repro.models import model as model_mod
+from repro.models.frontends import frontend_spec
+from repro.sharding.specs import ShardCtx, cache_shardings, param_shardings
+from repro.train.optimizer import adamw_init
+from repro.train.train_loop import make_train_step
 
 REPORT_DIR = os.path.join(os.path.dirname(__file__), "../../../reports/dryrun")
 
@@ -188,6 +185,9 @@ def _save(out: dict) -> dict:
 
 
 def main() -> None:
+    # 512 virtual CPU devices for the production meshes; must precede the
+    # first backend initialization (the device count locks there)
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=[*SHAPES, None])

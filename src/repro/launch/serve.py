@@ -1,35 +1,124 @@
-"""Serving launcher: plan with the paper's search, then run the engine.
+"""Serving launcher: plan with the paper's search, then serve through ``Server``.
 
     PYTHONPATH=src python -m repro.launch.serve --arch mixtral-8x7b \
         --requests 16 --prompt-len 32 --decode-len 16 --stream-weights
+
+Without ``--layers`` the registry's smoke preset runs (CPU-sized).  With
+``--layers N`` the registry config runs at its published widths, cut to
+its first N layers (a whole number of layer-pattern periods) — the size a
+TPU chip serves:
+
+    python -m repro.launch.serve --arch mixtral-8x7b --layers 4 \
+        --requests 16 --prompt-len 256 --decode-len 32 --batch 16
+
+On a TPU the hardware profile comes from the device's ``device_kind``
+(``core.hardware.DEVICE_KIND_PROFILES``); elsewhere it defaults to the
+paper's A5000 testbed.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
+from dataclasses import replace
+from typing import Optional, Tuple
 
 import jax
 
 from repro.configs import get_config
+from repro.configs.base import ModelConfig
 from repro.core import planner, workload as W
 from repro.core.dag_builder import Plan
-from repro.core.hardware import PROFILES
+from repro.core.hardware import PROFILES, HardwareProfile, profile_for_device
 from repro.data.datasets import DatasetSpec, synthetic_requests
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serving import arrivals
 from repro.serving.sampling import SamplingParams
-from repro.serving.scheduler import serve_dataset
+from repro.serving.server import ServeConfig, Server, StreamConfig
 from repro.serving.weights import ParamStore
 
 
-def main() -> None:
+def executing_config(arch: str, layers: Optional[int] = None) -> ModelConfig:
+    """The config that runs: the smoke preset, or — with ``layers`` — the
+    registry config at its published widths with only its depth cut."""
+    if layers is None:
+        return get_config(arch, smoke=True)
+    full = get_config(arch)
+    cfg = replace(full, num_layers=int(layers))
+    period = len(M.layer_pattern(full))
+    if not 1 <= cfg.num_layers <= full.num_layers \
+            or cfg.num_layers % period:
+        raise ValueError(
+            f"--layers {layers}: {arch} has {full.num_layers} layers in "
+            f"periods of {period}; pick a multiple of {period}")
+    return cfg
+
+
+def describe_cut(cfg: ModelConfig, arch: str) -> str:
+    full = get_config(arch)
+    return (f"{cfg.name}: {cfg.num_layers} of {full.num_layers} layers; "
+            f"d_model {cfg.d_model}, {cfg.num_heads} heads / "
+            f"{cfg.num_kv_heads} kv heads x {cfg.head_dim}, "
+            f"{cfg.num_experts} experts top-{cfg.experts_per_token} "
+            f"d_ff {cfg.moe_d_ff or cfg.d_ff}, vocab {cfg.vocab_size}; "
+            f"{W.model_bytes(cfg) / 1e9:.2f} GB of weights")
+
+
+def resolve_profile(name: Optional[str] = None) -> HardwareProfile:
+    """``name`` if given; on a TPU the profile of its ``device_kind`` (an
+    unknown kind is an error); elsewhere the paper's C2 A5000 testbed."""
+    if name is not None:
+        return PROFILES[name]
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        return profile_for_device(dev)
+    return PROFILES["C2-A5000-512GB"]
+
+
+def plan_serving(
+    cfg: ModelConfig, hw: HardwareProfile, batch: int, ctx: int,
+    decode_len: int, scheduler: str = "static",
+    mesh_shape: Optional[Tuple[int, int]] = None,
+    ep_chunks: Optional[int] = None,
+) -> Tuple[Plan, "planner.SearchResult"]:
+    """The paper's search on the config that executes, at the batch it
+    serves (``batch`` caps the accumulated batch B).
+
+    The search's ω is pinned to 0: the engine's host-path attention is a
+    jit on the engine's device, so the host-CPU overlap the cost model
+    would credit ω with does not exist yet (ROADMAP A8).  A mesh serves
+    fully resident, so it plans no predictive streaming."""
+    res = planner.search_decode(
+        cfg, hw, ctx=ctx, B=batch, use_cpu_attention=False,
+        decode_len=decode_len, scheduler=scheduler, mesh_shape=mesh_shape,
+    )
+    plan = replace(
+        res.plan, B=batch, b_a=max(1, min(res.plan.b_a, batch)),
+        predict_topk=0 if mesh_shape else res.plan.predict_topk,
+        ep_chunks=ep_chunks or res.plan.ep_chunks,
+    )
+    plan = replace(plan, decode_chunk=planner.select_decode_chunk(
+        plan, decode_len, scheduler=scheduler,
+    ))
+    return plan, res
+
+
+def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mixtral-8x7b")
-    ap.add_argument("--profile", default="C2-A5000-512GB", choices=PROFILES)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="run the registry config at its published widths "
+                         "cut to this many layers (default: the smoke "
+                         "preset)")
+    ap.add_argument("--profile", default=None, choices=PROFILES,
+                    help="hardware profile the planner plans for (default: "
+                         "the TPU's own by device_kind, else "
+                         "C2-A5000-512GB)")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--decode-len", type=int, default=16)
     ap.add_argument("--batch", type=int, default=8,
-                    help="accumulated batch B for the smoke execution")
+                    help="accumulated batch B the engine allocates")
     ap.add_argument("--expert-path", default="grouped",
                     choices=("grouped", "loop"),
                     help="MoE stage: grouped dispatch vs per-expert loop")
@@ -65,9 +154,9 @@ def main() -> None:
     ap.add_argument("--resident-gb", type=float, default=None,
                     help="device bytes (GB) of the greedy resident weight "
                          "set; implies --stream-weights (default when "
-                         "streaming: 0 — the smoke model is tiny, so the "
-                         "planned S_Params would pin everything and stream "
-                         "nothing)")
+                         "streaming: the plan's S_Params with --layers, 0 "
+                         "for the smoke preset, whose planned S_Params "
+                         "would pin everything and stream nothing)")
     ap.add_argument("--no-prefetch", action="store_true",
                     help="disable the async prefetch (streamed-serial: "
                          "fetch-on-demand, copy serialized with compute)")
@@ -98,9 +187,11 @@ def main() -> None:
                          "MoE layer's experts across EP devices (pipelined "
                          "all-to-all dispatch, repro.distributed) and DP "
                          "runs that engine in DP data-parallel Server "
-                         "replicas behind one arrival queue; needs "
-                         "DP*EP visible devices (CPU: XLA_FLAGS="
-                         "--xla_force_host_platform_device_count=8)")
+                         "replicas behind one arrival queue, one group "
+                         "of EP devices each (replicas share groups when "
+                         "fewer than DP*EP devices are visible; CPU: "
+                         "XLA_FLAGS=--xla_force_host_platform_device_"
+                         "count=8)")
     ap.add_argument("--ep-chunks", type=int, default=None,
                     help="expert-parallel pipeline chunk count (a2a of "
                          "chunk k+1 overlaps expert FFN of chunk k); "
@@ -120,9 +211,15 @@ def main() -> None:
                          "raises on unplanned transfers, log records them) "
                          "and donation aliasing is verified; prints the "
                          "sanitizer report after serving")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    cache_dir = enable_compile_cache()
 
-    hw = PROFILES[args.profile]
+    hw = resolve_profile(args.profile)
+    cfg = executing_config(args.arch, args.layers)
+    print(f"executing {describe_cut(cfg, args.arch)}")
+    print(f"device: {jax.devices()[0].platform} "
+          f"{jax.devices()[0].device_kind} x{len(jax.devices())}; "
+          f"compile cache {cache_dir}")
 
     dp = ep = 1
     if args.mesh:
@@ -144,24 +241,6 @@ def main() -> None:
                              "composes with neither --stream-weights nor "
                              "predictive streaming")
 
-    # 1. plan on the FULL config with the paper's search
-    full = get_config(args.arch)
-    res = planner.search_decode(
-        full, hw, ctx=args.prompt_len + args.decode_len,
-        decode_len=args.decode_len, scheduler=args.scheduler,
-        mesh_shape=(dp, ep) if args.mesh else None,
-    )
-    print(f"planned ({full.name} on {hw.name}): {res.plan.describe()}")
-    rp_full = W.plan_residency(full, res.plan.s_params)
-    print(f"planned residency: {rp_full.resident_bytes/1e9:.1f}GB resident "
-          f"of {W.model_bytes(full)/1e9:.1f}GB model "
-          f"({rp_full.n_streamed()} modules streamed, stream window "
-          f"{res.plan.s_expert/1e9:.1f}GB)")
-    print(f"predicted decode throughput: {res.estimate.throughput:.0f} tok/s")
-
-    # 2. execute module-based batching at smoke scale with the same shape
-    cfg = get_config(args.arch, smoke=True)
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
     spec = DatasetSpec("serve", args.requests, args.prompt_len, args.decode_len)
     parse = lambda s: [int(x) for x in s.split(",")] if s else None
     times = None
@@ -179,40 +258,39 @@ def main() -> None:
         arrivals=times,
         sampling=sampling if not sampling.is_greedy else None,
     )
-    plan = Plan(
-        B=args.batch,
-        b_a=max(1, min(res.plan.b_a, args.batch)),
-        # b_e is a per-expert capacity; the engine clamps it to the
-        # accumulated batch, so the planned value carries over directly
-        b_e=res.plan.b_e,
-        omega=res.plan.omega if cfg.has_attention else 0.0,
-        s_params=res.plan.s_params,
-        s_expert=res.plan.s_expert,
-        predict_topk=res.plan.predict_topk,
-        ep_chunks=(args.ep_chunks if args.ep_chunks
-                   else res.plan.ep_chunks),
-    )
-    # re-plan the fused chunk T at the smoke batch (the admission cadence
-    # scales with B, so the full-config T would over- or under-chunk here)
-    from dataclasses import replace as dc_replace
+    ctx = max(len(r.prompt) + r.decode_len for r in requests)
 
-    plan = dc_replace(plan, decode_chunk=planner.select_decode_chunk(
-        plan, args.decode_len, scheduler=args.scheduler,
-    ))
+    # 1. plan the config that executes, with the paper's search
+    plan, res = plan_serving(
+        cfg, hw, args.batch, ctx, args.decode_len, args.scheduler,
+        mesh_shape=(dp, ep) if args.mesh else None,
+        ep_chunks=args.ep_chunks,
+    )
+    print(f"planned ({cfg.name} on {hw.name}): {res.plan.describe()}")
+    rp = W.plan_residency(cfg, res.plan.s_params)
+    print(f"planned residency: {rp.resident_bytes/1e9:.1f}GB resident "
+          f"of {W.model_bytes(cfg)/1e9:.1f}GB model "
+          f"({rp.n_streamed()} modules streamed, stream window "
+          f"{res.plan.s_expert/1e9:.1f}GB)")
+    print(f"predicted decode throughput: {res.estimate.throughput:.0f} tok/s")
     print(f"fused decode chunk T={plan.decode_chunk} "
-          f"({args.scheduler} cadence at B={plan.B})")
-    # --resident-gb implies streaming; at smoke scale the full-model
-    # S_Params would pin everything, so the streamed smoke run defaults to
-    # resident_bytes=0 to actually exercise the stream path
+          f"({args.scheduler} cadence at B={plan.B}); omega={plan.omega}")
+
+    # 2. weights on the host, placed by the store the server executes
+    params = M.init_params_host(cfg, jax.random.PRNGKey(0))
+    # --resident-gb implies streaming; the smoke preset is tiny, so its
+    # planned S_Params would pin everything — a streamed smoke run
+    # defaults to resident_bytes=0 to actually exercise the stream path
     stream = (args.stream_weights or args.resident_gb is not None
               or args.predict_topk is not None)
-    resident_bytes = (
-        0.0 if args.resident_gb is None else args.resident_gb * 1e9
-    )
+    if args.resident_gb is not None:
+        resident_bytes = args.resident_gb * 1e9
+    else:
+        resident_bytes = 0.0 if args.layers is None else plan.s_params
     store = None
     if stream:
-        # the ONE store every scheduler engine executes through — built
-        # here so the realized split can be printed before serving
+        # the ONE store the engine executes through — built here so the
+        # realized split can be printed before serving
         khat = (plan.predict_topk if args.predict_topk is None
                 else args.predict_topk)
         store = ParamStore(
@@ -222,7 +300,7 @@ def main() -> None:
             lru_bytes=(None if args.expert_lru_gb is None
                        else args.expert_lru_gb * 1e9),
         )
-        print(f"realized residency (smoke): {store.describe()}")
+        print(f"realized residency: {store.describe()}")
     if args.kv_page_tokens:
         # page-pool residency at the serving shape, printed up front (the
         # table the scheduler's engines will build)
@@ -232,15 +310,14 @@ def main() -> None:
             cfg,
             [(cfg.layer_kind(i), cfg.ffn_kind(i))
              for i in range(cfg.num_layers)],
-            args.batch, args.prompt_len + args.decode_len,
+            args.batch, ctx,
             CacheConfig(
                 page_tokens=args.kv_page_tokens,
                 device_pool_bytes=(None if args.device_kv_gb is None
                                    else args.device_kv_gb * 1e9),
             ),
         )
-        print(f"page-pool residency (smoke): {probe.describe()}")
-    import contextlib
+        print(f"page-pool residency: {probe.describe()}")
 
     from repro import analysis
 
@@ -253,6 +330,14 @@ def main() -> None:
                         model_axis="model", moe_dispatch="a2a")
         print(f"mesh: dp={dp} replicas x ep={ep} expert-parallel ranks, "
               f"ep_chunks={plan.ep_chunks}")
+    serve_cfg = ServeConfig(
+        scheduler=args.scheduler, decode_len=args.decode_len,
+        eos_id=args.eos_id, expert_path=args.expert_path,
+        hw=hw if args.scheduler == "continuous" else None,
+        kv_page_tokens=args.kv_page_tokens, device_kv_gb=args.device_kv_gb,
+        prefix_cache=args.prefix_cache, sctx=sctx,
+        ep_chunks=plan.ep_chunks, faults=args.faults,
+    )
 
     san_ctx = (analysis.sanitize(strict=args.sanitize == "strict",
                                  donation=True)
@@ -261,39 +346,22 @@ def main() -> None:
     with san_ctx as san:
         if dp > 1:
             # data-parallel fan-out: one arrival queue over dp Server
-            # replicas (shared prefix keys, per-replica KV/engines)
+            # replicas, one device group each (shared prefix keys,
+            # per-replica KV/engines)
             from repro.distributed import ReplicaServer
-            from repro.serving.server import ServeConfig
 
-            rserver = ReplicaServer(
-                cfg, params, dp, plan=plan,
-                serve=ServeConfig(
-                    scheduler=args.scheduler, decode_len=args.decode_len,
-                    eos_id=args.eos_id, expert_path=args.expert_path,
-                    hw=hw if args.scheduler == "continuous" else None,
-                    kv_page_tokens=args.kv_page_tokens,
-                    device_kv_gb=args.device_kv_gb,
-                    prefix_cache=args.prefix_cache,
-                    sctx=sctx, ep_chunks=plan.ep_chunks,
-                    faults=args.faults,
-                ),
-            )
-            for r in requests:
-                rserver.submit(r)
-            rrep = rserver.run()
-            report, per_replica = rrep.merged, rrep.per_replica
+            server = ReplicaServer(cfg, params, dp, plan=plan,
+                                   serve=serve_cfg)
         else:
-            report = serve_dataset(
-                cfg, params, requests, plan, args.decode_len,
-                expert_path=args.expert_path,
-                scheduler=args.scheduler, eos_id=args.eos_id,
-                store=store,
-                hw=hw if args.scheduler == "continuous" else None,
-                kv_page_tokens=args.kv_page_tokens,
-                device_kv_gb=args.device_kv_gb,
-                prefix_cache=args.prefix_cache,
-                sctx=sctx, ep_chunks=plan.ep_chunks,
-                faults=args.faults)
+            server = Server(cfg, params, plan, serve_cfg, StreamConfig(),
+                            store=store)
+        for r in requests:
+            server.submit(r)
+        rep = server.run()
+        if dp > 1:
+            report, per_replica = rep.merged, rep.per_replica
+        else:
+            report = rep
     if san is not None:
         rep = san.report()
         planned = ", ".join(f"{k}={v}" for k, v in

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.data.datasets import synthetic_batches
-from repro.launch.mesh import make_ctx
+from repro.launch.mesh import make_ctx, make_debug_mesh
 from repro.models import model as M
 from repro.sharding.specs import ShardCtx, param_shardings
 from repro.train.train_loop import train_loop
@@ -37,7 +37,7 @@ def main() -> None:
     n_dev = jax.device_count()
     if n_dev > 1:
         data = max(1, n_dev // 16)
-        mesh = jax.make_mesh((data, n_dev // data), ("data", "model"))
+        mesh = make_debug_mesh(data, n_dev // data)
         ctx = make_ctx(mesh, seq_shard=True)
         print(f"mesh: {dict(mesh.shape)}")
     else:

@@ -14,6 +14,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.configs.base import ModelConfig
@@ -67,6 +68,54 @@ def init_params(cfg: ModelConfig, key) -> Dict:
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(
             k_head, (cfg.d_model, cfg.vocab_size), dtype=dt
+        )
+    return params
+
+
+def init_params_host(cfg: ModelConfig, key) -> Dict:
+    """``init_params``' weights, drawn one layer group at a time on the
+    default device and kept in host memory as numpy arrays.
+
+    The device never holds more than one group's tensors at once, so a
+    model larger than device memory can be made here and then placed by
+    ``serving.weights.ParamStore`` (resident modules copied to the device,
+    streamed ones left on the host) or by the caller's own shardings.  The
+    key schedule is ``init_params``', so the values agree with it up to the
+    bf16 rounding of a few scaled draws (jit and vmap fuse the scale
+    product differently)."""
+    pattern = layer_pattern(cfg)
+    G = num_groups(cfg)
+    dt = jnp.dtype(cfg.dtype)
+    k_layers, k_embed, k_head = jax.random.split(key, 3)
+
+    @jax.jit
+    def init_group(k):
+        sk = jax.random.split(k, len(pattern))
+        return [
+            init_layer_params(cfg, kind, ffn, sk[j])
+            for j, (kind, ffn) in enumerate(pattern)
+        ]
+
+    layers = None
+    for g, kg in enumerate(jax.random.split(k_layers, G)):
+        group = jax.tree.map(np.asarray, init_group(kg))
+        if layers is None:
+            layers = jax.tree.map(
+                lambda a: np.empty((G,) + a.shape, a.dtype), group
+            )
+        for dst, src in zip(jax.tree.leaves(layers), jax.tree.leaves(group)):
+            dst[g] = src
+        del group
+    draw = jax.jit(dense_init, static_argnums=(1,), static_argnames=("dtype",))
+    params = {
+        "embed": np.asarray(draw(k_embed, (cfg.vocab_size, cfg.d_model),
+                                 dtype=dt)),
+        "layers": layers,
+        "final_norm": np.ones((cfg.d_model,), dt),
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = np.asarray(
+            draw(k_head, (cfg.d_model, cfg.vocab_size), dtype=dt)
         )
     return params
 

@@ -28,7 +28,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import dense_init
-from repro.sharding.specs import ShardCtx, shard_map
+from repro.sharding.specs import ShardCtx
 
 
 # ---------------------------------------------------------------------------
@@ -336,7 +336,7 @@ def moe_apply_sharded(
             aux = jax.lax.pmean(aux, ctx.batch_axes)
         return y.reshape(Bl, Sl, D), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=ctx.mesh,
         in_specs=(
@@ -436,7 +436,7 @@ def moe_apply_a2a(
         return y.reshape(Bl, Sl, D), aux
 
     x_spec = ctx.spec("batch", "model", None, shape=x.shape)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=ctx.mesh,
         in_specs=(

@@ -45,12 +45,14 @@ per-token latency after the first).
 """
 from __future__ import annotations
 
+import contextlib
 import heapq
 import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Iterator, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -125,6 +127,10 @@ class ServeConfig:
     ep_chunks: int = 1                   # pipeline chunks the a2a MoE stage
     #   splits the accumulated batch into (chunk k+1's all-to-all overlaps
     #   chunk k's expert FFN); 1 = serial dispatch
+    device: Optional[object] = None      # jax Device the engine, its KV and
+    #   its resident weights live on (None = JAX's default device); with
+    #   sctx, the home device of everything but the sharded expert stacks.
+    #   ReplicaServer gives each replica its own
     faults: Optional[object] = None      # fault-injection schedule: a
     #   repro.faults FaultPlan / FaultSpec / spec string ("seed=0,
     #   transfer=0.1,..."); None = unarmed (the ambient REPRO_FAULTS plan,
@@ -624,6 +630,7 @@ class Server:
                 stream_weights=st.stream_weights,
                 resident_bytes=st.resident_bytes, prefetch=st.prefetch,
                 predict_topk=st.predict_topk, lru_bytes=st.lru_bytes,
+                device=self.serve.device, sctx=self.serve.sctx,
             )
         if self.serve.max_batch is not None:
             # planner-sized up front (ServeConfig.from_plan): the engine
@@ -765,15 +772,24 @@ class Server:
         """
         if not self.has_work():
             return False
-        self._ensure_engine()
-        with faults.armed(self._faults):
-            self._maybe_preempt()
-            self._admit()
-            if self._any_live():
-                self._decode_tick(self._chunk_T())
-                if self.serve.replan_skew is not None:
-                    self._maybe_replan()
+        with self._on_device():
+            self._ensure_engine()
+            with faults.armed(self._faults):
+                self._maybe_preempt()
+                self._admit()
+                if self._any_live():
+                    self._decode_tick(self._chunk_T())
+                    if self.serve.replan_skew is not None:
+                        self._maybe_replan()
         return self.has_work()
+
+    def _on_device(self):
+        """Make the server's device JAX's default for the work inside, so
+        the arrays the engine creates (KV, sampler state, counters) land
+        beside its weights."""
+        if self.serve.device is None:
+            return contextlib.nullcontext()
+        return jax.default_device(self.serve.device)
 
     def run(self, until_idle: bool = True) -> ServeReport:
         """Drive ``step()`` to completion and return the report.
@@ -790,7 +806,8 @@ class Server:
 
     def finalize(self) -> ServeReport:
         """Drain engine counters and order results; idempotent."""
-        self.report._expert_dropped += self._drain_engine_stats()
+        with self._on_device():
+            self.report._expert_dropped += self._drain_engine_stats()
         if self._prefix is not None:
             self.report.prefix_hits = self._prefix.hits
             self.report.prefix_misses = self._prefix.misses
@@ -912,7 +929,8 @@ class Server:
         )
         if handle.status != "running":
             return False
-        self._preempt_slot(self._slot_handle.index(handle), self._now())
+        with self._on_device():
+            self._preempt_slot(self._slot_handle.index(handle), self._now())
         return True
 
     def _preempt_slot(self, s: int, now: float) -> None:

@@ -34,6 +34,8 @@ from typing import Dict, Iterable, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro import faults
 from repro.analysis import runtime as sanitizer
@@ -273,6 +275,15 @@ class ParamStore:
     WHICH math runs.  ``lru_bytes`` (default: the residency plan's spare
     bytes) bounds a device-side hot-expert LRU: every expert use promotes
     its weights; cold experts are demoted when the byte budget overflows.
+
+    ``params`` may hold device arrays or host numpy arrays (as
+    ``models.model.init_params_host`` makes them): resident modules are
+    copied to ``device`` (JAX's default device when None) and streamed ones
+    stay on the host, so a model larger than device memory is never whole
+    on the device.  With ``sctx`` (an expert-parallel mesh) every resident
+    expert stack is placed sharded over ``sctx.model_axis`` instead — each
+    device holds only its own experts, the shards built straight from the
+    host copy.
     """
 
     def __init__(
@@ -284,8 +295,16 @@ class ParamStore:
         prefetch_depth: int = 2,
         predict_topk: int = 0,
         lru_bytes: Optional[float] = None,
+        device=None,
+        sctx=None,
     ) -> None:
         self.cfg = cfg
+        self.device = device
+        self._expert_sharding = (
+            NamedSharding(sctx.mesh, P(sctx.model_axis, None, None))
+            if sctx is not None and sctx.mesh is not None
+            and sctx.model_axis is not None else None
+        )
         self.prefetch_enabled = prefetch
         self.prefetch_depth = max(1, prefetch_depth)
         self.residency = W.plan_residency(cfg, resident_bytes)
@@ -296,9 +315,9 @@ class ParamStore:
         layers = unstack_layers(cfg, params)
         self.schema: List[Tuple[str, str]] = [(k, f) for k, f, _ in layers]
         # base params: always device-resident (embed / final_norm / lm_head)
-        self.base: Dict = {
-            k: v for k, v in params.items() if k != "layers"
-        }
+        self.base: Dict = self._pin(
+            {k: v for k, v in params.items() if k != "layers"}
+        )
         # per-layer split into resident (device) and streamed (host) modules
         self._resident: List[Dict[str, Dict]] = []
         self._host: List[Dict[str, Dict]] = []
@@ -313,17 +332,17 @@ class ParamStore:
             res: Dict[str, Dict] = {}
             host: Dict[str, Dict] = {}
             if self.residency.mixer_resident[li]:
-                res["mixer"] = mixer
+                res["mixer"] = self._pin(mixer)
             else:
                 host["mixer"] = _to_host(mixer)
             if ffnp:
                 if self.residency.ffn_resident[li]:
-                    res["ffn"] = ffnp
+                    res["ffn"] = self._pin(ffnp)
                 elif self.predict_topk > 0 and ffn == "moe":
-                    self._moe_shared[li] = {
-                        "norm2": jax.device_put(ffnp["norm2"]),
-                        "router": jax.device_put(ffnp["moe"]["router"]),
-                    }
+                    self._moe_shared[li] = self._pin({
+                        "norm2": ffnp["norm2"],
+                        "router": ffnp["moe"]["router"],
+                    })
                     self._experts_host[li] = {
                         k: np.asarray(ffnp["moe"][k])
                         for k in ("experts_w_gate", "experts_w_up",
@@ -373,6 +392,18 @@ class ParamStore:
         if self._experts_host:
             self._zeros_expert()
 
+    def _pin(self, tree: Dict) -> Dict:
+        """Copy a resident module to the device (a no-op for arrays that
+        already live there); a mesh store shards expert stacks over the
+        model axis."""
+        def put(path, a):
+            if (self._expert_sharding is not None
+                    and "experts" in jax.tree_util.keystr(path)):
+                return jax.device_put(a, self._expert_sharding)
+            return jax.device_put(a, self.device)
+
+        return jax.tree_util.tree_map_with_path(put, tree)
+
     @classmethod
     def build(
         cls,
@@ -384,6 +415,8 @@ class ParamStore:
         prefetch: bool = True,
         predict_topk: Optional[int] = None,
         lru_bytes: Optional[float] = None,
+        device=None,
+        sctx=None,
     ) -> "ParamStore":
         """THE budget-resolution policy, shared by the engine constructor
         and the scheduler: everything resident unless ``stream_weights``;
@@ -400,7 +433,7 @@ class ParamStore:
             )
         return cls(
             cfg, params, resident_bytes=budget, prefetch=prefetch,
-            predict_topk=khat, lru_bytes=lru_bytes,
+            predict_topk=khat, lru_bytes=lru_bytes, device=device, sctx=sctx,
         )
 
     # -- residency inspection -------------------------------------------
@@ -475,7 +508,8 @@ class ParamStore:
     def _fetch(self, li: int) -> Tuple[Dict[str, Dict], int]:
         """Issue the async htod copy of layer ``li``'s streamed modules."""
         fetched = {
-            name: jax.device_put(tree) for name, tree in self._host[li].items()
+            name: jax.device_put(tree, self.device)
+            for name, tree in self._host[li].items()
         }
         nbytes = sum(_tree_bytes(tree) for tree in fetched.values())
         return fetched, nbytes
@@ -540,7 +574,7 @@ class ParamStore:
         li, e = key
         host = self._experts_host[li]
         tree = tuple(
-            jax.device_put(host[k][e])
+            jax.device_put(host[k][e], self.device)
             for k in ("experts_w_gate", "experts_w_up", "experts_w_down")
         )
         return tree, _tree_bytes(tree)
@@ -553,7 +587,8 @@ class ParamStore:
         if self._zero_expert is None:
             host = next(iter(self._experts_host.values()))
             self._zero_expert = tuple(
-                jnp.zeros(host[k].shape[1:], dtype=host[k].dtype)
+                jnp.zeros(host[k].shape[1:], dtype=host[k].dtype,
+                          device=self.device)
                 for k in ("experts_w_gate", "experts_w_up", "experts_w_down")
             )
         return self._zero_expert

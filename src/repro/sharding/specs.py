@@ -16,20 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map only exists as a top-level name from jax 0.6; on the pinned
-# 0.4.x line fall back to the experimental home, where the replication-check
-# kwarg is still called check_rep (renamed to check_vma upstream).
-try:
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map_04x
-
-    def shard_map(f, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_04x(f, **kwargs)
-
-
 def _axis_size(mesh: Mesh, axes) -> int:
     if axes is None:
         return 1

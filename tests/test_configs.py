@@ -98,3 +98,21 @@ def test_smoke_configs_reduced():
 def test_sub_quadratic_census():
     subq = {a for a in ARCH_IDS if get_config(a).sub_quadratic}
     assert subq == {"mamba2-370m", "jamba-1.5-large-398b", "h2o-danube-1.8b"}
+
+
+def test_depth_cut_keeps_published_widths():
+    """``launch.serve.executing_config``: the smoke preset without a layer
+    count; otherwise the registry config at its published widths with only
+    the depth cut, in whole layer-pattern periods."""
+    from dataclasses import replace
+
+    from repro.launch.serve import executing_config
+
+    assert executing_config("mixtral-8x7b") == get_config("mixtral-8x7b",
+                                                          smoke=True)
+    full = get_config("mixtral-8x7b")
+    assert executing_config("mixtral-8x7b", 4) == replace(full, num_layers=4)
+    with pytest.raises(ValueError, match="multiple of 8"):
+        executing_config("jamba-1.5-large-398b", 4)
+    with pytest.raises(ValueError):
+        executing_config("mixtral-8x7b", 33)

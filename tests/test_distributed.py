@@ -39,6 +39,7 @@ MESH_SCRIPT = textwrap.dedent(
     from repro.serving.server import (
         Request, ServeConfig, Server, StreamConfig,
     )
+    from repro.launch.mesh import make_mesh
     from repro.sharding.specs import ShardCtx
 
     assert len(jax.devices()) == 8, jax.devices()
@@ -47,9 +48,8 @@ MESH_SCRIPT = textwrap.dedent(
     plan = Plan(B=8, b_a=8, b_e=64, decode_chunk=4)
     rng = np.random.default_rng(0)
 
-    # 8 requests pad the static wave to the full B=8, so the decode batch
-    # divides every ep degree and the collective path (not the T % n
-    # single-device fallback) is what each mesh case exercises
+    # 8 requests fill the static wave to the full B=8, so the decode batch
+    # divides every ep degree (the indivisible case is checked below)
     def workload(trial):
         lens = rng.integers(3, 17, size=8)
         reqs = [
@@ -78,7 +78,7 @@ MESH_SCRIPT = textwrap.dedent(
 
     meshes = {
         ep: ShardCtx(
-            mesh=jax.make_mesh((1, ep), ("data", "model")),
+            mesh=make_mesh((1, ep), ("data", "model")),
             batch_axes=("data",), model_axis="model", moe_dispatch="a2a",
         )
         for ep in (1, 2, 4)
@@ -93,6 +93,36 @@ MESH_SCRIPT = textwrap.dedent(
                 if ep > 1:
                     assert rep.a2a_bytes > 0, (trial, scheduler, ep)
                     assert rep.collective_dispatches > 0
+
+    # a batch that doesn't divide the mesh (6 rows over 4 ranks) is padded
+    # with rows that are never sent: still the collective path, still
+    # token-identical
+    reqs6 = workload(7)[:6]
+    _, want6 = serve(reqs6, "static")
+    rep6, got6 = serve(reqs6, "static", sctx=meshes[4])
+    assert got6 == want6, (got6, want6)
+    assert rep6.a2a_bytes > 0
+
+    # ReplicaServer: one device group per replica, each replica's expert
+    # stacks built sharded over its own group
+    from repro.distributed import ReplicaServer
+
+    rs = ReplicaServer(cfg, params, 2, plan=plan,
+                       serve=ServeConfig(scheduler="static",
+                                         sctx=meshes[2]))
+    for r in reqs6:
+        rs.submit(r)
+    got_rs = [rr.tokens.tolist() for rr in rs.run().merged.request_results]
+    assert got_rs == want6, (got_rs, want6)
+    devs = jax.devices()
+    for i, s in enumerate(rs.servers):
+        assert list(s.serve.sctx.mesh.devices.flat) == devs[2 * i:2 * i + 2]
+        assert s.serve.device == devs[2 * i]
+        wg = s._store._resident[0]["ffn"]["moe"]["experts_w_gate"]
+        assert {d for d in wg.devices()} == set(devs[2 * i:2 * i + 2])
+        assert {sh.data.shape[0] for sh in wg.addressable_shards} == {
+            cfg.num_experts // 2}
+        assert {d for d in s._store.base["embed"].devices()} == {devs[2 * i]}
 
     # sanitizer-strict pass over a mesh Server.run(): decode regions run
     # under jax.transfer_guard('disallow'); the mesh batch/combine moves
@@ -220,10 +250,11 @@ def test_mesh_engine_rejects_unsupported_combos():
 
     from repro.core.engine import ModuleBatchingEngine
     from repro.distributed import validate_ep_shard
+    from repro.launch.mesh import make_mesh
     from repro.sharding.specs import ShardCtx
 
     cfg, params, plan = _smoke_setup()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sctx = ShardCtx(mesh=mesh, batch_axes=("data",), model_axis="model",
                     moe_dispatch="a2a")
 

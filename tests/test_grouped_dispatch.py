@@ -140,7 +140,9 @@ def test_grouped_dispatch_rejected_on_mesh():
     cfg = get_config("olmoe-1b-7b", smoke=True)
     p = moe_mod.init_moe_params(cfg, KEY)
     x = jnp.zeros((2, 8, cfg.d_model), jnp.bfloat16)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"))
     ctx = ShardCtx(mesh=mesh, batch_axes=("data",), model_axis="model",
                    moe_dispatch="grouped")
     with pytest.raises(ValueError, match="grouped"):

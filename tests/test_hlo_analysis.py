@@ -57,13 +57,14 @@ def test_loop_trip_attribution():
 
 def test_real_module_collectives():
     """A psum under shard_map on a 1-device mesh still lowers an all-reduce."""
-    mesh = jax.make_mesh((1,), ("x",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("x",))
 
     def f(a):
         return jax.lax.psum(a, "x")
 
-    from repro.sharding.specs import shard_map
-    g = shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P(),
+    g = jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P(),
                       check_vma=False)
     txt = jax.jit(g).lower(jnp.ones((8,))).compile().as_text()
     stats = collective_stats(txt)

@@ -53,7 +53,9 @@ def test_sharded_matches_local_on_1dev_mesh():
     cfg = _cfg(cf=64.0)
     p = init_moe_params(cfg, KEY)
     x = (jax.random.normal(KEY, (2, 16, cfg.d_model)) * 0.3).astype(jnp.bfloat16)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"))
     ctx = ShardCtx(mesh=mesh, batch_axes=("data",), model_axis="model")
     y_sh, aux_sh = moe_apply_sharded(cfg, p, x, ctx)
     y_loc, aux_loc = moe_apply_local(cfg, p, x)

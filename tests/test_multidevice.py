@@ -24,10 +24,11 @@ SCRIPT = textwrap.dedent(
     from repro.models.moe import (
         init_moe_params, moe_apply_a2a, moe_apply_local, moe_apply_sharded,
     )
+    from repro.launch.mesh import make_mesh
     from repro.models import model as M
     from repro.sharding.specs import ShardCtx
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ShardCtx(mesh=mesh, batch_axes=("data",), model_axis="model")
     key = jax.random.PRNGKey(0)
 
@@ -73,7 +74,7 @@ SCRIPT = textwrap.dedent(
 def test_sharded_moe_on_8_fake_devices():
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-c", SCRIPT], env=env,
         capture_output=True, text=True, timeout=900,

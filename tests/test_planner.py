@@ -122,3 +122,16 @@ def test_expert_buffer_term_in_eq3():
     assert used_hi - used_lo == pytest.approx(
         W.expert_buffer_bytes(cfg, 4096) - W.expert_buffer_bytes(cfg, 512)
     )
+
+
+def test_profile_for_device_by_kind():
+    """On a TPU the planner's profile is looked up by ``device_kind``; an
+    unknown kind is an error, not a default."""
+    from types import SimpleNamespace
+
+    from repro.core.hardware import TPU_V5E, profile_for_device
+
+    assert profile_for_device(SimpleNamespace(device_kind="TPU v5 lite")) \
+        is TPU_V5E
+    with pytest.raises(ValueError, match="TPU v9"):
+        profile_for_device(SimpleNamespace(device_kind="TPU v9"))

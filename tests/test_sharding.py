@@ -5,6 +5,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.sharding.specs import ShardCtx, cache_shardings, param_shardings
 
@@ -12,7 +13,7 @@ KEY = jax.random.PRNGKey(0)
 
 
 def _ctx_1dev():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     return ShardCtx(mesh=mesh, batch_axes=("data",), model_axis="model")
 
 
@@ -27,12 +28,9 @@ def test_param_shardings_cover_all_leaves():
 
 def test_param_shardings_divisibility_respected():
     """On the production mesh every spec divides its dim."""
-    import numpy as np
-
     cfg = get_config("mixtral-8x7b")      # 8 experts vs model=16: fallback path
     params = jax.eval_shape(lambda: M.init_params(cfg, KEY))
-    devs = np.array(jax.devices() * 256)[:256].reshape(16, 16)
-    mesh = jax.sharding.Mesh(devs, ("data", "model"))
+    mesh = make_mesh((16, 16), ("data", "model"), jax.devices() * 256)
     ctx = ShardCtx(mesh=mesh, batch_axes=("data",), model_axis="model")
     specs = param_shardings(ctx, params, zero1=True)
 
@@ -52,12 +50,9 @@ def test_param_shardings_divisibility_respected():
 
 def test_expert_fallback_tensor_parallel():
     """mixtral 8 experts % model=16 != 0 => F-dim sharding instead."""
-    import numpy as np
-
     cfg = get_config("mixtral-8x7b")
     params = jax.eval_shape(lambda: M.init_params(cfg, KEY))
-    devs = np.array(jax.devices() * 256)[:256].reshape(16, 16)
-    mesh = jax.sharding.Mesh(devs, ("data", "model"))
+    mesh = make_mesh((16, 16), ("data", "model"), jax.devices() * 256)
     ctx = ShardCtx(mesh=mesh, batch_axes=("data",), model_axis="model")
     specs = param_shardings(ctx, params, zero1=False)
     flat = {

@@ -259,3 +259,30 @@ def test_serve_dataset_streaming_reports_htod():
         assert rep.prefetch_wait_s >= 0.0
         for a, b in zip(ref.request_results, rep.request_results):
             assert np.array_equal(a.tokens, b.tokens), (sched, a.index)
+
+
+def test_host_params_place_resident_and_keep_streamed_on_host():
+    """``init_params_host`` draws the same weights as ``init_params`` (to
+    bf16 rounding of the scaled draws) into numpy; a store built from them
+    copies only resident modules to the device, and generation matches the
+    store built from device arrays token for token."""
+    cfg, params, toks = _setup("mixtral-8x7b")
+    host = M.init_params_host(cfg, KEY)
+    assert jax.tree.structure(host) == jax.tree.structure(params)
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(host)):
+        assert isinstance(b, np.ndarray) and b.dtype == a.dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   b.astype(np.float32), rtol=2 ** -7)
+    budget = W.base_weight_bytes(cfg) + sum(
+        W.mixer_weight_bytes(cfg, cfg.layer_kind(i))
+        for i in range(cfg.num_layers))
+    store = ParamStore(cfg, host, resident_bytes=budget)
+    assert all(isinstance(a, jax.Array) for a in jax.tree.leaves(store.base))
+    assert all(isinstance(a, jax.Array) for r in store._resident
+               for a in jax.tree.leaves(r))
+    assert all(isinstance(a, np.ndarray) for h in store._host
+               for a in jax.tree.leaves(h))
+    assert store.streamed_module_bytes() > 0
+    want, _ = _generate(cfg, host, toks)
+    got, _ = _generate(cfg, host, toks, store=store)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
