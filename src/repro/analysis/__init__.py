@@ -10,6 +10,9 @@ Three layers (see ``analysis/README.md`` for the rule catalogue):
   stale-buffer poisoner;
 * AST lint — ``python -m repro.analysis.lint src/repro`` (rules
   MG101–MG107, stdlib-only, blocking in CI).
+
+Beside them, ``spans``: ``moegen.*`` host spans on the profiler's clock,
+switched on by ``tracing()`` (every planned transfer scope is one).
 """
 from repro.analysis.donation import DonationCheck, check_donation
 from repro.analysis.markers import hot_path, is_hot_path
@@ -24,6 +27,7 @@ from repro.analysis.runtime import (
     poison_stale,
     sanitize,
 )
+from repro.analysis.spans import span, tracing
 
 __all__ = [
     "DonationCheck",
@@ -40,4 +44,6 @@ __all__ = [
     "poison_stale",
     "register_jit",
     "sanitize",
+    "span",
+    "tracing",
 ]

@@ -48,7 +48,7 @@ from typing import Dict, List, Optional
 
 import jax
 
-from repro.analysis import registry
+from repro.analysis import registry, spans
 
 
 class SanitizerError(AssertionError):
@@ -196,18 +196,21 @@ def decode_region():
 
 
 @contextlib.contextmanager
-def allowed(tag: str):
+def allowed(tag: str, **args):
     """A PLANNED transfer scope inside a decode region (StreamWindow
     ``device_put``s, sampler-state uploads, the per-tick position vector,
     token readback).  Re-enters ``transfer_guard("allow")`` and counts
-    the occurrence under ``tag`` in the sanitizer report."""
+    the occurrence under ``tag`` in the sanitizer report.  Whether or not
+    a sanitizer is active, the scope is the program span ``xfer`` with
+    ``tag`` and ``args`` as its stats (``repro.analysis.spans``)."""
     san = current()
-    if san is None:
-        yield
-        return
-    san.planned[tag] = san.planned.get(tag, 0) + 1
-    with jax.transfer_guard("allow"):
-        yield
+    with spans.span("xfer", tag=tag, **args):
+        if san is None:
+            yield
+            return
+        san.planned[tag] = san.planned.get(tag, 0) + 1
+        with jax.transfer_guard("allow"):
+            yield
 
 
 def on_donating_launch(entry, args, kwargs) -> None:

@@ -89,6 +89,7 @@ from jax import lax
 from repro.analysis import runtime as sanitizer
 from repro.analysis.markers import hot_path
 from repro.analysis.registry import TraceKeySet, register_jit
+from repro.analysis.spans import span
 from repro.configs.base import ModelConfig
 from repro.core import workload as W
 from repro.core.dag_builder import Plan
@@ -657,7 +658,6 @@ class EngineStats:
     decode_retraces: int = 0             # distinct fused (B, path, chunk) keys
     kv_htod_bytes: int = 0               # streamed KV-page bytes copied htod
     kv_dtoh_bytes: int = 0               # KV bytes spilled device->host
-    kv_stream_wait_s: float = 0.0        # stall waiting on page transfers
     expert_tokens_dropped_by_layer: Optional[np.ndarray] = None
     #                                      (n_moe,) int64 per-MoE-layer drops;
     #                                      sums to expert_tokens_dropped
@@ -679,6 +679,10 @@ class EngineStats:
     #                                      (weight + expert + KV-page windows)
     transfer_timeouts: int = 0           # watchdog-expired acquire waits
     #                                      recovered by demand re-fetch
+    prefill_capacity_rows: int = 0       # grouped-prefill (E, cap) buffer
+    #                                      rows, per MoE layer per micro-batch
+    prefill_routed_copies: int = 0       # routed copies of real prompt
+    #                                      tokens those buffers carried
 
 
 class ModuleBatchingEngine:
@@ -900,10 +904,9 @@ class ModuleBatchingEngine:
             self.stats.expert_lru_hits += ec["lru_hits"]
             self.stats.expert_lru_bytes = ec["lru_bytes_used"]
         if self.pages is not None:
-            kv_htod, kv_dtoh, kv_wait = self.pages.take_counters()
+            kv_htod, kv_dtoh = self.pages.take_counters()
             self.stats.kv_htod_bytes += kv_htod
             self.stats.kv_dtoh_bytes += kv_dtoh
-            self.stats.kv_stream_wait_s += kv_wait
         for taker in (getattr(self.store, "take_fault_counters", None),
                       getattr(self.pages, "take_fault_counters", None)):
             if taker is not None:
@@ -1110,6 +1113,9 @@ class ModuleBatchingEngine:
         if cfg.sliding_window:
             assert S <= cfg.sliding_window, "engine prefill requires prompt <= window"
         rows = np.asarray(rows)
+        # the host copy counts prompt tokens without a device sync (the
+        # server passes numpy lengths)
+        lens_host = None if lengths is None else np.asarray(lengths)
         lengths = None if lengths is None else jnp.asarray(lengths, jnp.int32)
         b_a = max(1, min(plan.b_a, n))
         spans = [(lo, min(n, lo + b_a)) for lo in range(0, n, b_a)]
@@ -1123,39 +1129,9 @@ class ModuleBatchingEngine:
                 x = jnp.concatenate([fe.astype(x.dtype), x[:, F:]], axis=1)
             xs.append(x)
         for li, (kind, ffn) in enumerate(self.schema):
-            p = self.store.acquire(li)
-            self.store.prefetch(li + 1)     # hide l+1's copy behind this layer
-            # grouped-prefill MoE layers split into mixer+route / grouped-FFN
-            # launches so the FFN capacity can be the next pow2 bucket over
-            # the micro-batch's MEASURED max expert load instead of the full
-            # token count — smaller (E, C, D) buffers, zero drops preserved
-            split_moe = ffn == "moe" and self.grouped_prefill
-            outs = []
-            for (lo, hi), x in zip(spans, xs):
-                ln = None if lengths is None else lengths[lo:hi]
-                if split_moe:
-                    x_mid, entry, xt, gates, idx, max_load, _ = (
-                        _prefill_mixer_route_module(
-                            cfg, kind, p, x, positions, ln
-                        )
-                    )
-                    with sanitizer.allowed("prefill-capacity-probe"):
-                        cap = W.next_pow2(int(np.asarray(max_load)))
-                    if self.sctx is not None:
-                        y = x_mid + self._mesh_moe(li, p, x_mid, cap,
-                                                   (xt, gates, idx))
-                    else:
-                        y, _ = _prefill_moe_ffn_module(
-                            cfg, cap, p["moe"], x_mid, xt, gates, idx
-                        )
-                else:
-                    sctx = self._prefill_sctx((hi - lo) * S)
-                    y, entry, _ = _prefill_layer_module(
-                        cfg, kind, ffn, sctx, p, x, positions, ln
-                    )
-                self._write_cache_rows(li, kind, entry, rows[lo:hi])
-                outs.append(y)
-            xs = outs
+            with span("engine.layer", layer=li, phase="prefill"):
+                xs = self._prefill_layer(li, kind, ffn, xs, spans, rows,
+                                         positions, lengths, lens_host, S)
         self.stats.attn_microbatches += len(spans)
         x_full = jnp.concatenate(xs, axis=0)
         if lengths is None:
@@ -1163,6 +1139,49 @@ class ModuleBatchingEngine:
         else:
             h_last = x_full[jnp.arange(n), lengths - 1]
         return _head_module(cfg, cfg.tie_embeddings, self.store.base, h_last)
+
+    def _prefill_layer(self, li, kind, ffn, xs, spans, rows, positions,
+                       lengths, lens_host, S) -> List[jax.Array]:
+        """Layer ``li`` of ``prefill_slots`` over every micro-batch."""
+        cfg = self.cfg
+        p = self.store.acquire(li)
+        self.store.prefetch(li + 1)     # hide l+1's copy behind this layer
+        # grouped-prefill MoE layers split into mixer+route / grouped-FFN
+        # launches so the FFN capacity can be the next pow2 bucket over
+        # the micro-batch's MEASURED max expert load instead of the full
+        # token count — smaller (E, C, D) buffers, zero drops preserved
+        split_moe = ffn == "moe" and self.grouped_prefill
+        outs = []
+        for (lo, hi), x in zip(spans, xs):
+            ln = None if lengths is None else lengths[lo:hi]
+            if split_moe:
+                x_mid, entry, xt, gates, idx, max_load, _ = (
+                    _prefill_mixer_route_module(
+                        cfg, kind, p, x, positions, ln
+                    )
+                )
+                with sanitizer.allowed("prefill-capacity-probe"):
+                    cap = W.next_pow2(int(np.asarray(max_load)))
+                tokens = ((hi - lo) * S if lens_host is None
+                          else int(lens_host[lo:hi].sum()))
+                self.stats.prefill_capacity_rows += cfg.num_experts * cap
+                self.stats.prefill_routed_copies += (
+                    cfg.experts_per_token * tokens)
+                if self.sctx is not None:
+                    y = x_mid + self._mesh_moe(li, p, x_mid, cap,
+                                               (xt, gates, idx))
+                else:
+                    y, _ = _prefill_moe_ffn_module(
+                        cfg, cap, p["moe"], x_mid, xt, gates, idx
+                    )
+            else:
+                sctx = self._prefill_sctx((hi - lo) * S)
+                y, entry, _ = _prefill_layer_module(
+                    cfg, kind, ffn, sctx, p, x, positions, ln
+                )
+            self._write_cache_rows(li, kind, entry, rows[lo:hi])
+            outs.append(y)
+        return outs
 
     # -- prefix caching ---------------------------------------------------
     def read_prefix_rows(self, slot: int, pspan: int) -> List:
@@ -1277,32 +1296,41 @@ class ModuleBatchingEngine:
                 pos_host = np.asarray(pos, np.int32)  # lint: allow[MG101] planned once-per-tick position readback for the page table
         x = _embed_module(cfg, self.store.base["embed"], tokens)
         for li, (kind, ffn) in enumerate(self.schema):
-            # predictive-streamed MoE layers skip the full expert-stack
-            # assembly in acquire(): the stage fetches only the experts the
-            # router actually used (plus LRU hits) and prefetches the
-            # predicted set for the next streamed MoE layer
-            predictive = (ffn == "moe" and self.expert_path == "grouped"
-                          and self.store.streams_experts(li))
-            p = self.store.acquire(li, experts=not predictive)
-            if kind == "attn":
-                x = x + self._attention_stage(li, p, x, pos, row0, pos_host)
-            else:
-                y, h, conv = _ssm_decode_module(
-                    cfg, row0, p, x, self.cache[li]["h"], self.cache[li]["conv"]
-                )
-                self.cache[li] = {"h": h, "conv": conv}
-                x = x + y
-            self.store.prefetch(li + 1)     # before the FFN/grouped launch
-            if self.pages is not None:
-                self.pages.prefetch(li + 1)  # next layer's host KV frames
-            if ffn == "moe":
-                if predictive:
-                    x = x + self._expert_stage_predictive(li, x)
-                else:
-                    x = x + self._expert_stage(li, p, x)
-            elif cfg.d_ff > 0 and "ffn" in p:
-                x = x + _ffn_module(cfg, p, x)
+            with span("engine.layer", layer=li, phase="decode"):
+                x = self._decode_layer(li, kind, ffn, x, pos, row0, pos_host)
         return _head_module(cfg, cfg.tie_embeddings, self.store.base, x)
+
+    @hot_path
+    def _decode_layer(self, li, kind, ffn, x, pos, row0: int,
+                      pos_host) -> jax.Array:
+        """Layer ``li`` of ``_decode_rows``."""
+        cfg = self.cfg
+        # predictive-streamed MoE layers skip the full expert-stack
+        # assembly in acquire(): the stage fetches only the experts the
+        # router actually used (plus LRU hits) and prefetches the
+        # predicted set for the next streamed MoE layer
+        predictive = (ffn == "moe" and self.expert_path == "grouped"
+                      and self.store.streams_experts(li))
+        p = self.store.acquire(li, experts=not predictive)
+        if kind == "attn":
+            x = x + self._attention_stage(li, p, x, pos, row0, pos_host)
+        else:
+            y, h, conv = _ssm_decode_module(
+                cfg, row0, p, x, self.cache[li]["h"], self.cache[li]["conv"]
+            )
+            self.cache[li] = {"h": h, "conv": conv}
+            x = x + y
+        self.store.prefetch(li + 1)     # before the FFN/grouped launch
+        if self.pages is not None:
+            self.pages.prefetch(li + 1)  # next layer's host KV frames
+        if ffn == "moe":
+            if predictive:
+                x = x + self._expert_stage_predictive(li, x)
+            else:
+                x = x + self._expert_stage(li, p, x)
+        elif cfg.d_ff > 0 and "ffn" in p:
+            x = x + _ffn_module(cfg, p, x)
+        return x
 
     # -- module stages ---------------------------------------------------
     def _attention_stage(self, li, p, x, pos, row0: int = 0,

@@ -211,6 +211,10 @@ def main(argv=None) -> None:
                          "raises on unplanned transfers, log records them) "
                          "and donation aliasing is verified; prints the "
                          "sanitizer report after serving")
+    ap.add_argument("--trace-dir", default=None, metavar="DIR",
+                    help="record a profiler trace of the serving run into "
+                         "DIR with the program's moegen.* spans on (open it "
+                         "in TensorBoard's profile plugin or Perfetto)")
     args = ap.parse_args(argv)
     cache_dir = enable_compile_cache()
 
@@ -342,8 +346,12 @@ def main(argv=None) -> None:
     san_ctx = (analysis.sanitize(strict=args.sanitize == "strict",
                                  donation=True)
                if args.sanitize != "off" else contextlib.nullcontext())
+    trace_ctx = contextlib.ExitStack()
+    if args.trace_dir:
+        trace_ctx.enter_context(jax.profiler.trace(args.trace_dir))
+        trace_ctx.enter_context(analysis.tracing())
     per_replica = None
-    with san_ctx as san:
+    with san_ctx as san, trace_ctx:
         if dp > 1:
             # data-parallel fan-out: one arrival queue over dp Server
             # replicas, one device group each (shared prefix keys,
@@ -362,6 +370,8 @@ def main(argv=None) -> None:
             report, per_replica = rep.merged, rep.per_replica
         else:
             report = rep
+    if args.trace_dir:
+        print(f"profile with moegen.* spans written under {args.trace_dir}")
     if san is not None:
         rep = san.report()
         planned = ", ".join(f"{k}={v}" for k, v in

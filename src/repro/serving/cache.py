@@ -159,7 +159,7 @@ class KVPageTable:
                 self._epoch[li] = 0
             self._window = StreamWindow(
                 self._fetch_layer, depth=cache_cfg.prefetch_depth,
-                enabled=True,
+                enabled=True, tag="kv-pages",
             )
 
     # -- residency -------------------------------------------------------
@@ -393,7 +393,7 @@ class KVPageTable:
         assert self._window is not None
         epoch, k, v = self._window.acquire(li)
         if epoch != self._epoch[li]:
-            with sanitizer.allowed("stream-window"):
+            with sanitizer.allowed("kv-pages", key=li):
                 (epoch, k, v), nbytes = self._fetch_layer(li)
             self._window.htod_bytes += nbytes
             self._window.demand += 1
@@ -441,13 +441,14 @@ class KVPageTable:
         return moved
 
     # -- accounting ------------------------------------------------------
-    def take_counters(self) -> Tuple[int, int, float]:
-        """Drain (htod_bytes, dtoh_bytes, stream_wait_s) since last call."""
-        htod, wait = (self._window.take_counters()
-                      if self._window is not None else (0, 0.0))
+    def take_counters(self) -> Tuple[int, int]:
+        """Drain (htod_bytes, dtoh_bytes) since last call (the page
+        window's waits are its ``stream.wait`` spans)."""
+        htod = (self._window.take_counters()[0]
+                if self._window is not None else 0)
         dtoh = self.dtoh_bytes
         self.dtoh_bytes = 0
-        return htod, dtoh, wait
+        return htod, dtoh
 
     def take_fault_counters(self) -> Tuple[int, int]:
         """Drain (transfer retries, watchdog timeouts) of the page stream

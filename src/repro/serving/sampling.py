@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.analysis import runtime as sanitizer
 from repro.analysis.registry import register_jit
+from repro.analysis.spans import span
 
 
 @dataclass(frozen=True)
@@ -180,20 +181,21 @@ class BatchSampler:
                slots: Optional[Sequence[int]] = None) -> jax.Array:
         """Next token for each selected slot: (n, V) logits -> (n,) tokens,
         row j of ``logits`` belonging to ``slots[j]`` (default: all)."""
-        idx = (np.arange(self.nslots) if slots is None
-               else np.asarray(slots, np.int64))
-        assert logits.shape[0] == idx.size, (logits.shape, idx.size)
-        if not (self._temps[idx] > 0).any():
+        with span("sample"):
+            idx = (np.arange(self.nslots) if slots is None
+                   else np.asarray(slots, np.int64))
+            assert logits.shape[0] == idx.size, (logits.shape, idx.size)
+            if not (self._temps[idx] > 0).any():
+                self._steps[idx] += 1
+                return jnp.argmax(logits, axis=-1)
+            with sanitizer.allowed("sampler-state"):
+                toks = _sample_module(
+                    logits,
+                    jnp.asarray(self._keys[idx]),
+                    jnp.asarray(self._steps[idx]),
+                    jnp.asarray(self._temps[idx]),
+                    jnp.asarray(self._topks[idx]),
+                    use_topk=bool((self._topks[idx] > 0).any()),
+                )
             self._steps[idx] += 1
-            return jnp.argmax(logits, axis=-1)
-        with sanitizer.allowed("sampler-state"):
-            toks = _sample_module(
-                logits,
-                jnp.asarray(self._keys[idx]),
-                jnp.asarray(self._steps[idx]),
-                jnp.asarray(self._temps[idx]),
-                jnp.asarray(self._topks[idx]),
-                use_topk=bool((self._topks[idx] > 0).any()),
-            )
-        self._steps[idx] += 1
-        return toks
+            return toks

@@ -59,6 +59,7 @@ import numpy as np
 from repro import faults
 from repro.analysis import runtime as sanitizer
 from repro.analysis.markers import hot_path
+from repro.analysis.spans import span
 from repro.configs.base import ModelConfig
 from repro.core import workload as W
 from repro.core.dag_builder import Plan
@@ -232,6 +233,9 @@ class ServeReport:
     prefill_tokens: int = 0       # token-positions actually computed in prefill
     #   (full prompts on a miss, suffix only on a prefix hit — the gap vs
     #   sum(len(prompt)) is the prefill work the prefix cache skipped)
+    prefill_capacity_rows: int = 0  # grouped-prefill expert buffer rows
+    prefill_routed_copies: int = 0  # routed copies of real prompt tokens
+    #   in them (1 - copies/rows is prefill's expert padding)
     _expert_dropped: int = 0      # drops counted outside BatchResults
     # predictive per-expert streaming + imbalance accounting (grouped path)
     expert_dropped_by_layer: Optional[np.ndarray] = None  # (n_moe,) drops
@@ -507,7 +511,7 @@ class Server:
         # engine-stat totals already drained into the report
         self._seen = {"drop": 0, "htod": 0, "wait": 0.0, "kvh": 0, "kvd": 0,
                       "ph": 0, "pm": 0, "lh": 0, "a2a": 0, "cd": 0,
-                      "retr": 0, "tmo": 0}
+                      "retr": 0, "tmo": 0, "pcr": 0, "prc": 0}
         # online capacity re-plan (replan_skew): the hottest expert's share
         # at the last (re-)plan; None until the first measurement
         self._replan_share: Optional[float] = None
@@ -692,6 +696,10 @@ class Server:
         self.report.transfer_retries += st.transfer_retries - self._seen["retr"]
         self.report.transfer_timeouts += (st.transfer_timeouts
                                           - self._seen["tmo"])
+        self.report.prefill_capacity_rows += (st.prefill_capacity_rows
+                                              - self._seen["pcr"])
+        self.report.prefill_routed_copies += (st.prefill_routed_copies
+                                              - self._seen["prc"])
         # cumulative engine totals — one engine per server, so the report's
         # arrays are simply the latest snapshot (copies: the engine keeps
         # accumulating into its own buffers)
@@ -711,7 +719,9 @@ class Server:
                       "a2a": st.a2a_bytes,
                       "cd": st.collective_dispatches,
                       "retr": st.transfer_retries,
-                      "tmo": st.transfer_timeouts}
+                      "tmo": st.transfer_timeouts,
+                      "pcr": st.prefill_capacity_rows,
+                      "prc": st.prefill_routed_copies}
         return d_drop
 
     def _maybe_replan(self) -> None:
@@ -772,7 +782,7 @@ class Server:
         """
         if not self.has_work():
             return False
-        with self._on_device():
+        with span("step", step=self._ticks), self._on_device():
             self._ensure_engine()
             with faults.armed(self._faults):
                 self._maybe_preempt()
@@ -823,10 +833,11 @@ class Server:
         return None
 
     def _admit(self) -> None:
-        if self.serve.scheduler == "static":
-            self._admit_static()
-        else:
-            self._admit_continuous()
+        with span("admit"):
+            if self.serve.scheduler == "static":
+                self._admit_static()
+            else:
+                self._admit_continuous()
 
     def _admit_static(self) -> None:
         """Admit-in-waves policy: a new wave only once the previous wave has
@@ -1049,60 +1060,63 @@ class Server:
         batched prefill and donate their prefix rows to the store
         afterwards.  Tokens are identical either way (per-slot seeded
         sampling; copied KV equals recomputed KV)."""
-        engine, sampler = self._engine, self._sampler
-        t0 = self._now()
-        hits: List = []
-        misses, miss_slots = list(handles), list(slots)
-        if self._prefix is not None:
-            hits, misses, miss_slots = [], [], []
-            for h, s in zip(handles, slots):
-                kp = self._prefix.key(h.prompt)
-                kvs = None if kp is None else self._prefix.get(kp[0])
-                if kvs is not None:
-                    hits.append((h, s, kp[1], kvs))
-                else:
-                    misses.append(h)
-                    miss_slots.append(s)
-        for h, s in zip(handles, slots):
-            sampler.set_slot(s, h.sampling)
-        tok0: Dict[int, int] = {}
-        if misses:
-            self.report.prefill_tokens += sum(len(h.prompt) for h in misses)
-            ptoks, lens = pad_requests(misses, self.serve.pad_id)
-            lg = engine.prefill_slots(jnp.asarray(ptoks), miss_slots,
-                                      lengths=lens)
-            for s, tk in zip(miss_slots,
-                             np.asarray(sampler.sample(lg, miss_slots))):
-                tok0[s] = int(tk)
+        with span("prefill", rows=len(handles),
+                  tokens=sum(len(h.prompt) for h in handles)):
+            engine, sampler = self._engine, self._sampler
+            t0 = self._now()
+            hits: List = []
+            misses, miss_slots = list(handles), list(slots)
             if self._prefix is not None:
-                for h, s in zip(misses, miss_slots):
+                hits, misses, miss_slots = [], [], []
+                for h, s in zip(handles, slots):
                     kp = self._prefix.key(h.prompt)
-                    if kp is not None:
-                        self._prefix.put(
-                            kp[0], engine.read_prefix_rows(s, kp[1])
-                        )
-        for h, s, pspan, kvs in hits:
-            self.report.prefill_tokens += len(h.prompt) - pspan
-            lg = engine.prefill_prefix_hit(s, h.prompt, kvs, pspan)
-            tok0[s] = int(np.asarray(sampler.sample(lg, [s]))[0])
-        now = self._now()
-        self.report.prefill_s += now - t0
-        if self._wave is not None:
-            self._wave["prefill_s"] += now - t0
-        eos = self.serve.eos_id
-        for h, s in zip(handles, slots):
-            tk = tok0[s]
-            self._slot_handle[s] = h
-            self._pos[s] = len(h.prompt)
-            self._cur[s] = tk
-            h.status = "running"
-            h.admit_s = t0
-            h.first_token_s = now
-            h._emit(tk)
+                    kvs = None if kp is None else self._prefix.get(kp[0])
+                    if kvs is not None:
+                        hits.append((h, s, kp[1], kvs))
+                    else:
+                        misses.append(h)
+                        miss_slots.append(s)
+            for h, s in zip(handles, slots):
+                sampler.set_slot(s, h.sampling)
+            tok0: Dict[int, int] = {}
+            if misses:
+                self.report.prefill_tokens += sum(len(h.prompt)
+                                                  for h in misses)
+                ptoks, lens = pad_requests(misses, self.serve.pad_id)
+                lg = engine.prefill_slots(jnp.asarray(ptoks), miss_slots,
+                                          lengths=lens)
+                for s, tk in zip(miss_slots,
+                                 np.asarray(sampler.sample(lg, miss_slots))):
+                    tok0[s] = int(tk)
+                if self._prefix is not None:
+                    for h, s in zip(misses, miss_slots):
+                        kp = self._prefix.key(h.prompt)
+                        if kp is not None:
+                            self._prefix.put(
+                                kp[0], engine.read_prefix_rows(s, kp[1])
+                            )
+            for h, s, pspan, kvs in hits:
+                self.report.prefill_tokens += len(h.prompt) - pspan
+                lg = engine.prefill_prefix_hit(s, h.prompt, kvs, pspan)
+                tok0[s] = int(np.asarray(sampler.sample(lg, [s]))[0])
+            now = self._now()
+            self.report.prefill_s += now - t0
             if self._wave is not None:
-                self._wave["rows"][s] = [tk]
-            if h.decode_len <= 1 or (eos is not None and tk == eos):
-                self._finish_slot(s, now)
+                self._wave["prefill_s"] += now - t0
+            eos = self.serve.eos_id
+            for h, s in zip(handles, slots):
+                tk = tok0[s]
+                self._slot_handle[s] = h
+                self._pos[s] = len(h.prompt)
+                self._cur[s] = tk
+                h.status = "running"
+                h.admit_s = t0
+                h.first_token_s = now
+                h._emit(tk)
+                if self._wave is not None:
+                    self._wave["rows"][s] = [tk]
+                if h.decode_len <= 1 or (eos is not None and tk == eos):
+                    self._finish_slot(s, now)
 
     def _chunk_T(self) -> int:
         """Decode ticks to run this step as ONE fused multi-token chunk.
@@ -1176,10 +1190,11 @@ class Server:
             live[[s for s in range(self._b)
                   if self._slot_handle[s] is not None]] = True
         t0 = self._now()
-        toks = engine.decode_chunk(
-            jnp.asarray(self._cur), jnp.asarray(self._pos), sampler, T,
-            live=live,
-        )
+        with span("decode", T=T):
+            toks = engine.decode_chunk(
+                jnp.asarray(self._cur), jnp.asarray(self._pos), sampler, T,
+                live=live,
+            )
         with sanitizer.allowed("token-readback"):
             mat = np.asarray(toks)  # lint: allow[MG101] the per-chunk token readback — the ONE planned d2h sync per scheduler tick
         now = self._now()
@@ -1187,6 +1202,15 @@ class Server:
         self.report.decode_s += now - t0
         if wave is not None:
             wave["decode_s"] += now - t0
+        with span("emit", T=T):
+            self._emit_chunk(mat, T, now)
+
+    @hot_path
+    def _emit_chunk(self, mat: np.ndarray, T: int, now: float) -> None:
+        """Emit the ``(B, T)`` chunk of read-back tokens tick by tick:
+        live slots take their tokens, finishers go to the policy's finish
+        path, and a static wave closes at its drain."""
+        wave = self._wave
         counted = len(wave["slots"]) if wave is not None else self._b
         eos = self.serve.eos_id
         for t in range(T):

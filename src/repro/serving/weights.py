@@ -39,6 +39,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import faults
 from repro.analysis import runtime as sanitizer
+from repro.analysis.spans import span
 from repro.configs.base import ModelConfig
 from repro.core import workload as W
 from repro.models import model as model_mod
@@ -96,7 +97,10 @@ class StreamWindow:
     ``tag`` names the planned-transfer scope every copy through this window
     is issued under, so the runtime sanitizer can attribute traffic per
     stream (``stream-window`` for whole-module staging, ``expert-prefetch``
-    for the predictive per-expert window).
+    for the predictive per-expert window, ``kv-pages`` for host KV
+    frames).  Each copy is the program span ``xfer`` with the tag and its
+    key, and each blocking wait in ``acquire`` the span ``stream.wait``
+    with the tag, key and bytes of the copy it waits for.
 
     Fault tolerance: every fetch consults the armed ``faults`` plan (a
     no-op when unarmed) and retries transient failures under the shared
@@ -118,7 +122,7 @@ class StreamWindow:
         self.depth = max(1, depth)
         self.enabled = enabled
         self.retry = retry if retry is not None else faults.RetryPolicy()
-        self.inflight: Dict = {}
+        self.inflight: Dict = {}             # key -> (value, nbytes)
         self._order: List = []
         self.htod_bytes = 0
         self.wait_s = 0.0
@@ -145,7 +149,7 @@ class StreamWindow:
             try:
                 scope = ("fault-retry" if recovery or attempt > 0
                          else self.tag)
-                with sanitizer.allowed(scope):
+                with sanitizer.allowed(scope, key=key):
                     return self._issue(key)
             except faults.TransientTransferError:
                 if attempt >= self.retry.max_retries:
@@ -178,6 +182,16 @@ class StreamWindow:
             [x for x in jax.tree.leaves(value) if isinstance(x, jax.Array)])
         return True
 
+    def _wait(self, value, key, nbytes: int, demand: bool) -> bool:
+        """``_wait_ready`` in the span ``stream.wait``, its seconds
+        accounted in ``wait_s``."""
+        t0 = time.perf_counter()
+        with span("stream.wait", tag=self.tag, key=key, bytes=nbytes,
+                  demand=int(demand)):
+            ok = self._wait_ready(value)
+        self.wait_s += time.perf_counter() - t0
+        return ok
+
     def prefetch(self, key) -> None:
         """Stage ``key``'s transfer into the window (async; returns
         immediately).  No-op when disabled or already in flight."""
@@ -190,7 +204,7 @@ class StreamWindow:
         fp = faults.current()
         if fp is not None and fp.stall_fault(self.tag, key):
             value = _StalledTransfer(value)
-        self.inflight[key] = value
+        self.inflight[key] = (value, nbytes)
         self._order.append(key)
         self.htod_bytes += nbytes
         self.issued += 1
@@ -202,16 +216,15 @@ class StreamWindow:
         injected stalled transfer) is recovered by abandoning the dead
         entry and demand re-fetching once; a second expiry raises
         ``StreamTimeoutError`` with the window tag and key."""
-        if key in self.inflight:
-            value = self.inflight.pop(key)
-            self._order.remove(key)
-        else:
+        demand = key not in self.inflight
+        if demand:
             value, nbytes = self._issue_with_retry(key)
             self.htod_bytes += nbytes
             self.demand += 1
-        t0 = time.perf_counter()
-        ok = self._wait_ready(value)
-        self.wait_s += time.perf_counter() - t0
+        else:
+            value, nbytes = self.inflight.pop(key)
+            self._order.remove(key)
+        ok = self._wait(value, key, nbytes, demand)
         if ok:
             return value
         self.timeouts += 1
@@ -225,9 +238,7 @@ class StreamWindow:
                 f"(window {self.tag!r}, key {key!r})") from e
         self.htod_bytes += nbytes
         self.demand += 1
-        t0 = time.perf_counter()
-        ok = self._wait_ready(value)
-        self.wait_s += time.perf_counter() - t0
+        ok = self._wait(value, key, nbytes, True)
         if not ok:
             raise faults.StreamTimeoutError(
                 f"stream transfer stalled twice (watchdog "
@@ -486,7 +497,7 @@ class ParamStore:
     # -- streaming -------------------------------------------------------
     # window-facing views kept for callers/tests that inspect the store
     @property
-    def _inflight(self) -> Dict[int, Dict[str, Dict]]:
+    def _inflight(self) -> Dict[int, Tuple[Dict[str, Dict], int]]:
         return self._window.inflight
 
     @property

@@ -109,7 +109,7 @@ def test_mode_a_table_is_bookkeeping_only():
     pt = KVPageTable(cfg, _schema(cfg), B, S + DEC, CacheConfig(page_tokens=8))
     assert pt.fully_resident
     assert not pt.pool_k and not pt.host_k        # no pools materialized
-    assert pt.take_counters() == (0, 0, 0.0)
+    assert pt.take_counters() == (0, 0)
 
 
 # ---------------------------------------------------------------------------
