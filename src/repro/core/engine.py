@@ -1263,11 +1263,12 @@ class ModuleBatchingEngine:
         scheduler (each slot decodes at its own sequence position).
 
         Streamed layers pipeline with compute: layer *l+1*'s weight
-        prefetch is issued after layer *l*'s mixer and before its FFN /
-        grouped-GEMM launch, so the htod copy rides the async dispatch
-        queue behind the step's heaviest compute.  (The fused one-launch
-        path lives in ``decode_chunk``; this method is the per-module
-        oracle and the streamed/loop execution path.)
+        prefetch is issued as soon as ``acquire(l)`` returns, before layer
+        *l*'s mixer stage, so the htod copy is in flight while the host
+        dispatches *l*'s attention / SSM and FFN / grouped-GEMM launches
+        (the last layer's wraps to layer 0 for the next step).  (The
+        fused one-launch path lives in ``decode_chunk``; this method is
+        the per-module oracle and the streamed/loop execution path.)
         """
         stale = self._stale_snapshot()
         with sanitizer.allowed("decode-inputs"):
@@ -1312,6 +1313,7 @@ class ModuleBatchingEngine:
         predictive = (ffn == "moe" and self.expert_path == "grouped"
                       and self.store.streams_experts(li))
         p = self.store.acquire(li, experts=not predictive)
+        self.store.prefetch(li + 1)     # l+1's copy rides under l's stages
         if kind == "attn":
             x = x + self._attention_stage(li, p, x, pos, row0, pos_host)
         else:
@@ -1320,7 +1322,6 @@ class ModuleBatchingEngine:
             )
             self.cache[li] = {"h": h, "conv": conv}
             x = x + y
-        self.store.prefetch(li + 1)     # before the FFN/grouped launch
         if self.pages is not None:
             self.pages.prefetch(li + 1)  # next layer's host KV frames
         if ffn == "moe":
@@ -1355,21 +1356,26 @@ class ModuleBatchingEngine:
         outs = []
         b_a = max(1, min(plan.b_a, n))
         k, v = self.cache[li]["k"], self.cache[li]["v"]
+        bounds = []
         lo, end = row0, row0 + n
         while lo < end:
             hi = min(end, lo + b_a)
             if lo < n_host < hi:
                 hi = n_host                    # split the straddling batch
+            bounds.append((lo, hi))
+            lo = hi
+        # one split at static bounds: eager basic slicing would upload
+        # each micro-batch's start index, and on the chip that upload
+        # waits behind the next layer's weight copy in flight
+        sizes = [hi - lo for lo, hi in bounds]
+        mb_xs = jax.lax.split(x, sizes)
+        mb_ps = ([pos] * len(sizes) if pos.ndim == 0
+                 else jax.lax.split(pos, sizes))
+        for (lo, hi), mb_x, mb_pos in zip(bounds, mb_xs, mb_ps):
             fn = (
                 _attn_decode_host_module if hi <= n_host
                 else _attn_decode_module
             )
-            # eager basic slicing uploads its start indices as int32
-            # scalars (jax dispatches slice_p as dynamic_slice) — a
-            # planned, bounded per-micro-batch transfer
-            with sanitizer.allowed("decode-row-slice"):
-                mb_x = x[lo - row0:hi - row0]
-                mb_pos = pos if pos.ndim == 0 else pos[lo - row0:hi - row0]
             y, k, v = fn(cfg, lo, p, mb_x, k, v, mb_pos)
             outs.append(y)
             self.stats.attn_microbatches += 1
@@ -1377,7 +1383,6 @@ class ModuleBatchingEngine:
                 self.stats.host_attn_tokens += hi - lo
             else:
                 self.stats.device_attn_tokens += hi - lo
-            lo = hi
         self.cache[li]["k"], self.cache[li]["v"] = k, v
         return jnp.concatenate(outs, axis=0)
 

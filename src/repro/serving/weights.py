@@ -14,11 +14,13 @@ device-ward on an htod channel that hides behind the grouped expert GEMM.
 * the **streamed set** is kept host-side (numpy — the pinned-host analogue
   on this backend) and served through a bounded in-flight window of
   ``prefetch_depth`` per-layer modules (the double buffer ``Plan.s_expert``
-  sizes): the engine issues ``prefetch(l+1)`` before launching layer *l*'s
-  grouped GEMM, so ``jax.device_put``'s async dispatch overlaps the copy
-  with compute; ``acquire(l)`` consumes the in-flight transfer (or fetches
-  on demand when prefetch is off — the streamed-serial baseline of the
-  ``weight_streaming`` benchmark).
+  sizes): the engine issues ``prefetch(l+1)`` as soon as ``acquire(l)``
+  returns, before layer *l*'s mixer stage, so ``jax.device_put``'s async
+  copy is in flight while the host dispatches layer *l*; ``acquire(l)``
+  consumes the in-flight transfer (or fetches on demand when prefetch is
+  off — the streamed-serial baseline of the ``weight_streaming``
+  benchmark).  At that issue point the device holds two layers' streamed
+  modules: *l*, just landed, and *l+1*, in flight.
 
 The store keeps device-side accounting (htod bytes at issue time, stall
 seconds at acquire time) that ``ModuleBatchingEngine.sync_stats`` folds
@@ -527,9 +529,12 @@ class ParamStore:
 
     def prefetch(self, li: int) -> None:
         """Stage layer ``li``'s streamed modules into the in-flight window
-        (async; returns immediately).  Call BEFORE launching the previous
-        layer's compute so the copy hides behind it.  Wraps module indices,
-        so the last layer prefetches layer 0 for the next decode step."""
+        (async; returns immediately).  Call right after ``acquire(li - 1)``
+        returns, before launching that layer's stages, so the copy hides
+        behind their dispatch and compute; never before: the window would
+        then hold a third layer.  Wraps module indices, so the last layer
+        prefetches layer 0 for the next decode step.  Returns at once when
+        layer ``li`` has no host-side modules."""
         if not self.prefetch_enabled:
             return
         li = li % len(self.schema)

@@ -120,6 +120,82 @@ def test_streamed_single_layer_model():
     assert eng.stats.weight_htod_bytes > 0
 
 
+@pytest.mark.parametrize("expert_path", ["grouped", "loop"])
+def test_decode_issues_next_copy_before_attention(expert_path, monkeypatch):
+    """Per-module streamed decode issues layer l+1's copy right after
+    ``acquire(l)`` returns and before l's attention stage; the last layer
+    wraps to layer 0.  At each issue the window holds no other layer (only
+    l, just landed, and l+1 are on device), and no acquire demand-fetches."""
+    cfg, params, toks = _setup("mixtral-8x7b")
+    L = cfg.num_layers
+    assert L == 2
+    eng = ModuleBatchingEngine(
+        cfg, params, Plan(B=B, b_a=2, b_e=B, omega=0.0), max_seq=S + DEC,
+        expert_path=expert_path, stream_weights=True, resident_bytes=0.0,
+    )
+    store = eng.store
+    store.prefetch(0)           # else prefill's first acquire demand-fetches
+    logits = eng.prefill(toks)
+    assert store.demand_fetches == 0
+
+    events, inflight_at_issue = [], []
+    acquire, prefetch = store.acquire, store.prefetch
+    attention = eng._attention_stage
+
+    def rec_acquire(li, *a, **kw):
+        out = acquire(li, *a, **kw)
+        events.append(("acquire", li))
+        return out
+
+    def rec_prefetch(li):
+        events.append(("prefetch", li))
+        inflight_at_issue.append(sorted(store._inflight))
+        prefetch(li)
+
+    def rec_attention(li, *a, **kw):
+        events.append(("attention", li))
+        return attention(li, *a, **kw)
+
+    monkeypatch.setattr(store, "acquire", rec_acquire)
+    monkeypatch.setattr(store, "prefetch", rec_prefetch)
+    monkeypatch.setattr(eng, "_attention_stage", rec_attention)
+
+    for t in range(3):
+        events.clear()
+        inflight_at_issue.clear()
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        logits = eng.decode_step(tok, S + t)
+        assert [e for e in events if e[0] == "acquire"] == [
+            ("acquire", li) for li in range(L)]
+        for li in range(L):
+            a = events.index(("acquire", li))
+            p = events.index(("prefetch", li + 1))   # wraps to layer 0
+            assert a < p < events.index(("attention", li)), events
+        assert inflight_at_issue == [[]] * L
+        assert sorted(store._inflight) == [0]        # layer 0, next step's
+        assert store.demand_fetches == 0
+
+
+@pytest.mark.parametrize("omega", [0.0, 0.5])
+@pytest.mark.parametrize("vector_pos", [False, True])
+def test_attention_stage_uploads_nothing(omega, vector_pos):
+    """The attention stage cuts its micro-batches at static bounds, so no
+    start index goes host-to-device: on the chip such an upload waits
+    behind the next layer's weight copy, which is then in flight."""
+    cfg, params, toks = _setup("mixtral-8x7b")
+    eng = ModuleBatchingEngine(
+        cfg, params, Plan(B=B, b_a=3, b_e=B, omega=omega), max_seq=S + DEC,
+    )
+    eng.prefill(toks)
+    x = jnp.ones((B, cfg.d_model), jnp.bfloat16)
+    pos = jnp.full((B,), S, jnp.int32) if vector_pos else jnp.asarray(S)
+    p = eng.store.acquire(0)
+    with jax.transfer_guard_host_to_device("disallow"):
+        y = eng._attention_stage(0, p, x, pos)
+    assert y.shape == x.shape
+    assert eng.stats.host_attn_tokens == round(omega * B)
+
+
 # ---------------------------------------------------------------------------
 # ParamStore unit behavior
 # ---------------------------------------------------------------------------
