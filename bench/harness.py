@@ -14,9 +14,13 @@ first step (the first wave's prefill and first decode).  The window then
 steps the server for ``seconds`` and ends with the step that crosses it;
 its rate is taken over whole steps.  After the window, the device's peak
 memory is read, the program's state is freed, and the reference judges a
-sample of the served requests (``bench/reference.py``): the check is the
-mean over the judged requests of the share of each one's served tokens
-that lie more than TAU logits below the reference's first choice.
+sample of the served requests: the check is the mean over the judged
+requests of the share of each one's served tokens that lie more than TAU
+logits below the reference's first choice.
+
+Whatever knows the architecture (its shapes, weights, reference and
+work counts) comes from the cell's model module, ``cell.model``
+(``bench/manifest.py``); nothing here names an architecture.
 """
 from __future__ import annotations
 
@@ -29,10 +33,9 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from bench import flops, reference, traffic
+from bench import traffic
 from bench.compile_clock import CompileClock
 from bench.peaks import peaks
-from bench.weights import Dims, host_params, resident_params
 
 # The Pallas expert kernel's name in the device trace.
 KERNELS = ("expert_ffn",)
@@ -44,25 +47,15 @@ TAU = 0.1                  # a served token this many logits below the
 TAUS = (0.0, 0.03, 0.1, 0.3)   # the readings kept beside the check's
 
 
-def program_config(config: Dict):
+def program_config(model, dims, config: Dict):
     """The program's own ModelConfig for a configuration file: its
-    registry architecture with the file's overrides, checked against the
-    published widths the reference reads."""
+    registry architecture with the file's overrides, checked by the model
+    module against the published widths the reference reads."""
     from repro.configs import get_config
 
     prog = config["program"]
     cfg = replace(get_config(prog["arch"]), **prog.get("overrides", {}))
-    d = Dims.of(config)
-    have = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
-            cfg.head_dim, cfg.moe_d_ff, cfg.num_experts,
-            cfg.experts_per_token, cfg.vocab_size, cfg.norm_eps,
-            cfg.rope_theta, cfg.tie_embeddings)
-    want = (d.layers, d.d, d.heads, d.kv_heads, d.head_dim, d.d_ff,
-            d.experts, d.top_k, d.vocab, d.eps, d.theta,
-            bool(config.get("tie_word_embeddings", False)))
-    if have != want:
-        raise ValueError(f"program config {have} differs from the "
-                         f"published {want}")
+    model.check_program(cfg, dims, config)
     return cfg
 
 
@@ -220,15 +213,17 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t0: float,
     clock = CompileClock(jax)
     dev = jax.devices()[0]
     tpu = dev.platform == "tpu"
-    dims = Dims.of(cell.config)
-    cfg = program_config(cell.config)
+    model = cell.model
+    dims = model.dims(cell.config)
+    cfg = program_config(model, dims, cell.config)
     serving, mix = cell.config["serving"], cell.traffic
     B = int(serving["batch"])
     reqs = traffic.generate(mix, seed, dims.vocab, B)
     waves = [reqs[i:i + B] for i in range(0, len(reqs), B)]
     offload = serving["residency"] == "offload"
     marks = {"start": time.perf_counter() - t0}
-    params = (host_params if offload else resident_params)(seed, dims)
+    params = (model.host_params if offload else model.resident_params)(
+        seed, dims)
     marks["weights"] = time.perf_counter() - t0
     hw = profile_for_device(dev) if tpu else PROFILES["tpu-v5e"]
     median_out = int(np.median([r.output_len for r in waves[0]]))
@@ -299,9 +294,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t0: float,
         "experts": dims.experts, "top_k": dims.top_k,
         "delta": {k: after[k] - before[k] for k in before},
         "trace": cap.result if cap is not None else None,
-        "kernel": KERNELS[0],
+        "kernel": KERNELS[0], "expert_ffn_work": model.expert_ffn_work,
         "peaks": peaks(dev.device_kind) if tpu else None,
-        "work_flops": window_flops(dims, handles, served, tok0),
+        "work_flops": window_flops(model, dims, handles, served, tok0),
     }
 
     # -- the check, on the program's state freed ---------------------------
@@ -312,8 +307,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t0: float,
     del server, handles
     gc.collect()
     t_ref = time.perf_counter()
-    gaps, ctl = (reference.judge(dims, seed, judged, int(mix["max_seq"]),
-                                 control) if judged else ([], []))
+    gaps, ctl = (model.judge(dims, seed, judged, int(mix["max_seq"]),
+                             control) if judged else ([], []))
     readings = {"program": gap_stats(gaps)}
     if control:
         readings["control"] = gap_stats(ctl)
@@ -384,10 +379,10 @@ def gap_stats(gaps: List[np.ndarray]) -> Dict:
             "max": float(flat.max())}
 
 
-def window_flops(dims: Dims, handles, served, tok0) -> int:
-    """Model FLOPs of the work the window completed: the unpadded prompts
-    it prefilled and every token it generated by decode, each at the
-    context it attended."""
+def window_flops(model, dims, handles, served, tok0) -> int:
+    """Model FLOPs of the work the window completed, by ``model``'s counts:
+    the unpadded prompts it prefilled and every token it generated by
+    decode, each at the context it attended."""
     total = 0
     for h in handles:
         n_prompt = len(h.prompt)
@@ -395,8 +390,8 @@ def window_flops(dims: Dims, handles, served, tok0) -> int:
         if a == b:
             continue
         if a == 0:                 # admitted inside the window
-            total += flops.prefill_flops(dims, n_prompt)
+            total += model.prefill_flops(dims, n_prompt)
             a = 1
         for i in range(a, b):      # token i was fed token i-1's position
-            total += flops.decode_flops(dims, n_prompt + i - 1)
+            total += model.decode_flops(dims, n_prompt + i - 1)
     return total

@@ -7,10 +7,30 @@ per-layer metric sits in a file of its own:
 * a traffic mix: ``<bench>/traffic/<traffic>.json``;
 * a per-layer metric: ``<bench>/metrics/<name>.py``, whose
   ``read(ctx) -> float | None`` takes the number from the run's counters
-  or its reduced trace and returns None where it finds nothing to read.
+  or its reduced trace and returns None where it finds nothing to read;
+* an architecture: ``<bench>/models/<arch>.py``, where ``<arch>`` is the
+  configuration file's ``architectures[0]``.  Everything that knows the
+  architecture's shapes and mathematics is there, and nowhere else:
 
-A later cell, configuration or metric is added by adding such files and
-manifest entries; nothing here names one.
+  - ``dims(config)``: a hashable shape object.  The harness, the traffic
+    generator and the readers use only its ``layers``, ``vocab``,
+    ``experts`` and ``top_k``; every other field is the module's own.
+  - ``check_program(cfg, dims, config)``: raises unless the program's
+    ModelConfig ``cfg`` serves the published widths.
+  - ``host_params(seed, dims)``, ``resident_params(seed, dims)``: the
+    weights drawn from the seed, in the program's parameter tree, with
+    every layer in host memory or on the device.
+  - ``judge(dims, seed, judged, max_seq, control)``: for each judged
+    ``(prompt, served tokens)``, the gap of every served token below the
+    float32 reference's best logit; with ``control``, also the gaps of
+    the float8 control's choices at the same positions.
+  - ``prefill_flops(dims, n)``, ``decode_flops(dims, pos)``: the model
+    FLOPs of a prompt of ``n`` tokens and of one token fed at ``pos``.
+  - ``expert_ffn_work(dims, copies, experts_hit)``: (FLOPs, bytes) of one
+    grouped expert FFN call, for the kernel's roofline.
+
+A later cell, configuration, architecture or metric is added by adding
+such files and manifest entries; nothing here names one.
 """
 from __future__ import annotations
 
@@ -18,7 +38,9 @@ import importlib.util
 import json
 import os
 import re
+import sys
 from dataclasses import dataclass
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}")
@@ -37,6 +59,7 @@ class Cell:
     end_to_end: List[Dict]  # manifest entries of the metrics it reports
     per_layer: List[Dict]
     readers: Dict[str, Callable]   # per-layer metric name -> read(ctx)
+    model: ModuleType       # the architecture's model module
 
 
 def load_json(path: str) -> Dict:
@@ -50,15 +73,32 @@ def reports(metric: Dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def load_module(name: str, path: str) -> ModuleType:
+    """The Python file ``path``, run as module ``name``.  It is entered in
+    ``sys.modules`` first, as an import would, so that what it defines
+    (a dataclass, say) can find its own module."""
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no file {path}")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(bench_dir: str, name: str) -> Callable:
     path = os.path.join(bench_dir, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    if spec is None or spec.loader is None:
-        raise FileNotFoundError(path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(f"bench_metric_{name}", path).read
+
+
+def model_file(bench_dir: str, arch: str) -> str:
+    return os.path.join(bench_dir, "models", f"{arch}.py")
+
+
+def load_model(bench_dir: str, arch: str) -> ModuleType:
+    """The model module of architecture ``arch`` (a configuration's
+    ``architectures[0]``)."""
+    return load_module(f"bench_model_{arch}", model_file(bench_dir, arch))
 
 
 def resolve(manifest: Dict, workload: str, root: str,
@@ -82,13 +122,14 @@ def resolve(manifest: Dict, workload: str, root: str,
              and any(e["name"] == m["moves"] for e in e2e)]
     readers = {m["name"]: load_reader(bench_dir, m["name"]) for m in layer}
     return Cell(workload, int(w["chips"]), config, traffic, e2e, layer,
-                readers)
+                readers, load_model(bench_dir, config["architectures"][0]))
 
 
 def problems(manifest: Dict, root: str) -> List[str]:
     """What in ``manifest`` breaks the benchmark's rules of form (names,
-    units, sources, files, and each per-layer metric's cells reporting
-    the end-to-end metric it moves); empty when it is sound."""
+    units, sources, files, model modules, and each per-layer metric's
+    cells reporting the end-to-end metric it moves); empty when it is
+    sound."""
     out: List[str] = []
     cells = {w["name"]: w for w in manifest.get("workloads", [])}
     configs = {c["name"]: c for c in manifest.get("configs", [])}
@@ -154,8 +195,17 @@ def problems(manifest: Dict, root: str) -> List[str]:
     for c in configs.values():
         if c["name"] not in used:
             out.append(f"config {c['name']} has no cell")
-        if not os.path.exists(os.path.join(root, c["file"])):
+        path = os.path.join(root, c["file"])
+        if not os.path.exists(path):
             out.append(f"config {c['name']}: no file {c['file']}")
+        else:
+            arch = (load_json(path).get("architectures") or [""])[0]
+            model = model_file(os.path.join(root, "bench"), arch)
+            if not NAME.fullmatch(arch):
+                out.append(f"config {c['name']}: architecture {arch!r}")
+            elif not os.path.isfile(model):
+                out.append(f"config {c['name']}: no model module "
+                           f"{os.path.relpath(model, root)}")
         for k in c.get("reduced", []):
             if not NAME.fullmatch(k):
                 out.append(f"config {c['name']}: reduced key {k!r}")

@@ -1,13 +1,11 @@
 """Kernel: the Pallas ``expert_ffn`` kernel's decode calls, as a share of
 their roofline.  The least time the chip could take is the larger of the
 FLOPs over the bf16 peak and the bytes over the HBM bandwidth, for the
-work the routed copies need (``bench/flops.expert_ffn_work``: every row
-of the batch routed to ``top_k`` experts, each hit expert's weights read
-once, each copy's row in and out); the time is the kernel's device time
-in the trace, outside the prefill programs.  Capacity rows and padding
-never enter the work."""
-
-from bench.flops import expert_ffn_work
+work the routed copies need (the model module's ``expert_ffn_work``,
+given in ``ctx``: every row of the batch routed to ``top_k`` experts,
+each hit expert's weights read once, each copy's row in and out); the
+time is the kernel's device time in the trace, outside the prefill
+programs.  Capacity rows and padding never enter the work."""
 
 
 def read(ctx):
@@ -22,7 +20,7 @@ def read(ctx):
     if not calls or secs <= 0:
         return None
     copies = ctx["batch"] * ctx["top_k"]
-    fl, by = expert_ffn_work(ctx["dims"], copies,
-                             min(ctx["experts"], copies))
+    fl, by = ctx["expert_ffn_work"](ctx["dims"], copies,
+                                    min(ctx["experts"], copies))
     t_min = calls * max(fl / pk["bf16_flops"], by / pk["hbm_bytes_per_s"])
     return 100.0 * t_min / secs

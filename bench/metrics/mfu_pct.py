@@ -1,7 +1,8 @@
 """Whole step: model FLOPs of the work the window completed (the unpadded
 prompts it prefilled, each generated token at the context it attended;
-``bench/flops.py``) over the window's seconds, as a share of the chip's
-bf16 peak (``bench/peaks.py``)."""
+the model module's ``prefill_flops`` and ``decode_flops``) over the
+window's seconds, as a share of the chip's bf16 peak
+(``bench/peaks.py``)."""
 
 
 def read(ctx):

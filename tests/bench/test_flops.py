@@ -3,13 +3,11 @@ import os
 
 import pytest
 
-from bench import flops
-from bench.weights import Dims
-from tiny_bench import ROOT
+from tiny_bench import MIXTRAL, ROOT
 
-MIXTRAL_4L = Dims(layers=4, d=4096, heads=32, kv_heads=8, head_dim=128,
-                  d_ff=14336, experts=8, top_k=2, vocab=32000, eps=1e-5,
-                  theta=1e6)
+MIXTRAL_4L = MIXTRAL.Dims(layers=4, d=4096, heads=32, kv_heads=8,
+                          head_dim=128, d_ff=14336, experts=8, top_k=2,
+                          vocab=32000, eps=1e-5, theta=1e6)
 
 
 def reader(name):
@@ -27,26 +25,26 @@ def test_mixtral_token_is_twice_its_active_parameters():
                  + 2 * 3 * 4096 * 14336)
     active = 4 * per_layer + 4096 * 32000
     assert active == 1_708_261_376
-    no_context = flops.decode_flops(MIXTRAL_4L, 0) - 4 * flops.attention_flops(
-        MIXTRAL_4L, 1)
+    no_context = (MIXTRAL.decode_flops(MIXTRAL_4L, 0)
+                  - 4 * MIXTRAL.attention_flops(MIXTRAL_4L, 1))
     assert no_context == 2 * active == 3_416_522_752
 
 
 def test_attention_grows_with_the_context():
-    d0 = flops.decode_flops(MIXTRAL_4L, 0)
-    d9 = flops.decode_flops(MIXTRAL_4L, 1023)
+    d0 = MIXTRAL.decode_flops(MIXTRAL_4L, 0)
+    d9 = MIXTRAL.decode_flops(MIXTRAL_4L, 1023)
     assert d9 - d0 == 4 * 4 * 1023 * 32 * 128
 
 
 def test_prefill_is_its_tokens_and_one_head():
     n = 300
-    per = [flops.token_flops(MIXTRAL_4L, p + 1) for p in range(n)]
-    assert flops.prefill_flops(MIXTRAL_4L, n) == sum(per) + flops.head_flops(
-        MIXTRAL_4L)
+    per = [MIXTRAL.token_flops(MIXTRAL_4L, p + 1) for p in range(n)]
+    assert MIXTRAL.prefill_flops(MIXTRAL_4L, n) == (
+        sum(per) + MIXTRAL.head_flops(MIXTRAL_4L))
 
 
 def test_expert_ffn_work_counts_routed_copies_only():
-    fl, by = flops.expert_ffn_work(MIXTRAL_4L, copies=128, experts_hit=8)
+    fl, by = MIXTRAL.expert_ffn_work(MIXTRAL_4L, copies=128, experts_hit=8)
     assert fl == 128 * 6 * 4096 * 14336
     assert by == 8 * 3 * 4096 * 14336 * 2 + 128 * 2 * 4096 * 2
 
@@ -64,6 +62,7 @@ def _trace(kernel_s, calls, module="jit__fused_decode_chunk"):
 def _ctx(batch, capacity, trace=None, **kw):
     ctx = {"dims": MIXTRAL_4L, "batch": batch, "capacity": capacity,
            "experts": 8, "top_k": 2, "kernel": "expert_ffn",
+           "expert_ffn_work": MIXTRAL.expert_ffn_work,
            "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
            "trace": trace, "chips": 1}
     ctx.update(kw)
@@ -78,7 +77,7 @@ def test_roofline_ignores_capacity_and_padding():
     a = read(_ctx(64, 64, tr))
     b = read(_ctx(64, 4096, tr))
     assert a == b
-    fl, by = flops.expert_ffn_work(MIXTRAL_4L, 128, 8)
+    fl, by = MIXTRAL.expert_ffn_work(MIXTRAL_4L, 128, 8)
     t_min = max(fl / 197e12, by / 819e9)
     assert a == pytest.approx(100 * 2 * t_min / 0.01)
     assert by / 819e9 > fl / 197e12          # decode is bound by bytes
@@ -102,11 +101,11 @@ def test_mfu_counts_the_work_not_the_padding():
     hs = [H(0, 100), H(1, 1000)]
     served = {0: [1] * 5, 1: [1] * 3}
     tok0 = {0: 0, 1: 1}
-    got = window_flops(MIXTRAL_4L, hs, served, tok0)
-    want = (flops.prefill_flops(MIXTRAL_4L, 100)
-            + sum(flops.decode_flops(MIXTRAL_4L, 100 + i - 1)
+    got = window_flops(MIXTRAL, MIXTRAL_4L, hs, served, tok0)
+    want = (MIXTRAL.prefill_flops(MIXTRAL_4L, 100)
+            + sum(MIXTRAL.decode_flops(MIXTRAL_4L, 100 + i - 1)
                   for i in range(1, 5))
-            + sum(flops.decode_flops(MIXTRAL_4L, 1000 + i - 1)
+            + sum(MIXTRAL.decode_flops(MIXTRAL_4L, 1000 + i - 1)
                   for i in range(1, 3)))
     assert got == want
     read = reader("mfu_pct")

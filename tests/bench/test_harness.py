@@ -3,6 +3,7 @@ decides ``correct`` against faults planted in the timed path, and the
 control, which has to fail the check."""
 import copy
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -12,7 +13,7 @@ import numpy as np
 import pytest
 
 from bench import faults, harness, manifest
-from tiny_bench import ROOT, TINY_CONFIG, TINY_MIX, write_bench
+from tiny_bench import MIXTRAL, ROOT, TINY_CONFIG, TINY_MIX, write_bench
 
 LIMIT = TINY_CONFIG["limits"]["mismatch_share"]
 
@@ -23,7 +24,7 @@ def tiny_cell(**serving):
     return manifest.Cell(
         "tiny.waves", 1, config, TINY_MIX,
         [{"name": "gen_tok_s", "unit": "tokens/s"},
-         {"name": "setup_s", "unit": "s"}], [], {})
+         {"name": "setup_s", "unit": "s"}], [], {}, MIXTRAL)
 
 
 def run(cell, seed=3, fault=None, tmp="/nonexistent"):
@@ -72,14 +73,36 @@ def test_tiny_cell_serves_correctly(residency, sound):
 
 
 def test_a_cell_added_by_files_runs(tmp_path):
-    write_bench(tmp_path)
+    """A cell whose architecture, too, is new: its model module exists
+    only in the checkout (a copy of the Mixtral module under another
+    name, serving the program's mixtral-8x7b), and the cell is served and
+    judged through it."""
+    config = copy.deepcopy(TINY_CONFIG)
+    config["architectures"] = ["TinyMixForCausalLM"]
+    write_bench(tmp_path, config)
     m = manifest.load_json(str(tmp_path / "BENCHMARK.json"))
+    assert manifest.problems(m, str(tmp_path)) == []
     cell = manifest.resolve(m, "tiny.waves", str(tmp_path))
+    assert cell.model.__file__ == str(
+        tmp_path / "bench" / "models" / "TinyMixForCausalLM.py")
     out = harness.run_cell(cell, 4, 1.0, True, time.perf_counter(),
                            str(tmp_path))
     assert out["correct"], out["checks"]
     assert 0 < out["metrics"]["occupancy_pct"]["value"] <= 100
     assert out["device"]["window_s"] > 0
+
+
+def test_a_configuration_without_its_model_module_is_named(tmp_path):
+    config = copy.deepcopy(TINY_CONFIG)
+    config["architectures"] = ["NoSuchForCausalLM"]
+    write_bench(tmp_path, config, models={})
+    m = manifest.load_json(str(tmp_path / "BENCHMARK.json"))
+    path = os.path.join("bench", "models", "NoSuchForCausalLM.py")
+    assert any("no model module" in p and path in p
+               for p in manifest.problems(m, str(tmp_path)))
+    with pytest.raises(FileNotFoundError,
+                       match=re.escape(str(tmp_path / path))):
+        manifest.resolve(m, "tiny.waves", str(tmp_path))
 
 
 @pytest.mark.parametrize("fault", [faults.altered_token,

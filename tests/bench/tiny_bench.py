@@ -2,12 +2,17 @@
 import json
 import os
 
+from bench.manifest import load_model, model_file
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+MIXTRAL_FILE = model_file(os.path.join(ROOT, "bench"), "MixtralForCausalLM")
+MIXTRAL = load_model(os.path.join(ROOT, "bench"), "MixtralForCausalLM")
 
 # A Mixtral-shaped model small enough for the CPU: every mechanism of the
 # benchmark's configurations (GQA, RoPE, top-2 of 4 experts, untied head).
 TINY_CONFIG = {
+    "architectures": ["MixtralForCausalLM"],
     "hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
     "num_key_value_heads": 2, "num_hidden_layers": 2,
     "num_local_experts": 4, "num_experts_per_tok": 2, "vocab_size": 256,
@@ -30,10 +35,16 @@ TINY_MIX = {
 }
 
 
-def write_bench(tmp, config=TINY_CONFIG, mix=TINY_MIX, readers=None):
+def write_bench(tmp, config=TINY_CONFIG, mix=TINY_MIX, readers=None,
+                models=None):
     """A checkout holding one tiny cell, laid out as the benchmark is:
-    a manifest, a configuration file, a traffic mix and metric readers,
-    each found by its name."""
+    a manifest, a configuration file, a traffic mix, metric readers and
+    model modules (``models``: architecture -> source; by default the
+    configuration's architecture as a copy of the Mixtral module), each
+    found by its name."""
+    if models is None:
+        with open(MIXTRAL_FILE) as f:
+            models = {config["architectures"][0]: f.read()}
     readers = readers or {
         "occupancy_pct": "def read(ctx):\n"
                          "    d = ctx['delta']\n"
@@ -42,10 +53,13 @@ def write_bench(tmp, config=TINY_CONFIG, mix=TINY_MIX, readers=None):
     os.makedirs(tmp / "bench" / "configs")
     os.makedirs(tmp / "bench" / "traffic")
     os.makedirs(tmp / "bench" / "metrics")
+    os.makedirs(tmp / "bench" / "models")
     (tmp / "bench" / "configs" / "tiny.json").write_text(json.dumps(config))
     (tmp / "bench" / "traffic" / "waves.json").write_text(json.dumps(mix))
     for name, src in readers.items():
         (tmp / "bench" / "metrics" / f"{name}.py").write_text(src)
+    for arch, src in models.items():
+        (tmp / "bench" / "models" / f"{arch}.py").write_text(src)
     manifest = {
         "command": ["python3", "bench/run.py"], "paths": ["bench"],
         "run_seconds": 10,
