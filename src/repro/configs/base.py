@@ -36,6 +36,20 @@ class ModelConfig:
     sliding_window: int = 0             # 0 => full attention
     rope_theta: float = 10_000.0
 
+    # --- multi-head latent attention (DeepSeek-V2 MLA) ---
+    kv_lora_rank: int = 0               # 0 => no MLA; else the latent width
+    qk_nope_head_dim: int = 0           # per-head query/key dims without rope
+    qk_rope_head_dim: int = 0           # per-head rope dims (one shared key)
+    v_head_dim: int = 0
+
+    # --- yarn RoPE scaling (0 => plain RoPE) ---
+    yarn_factor: float = 0.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+
     # --- dense FFN ---
     d_ff: int = 0                       # 0 => no dense FFN (pure-MoE / pure-SSM layer)
 
@@ -46,6 +60,8 @@ class ModelConfig:
     num_shared_experts: int = 0         # DeepSeek/Qwen-style always-on experts
     capacity_factor: float = 1.25
     moe_layer_period: int = 1           # MoE every Nth layer (jamba: 2)
+    first_k_dense: int = 0              # leading layers with a dense FFN
+    norm_topk_prob: bool = True         # renormalise the top-k gates
 
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0                  # d_state; 0 => no SSM layers
@@ -81,6 +97,16 @@ class ModelConfig:
         return self.num_heads > 0
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def mla_cache_width(self) -> int:
+        """Values one token caches per MLA layer: the normalised latent and
+        the shared rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
     def has_moe(self) -> bool:
         return self.num_experts > 0
 
@@ -98,7 +124,9 @@ class ModelConfig:
         return self.sliding_window > 0
 
     def layer_kind(self, i: int) -> str:
-        """'attn' or 'ssm' for layer i (hybrid interleave)."""
+        """'attn', 'mla' or 'ssm' for layer i (hybrid interleave)."""
+        if self.mla:
+            return "mla"
         if not self.has_ssm:
             return "attn"
         if not self.has_attention:
@@ -107,8 +135,10 @@ class ModelConfig:
         return "attn" if (i % self.attn_period) == self.attn_offset else "ssm"
 
     def ffn_kind(self, i: int) -> str:
-        """'moe' or 'dense' for the FFN of layer i."""
-        if not self.has_moe:
+        """'moe' or 'dense' for the FFN of layer i: the leading
+        ``first_k_dense`` layers are dense, then MoE every
+        ``moe_layer_period``-th layer."""
+        if not self.has_moe or i < self.first_k_dense:
             return "dense"
         if (i % self.moe_layer_period) == (self.moe_layer_period - 1):
             return "moe"
@@ -121,7 +151,13 @@ class ModelConfig:
         attn = moe = dense = ssm = norm = 0
         for i in range(self.num_layers):
             norm += 2 * d
-            if self.layer_kind(i) == "attn":
+            if self.layer_kind(i) == "mla":
+                h, r = self.num_heads, self.kv_lora_rank
+                attn += (d * h * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+                         + d * (r + self.qk_rope_head_dim) + r
+                         + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                         + h * self.v_head_dim * d)
+            elif self.layer_kind(i) == "attn":
                 q = self.num_heads * self.head_dim
                 kv = self.num_kv_heads * self.head_dim
                 attn += d * q + 2 * d * kv + q * d
@@ -191,6 +227,8 @@ ARCH_IDS = [
     "phi3.5-moe-42b-a6.6b",
     # the paper's own evaluation model family
     "mixtral-8x7b",
+    # latent attention, fine-grained + shared experts, a leading dense layer
+    "deepseek-v2-lite",
 ]
 
 

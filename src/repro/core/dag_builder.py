@@ -105,7 +105,7 @@ def _miss_fractions(cfg: ModelConfig, plan: Plan) -> Dict[str, float]:
         return sum(not f for f in flags) / len(flags) / reuse
 
     attn_f = [rp.mixer_resident[i] for i in range(cfg.num_layers)
-              if cfg.layer_kind(i) == "attn"]
+              if W.is_attention(cfg, i)]
     ssm_f = [rp.mixer_resident[i] for i in range(cfg.num_layers)
              if cfg.layer_kind(i) == "ssm"]
     moe_f = [rp.ffn_resident[i] for i in range(cfg.num_layers)
@@ -415,7 +415,10 @@ def build_prefill_layer_dag(
 def _layer_types(cfg: ModelConfig) -> Dict[Tuple[str, str], int]:
     types: Dict[Tuple[str, str], int] = {}
     for i in range(cfg.num_layers):
-        key = (cfg.layer_kind(i), cfg.ffn_kind(i))
+        # latent attention is costed as attention: its weight and cache
+        # bytes come from the MLA-aware workload counts
+        kind = "attn" if W.is_attention(cfg, i) else cfg.layer_kind(i)
+        key = (kind, cfg.ffn_kind(i))
         types[key] = types.get(key, 0) + 1
     return types
 

@@ -156,6 +156,22 @@ def _attn_decode_module(cfg, lo, p, x_mb, k, v, pos):
 
 
 @_counted
+@register_jit("engine.mla_decode", donated=("ckv",))
+@functools.partial(jax.jit, static_argnames=("cfg", "lo"),
+                   donate_argnames=("ckv",))
+def _mla_decode_module(cfg, lo, p, x_mb, ckv, pos):
+    """Absorbed latent-attention decode over batch rows ``[lo, lo+n)``,
+    with the layer's full latent cache DONATED and the rows written back
+    in place (the contract of ``_attn_decode_module``)."""
+    n = x_mb.shape[0]
+    h = rms_norm(x_mb[:, None, :], p["norm1"], cfg.norm_eps)
+    c = lax.dynamic_slice_in_dim(ckv, lo, n, axis=0)
+    y, cache = attn_mod.mla_decode(cfg, p["mla"], h, {"ckv": c}, pos)
+    ckv = lax.dynamic_update_slice_in_dim(ckv, cache["ckv"], lo, axis=0)
+    return y[:, 0], ckv
+
+
+@_counted
 @register_jit("engine.attn_decode_host", donated=("k", "v"))
 @functools.partial(jax.jit, static_argnames=("cfg", "lo"),
                    donate_argnames=("k", "v"))
@@ -227,7 +243,8 @@ def _expert_module(wg, wu, wd, h_chunk):
 
 def _grouped_expert_math(cfg, p, x, capacity):
     """The whole MoE stage, traceable: norm -> route -> capacity-bucketed
-    gather -> grouped FFN -> weighted scatter-add.  Returns (y, kept,
+    gather -> grouped FFN -> weighted scatter-add, plus the shared experts
+    over every row where the layer has them.  Returns (y, kept,
     dropped, load); the counters — including the (E,) per-expert routed
     histogram feeding the planner's measured-skew b_e search — stay on
     device.  Launched standalone by the per-module path
@@ -236,11 +253,14 @@ def _grouped_expert_math(cfg, p, x, capacity):
     moe = p["moe"]
     h = rms_norm(x, p["norm2"], cfg.norm_eps)
     gates, idx, _ = moe_mod.route(cfg, moe["router"], h)
-    return moe_mod.grouped_dispatch(
+    y, kept, dropped, load = moe_mod.grouped_dispatch(
         cfg, h, gates, idx,
         moe["experts_w_gate"], moe["experts_w_up"], moe["experts_w_down"],
         capacity,
     )
+    if "shared" in moe:
+        y = y + moe_mod.shared_experts(moe, h)
+    return y, kept, dropped, load
 
 
 _grouped_expert_module = _counted(
@@ -276,14 +296,28 @@ def _route_predict_module(cfg, khat, norm2_w, router_w, next_router_w, x):
 @_counted
 @register_jit("engine.grouped_expert_ffn")
 @functools.partial(jax.jit, static_argnames=("cfg", "capacity"))
-def _grouped_ffn_module(cfg, capacity, h, gates, idx, wg, wu, wd):
+def _grouped_ffn_module(cfg, capacity, h, gates, idx, wg, wu, wd,
+                        moe_pinned=None):
     """Grouped FFN over PRE-ROUTED tokens with externally assembled expert
     stacks — the second half of the predictive-streamed MoE stage.  The
     stacks carry true weights for every expert with a routed copy and the
     zeros filler elsewhere (``ParamStore.acquire_experts``), which is
     bit-identical to the full stack: an unrouted expert's capacity rows are
-    all-zero and its outputs are never gathered back."""
-    return moe_mod.grouped_dispatch(cfg, h, gates, idx, wg, wu, wd, capacity)
+    all-zero and its outputs are never gathered back.  The shared experts
+    (pinned beside the router, ``moe_pinned``) are added over every row."""
+    y, kept, dropped, load = moe_mod.grouped_dispatch(
+        cfg, h, gates, idx, wg, wu, wd, capacity)
+    if moe_pinned:
+        y = y + moe_mod.shared_experts(moe_pinned, h)
+    return y, kept, dropped, load
+
+
+@_counted
+@register_jit("engine.shared_experts")
+@jax.jit
+def _shared_module(shared, h):
+    """The shared experts over every row (the loop oracle's unit)."""
+    return moe_mod.shared_experts({"shared": shared}, h)
 
 
 @_counted
@@ -354,6 +388,9 @@ def _prefill_mixer_route_module(cfg, kind, p, x, positions, lengths):
     if kind == "attn":
         y, entry = attn_mod.attn_forward(cfg, p["attn"], h, ShardCtx(),
                                          positions, lengths)
+    elif kind == "mla":
+        y, entry = attn_mod.mla_forward(cfg, p["mla"], h, ShardCtx(),
+                                        positions, lengths)
     else:
         y, entry = ssm_mod.ssm_forward(cfg, p["ssm"], h, ShardCtx(), lengths)
     x = x + y
@@ -372,12 +409,15 @@ def _prefill_moe_ffn_module(cfg, capacity, moe_p, x, xt, gates, idx):
     pow2-bucketed ``capacity``.  Same dispatch math as ``moe_apply_grouped``
     (route happened in the mixer half), so the residual-added output is
     bit-identical to the unsplit layer whenever no copy drops — guaranteed
-    by capacity >= the measured max load."""
+    by capacity >= the measured max load.  The shared experts, where the
+    layer has them, run here over every row, outside the grouped kernel."""
     y, _, dropped, _ = moe_mod.grouped_dispatch(
         cfg, xt, gates, idx,
         moe_p["experts_w_gate"], moe_p["experts_w_up"],
         moe_p["experts_w_down"], capacity,
     )
+    if "shared" in moe_p:
+        y = y + moe_mod.shared_experts(moe_p, xt)
     B, S, D = x.shape
     return x + y.reshape(B, S, D).astype(x.dtype), dropped
 
@@ -594,6 +634,17 @@ def _fused_decode_chunk(cfg, schema, tie, capacity, lo, pos_cap, use_topk,
                 y, nk, nv = bar((y[:, 0], nk, nv))
                 cache[li] = {"k": nk, "v": nv}
                 x = bar(x + y)
+            elif kind == "mla":
+                c = cache[li]["ckv"]
+                h = rms_norm(x[:, None, :], p["norm1"], cfg.norm_eps)
+                cc = lax.dynamic_slice_in_dim(c, lo, n, axis=0)
+                y, upd = attn_mod.mla_decode(
+                    cfg, p["mla"], h, {"ckv": cc}, posv
+                )
+                nc = lax.dynamic_update_slice_in_dim(c, upd["ckv"], lo, 0)
+                y, nc = bar((y[:, 0], nc))
+                cache[li] = {"ckv": nc}
+                x = bar(x + y)
             else:
                 hs, cs = cache[li]["h"], cache[li]["conv"]
                 sh = lax.dynamic_slice_in_dim(hs, lo, n, axis=0)
@@ -808,7 +859,10 @@ class ModuleBatchingEngine:
                 "collective MoE stage shards whole expert stacks across "
                 "the model axis and cannot stream them"
             )
-        self.schema = store.schema                  # [(kind, ffn)] per layer
+        # [(kind, ffn)] per layer: the leading dense layers (pinned in the
+        # store's ``lead``, outside its layer index), then the store's own
+        self.n_lead = len(store.lead)
+        self.schema = store.lead_schema + store.schema
         # kept for introspection/back-compat: (kind, ffn, _) triples
         self.layers: List[Tuple[str, str, None]] = [
             (k, f, None) for k, f in self.schema
@@ -848,6 +902,20 @@ class ModuleBatchingEngine:
         # report folds it in next to the XLA compile counts)
         self._fused_params: Optional[Tuple[Dict, ...]] = None
         self._fused_keys = TraceKeySet("engine.fused_decode_chunk")
+
+    def _acquire(self, li: int, experts: bool = True) -> Dict:
+        """Layer ``li``'s parameters: a leading dense layer from the store's
+        pinned ``lead``, any later one through ``store.acquire`` (whose
+        index starts after them)."""
+        if li < self.n_lead:
+            return self.store.lead[li]
+        return self.store.acquire(li - self.n_lead, experts=experts)
+
+    def _prefetch(self, li: int) -> None:
+        """``store.prefetch`` of layer ``li`` (wrapping past the last layer
+        to the store's first); the pinned leading layers need none."""
+        if li >= self.n_lead:
+            self.store.prefetch(li - self.n_lead)
 
     def _expert_capacity(self, batch: int) -> int:
         """Per-expert capacity C: the plan's b_e (or the online re-plan
@@ -1129,30 +1197,37 @@ class ModuleBatchingEngine:
                 x = jnp.concatenate([fe.astype(x.dtype), x[:, F:]], axis=1)
             xs.append(x)
         for li, (kind, ffn) in enumerate(self.schema):
-            with span("engine.layer", layer=li, phase="prefill"):
+            with span("engine.layer", layer=li, phase="prefill",
+                      mixer=kind, ffn=ffn):
                 xs = self._prefill_layer(li, kind, ffn, xs, spans, rows,
                                          positions, lengths, lens_host, S)
         self.stats.attn_microbatches += len(spans)
-        x_full = jnp.concatenate(xs, axis=0)
+        # each micro-batch's last real position, without a copy of the
+        # whole wave's activations
         if lengths is None:
-            h_last = x_full[:, -1]
+            h_last = jnp.concatenate([x[:, -1] for x in xs], axis=0)
         else:
-            h_last = x_full[jnp.arange(n), lengths - 1]
+            h_last = jnp.concatenate(
+                [x[jnp.arange(hi - lo), lengths[lo:hi] - 1]
+                 for (lo, hi), x in zip(spans, xs)], axis=0)
         return _head_module(cfg, cfg.tie_embeddings, self.store.base, h_last)
 
     def _prefill_layer(self, li, kind, ffn, xs, spans, rows, positions,
                        lengths, lens_host, S) -> List[jax.Array]:
         """Layer ``li`` of ``prefill_slots`` over every micro-batch."""
         cfg = self.cfg
-        p = self.store.acquire(li)
-        self.store.prefetch(li + 1)     # hide l+1's copy behind this layer
+        p = self._acquire(li)
+        self._prefetch(li + 1)          # hide l+1's copy behind this layer
         # grouped-prefill MoE layers split into mixer+route / grouped-FFN
         # launches so the FFN capacity can be the next pow2 bucket over
         # the micro-batch's MEASURED max expert load instead of the full
         # token count — smaller (E, C, D) buffers, zero drops preserved
         split_moe = ffn == "moe" and self.grouped_prefill
         outs = []
-        for (lo, hi), x in zip(spans, xs):
+        for m, (lo, hi) in enumerate(spans):
+            # drop the caller's reference as the micro-batch is consumed:
+            # the wave's activations are then held once, not twice
+            x, xs[m] = xs[m], None
             ln = None if lengths is None else lengths[lo:hi]
             if split_moe:
                 x_mid, entry, xt, gates, idx, max_load, _ = (
@@ -1220,8 +1295,8 @@ class ModuleBatchingEngine:
         pos0j = jnp.asarray(pos0, jnp.int32)
         for li, (kind, ffn) in enumerate(self.schema):
             assert kind == "attn", "prefix cache requires attention-only"
-            p = self.store.acquire(li)
-            self.store.prefetch(li + 1)
+            p = self._acquire(li)
+            self._prefetch(li + 1)
             pk = jnp.asarray(prefix_kvs[li][0])[None]
             pv = jnp.asarray(prefix_kvs[li][1])[None]
             x, ks, vs = _suffix_layer_module(cfg, ffn, sctx, p, x, pk, pv,
@@ -1251,7 +1326,8 @@ class ModuleBatchingEngine:
 
     def _fused_layer_params(self) -> Tuple[Dict, ...]:
         if self._fused_params is None:
-            self._fused_params = self.store.fused_layer_params()
+            self._fused_params = (tuple(self.store.lead)
+                                  + self.store.fused_layer_params())
         return self._fused_params
 
     # -- decode -----------------------------------------------------------
@@ -1297,7 +1373,8 @@ class ModuleBatchingEngine:
                 pos_host = np.asarray(pos, np.int32)  # lint: allow[MG101] planned once-per-tick position readback for the page table
         x = _embed_module(cfg, self.store.base["embed"], tokens)
         for li, (kind, ffn) in enumerate(self.schema):
-            with span("engine.layer", layer=li, phase="decode"):
+            with span("engine.layer", layer=li, phase="decode",
+                      mixer=kind, ffn=ffn):
                 x = self._decode_layer(li, kind, ffn, x, pos, row0, pos_host)
         return _head_module(cfg, cfg.tie_embeddings, self.store.base, x)
 
@@ -1311,11 +1388,13 @@ class ModuleBatchingEngine:
         # router actually used (plus LRU hits) and prefetches the
         # predicted set for the next streamed MoE layer
         predictive = (ffn == "moe" and self.expert_path == "grouped"
-                      and self.store.streams_experts(li))
-        p = self.store.acquire(li, experts=not predictive)
-        self.store.prefetch(li + 1)     # l+1's copy rides under l's stages
+                      and self.store.streams_experts(li - self.n_lead))
+        p = self._acquire(li, experts=not predictive)
+        self._prefetch(li + 1)          # l+1's copy rides under l's stages
         if kind == "attn":
             x = x + self._attention_stage(li, p, x, pos, row0, pos_host)
+        elif kind == "mla":
+            x = x + self._mla_stage(li, p, x, pos, row0)
         else:
             y, h, conv = _ssm_decode_module(
                 cfg, row0, p, x, self.cache[li]["h"], self.cache[li]["conv"]
@@ -1384,6 +1463,29 @@ class ModuleBatchingEngine:
             else:
                 self.stats.device_attn_tokens += hi - lo
         self.cache[li]["k"], self.cache[li]["v"] = k, v
+        return jnp.concatenate(outs, axis=0)
+
+    def _mla_stage(self, li, p, x, pos, row0: int = 0) -> jax.Array:
+        """Micro-batched absorbed latent-attention decode over the layer's
+        latent cache, threaded through the donated row-block module.  Every
+        row takes the device path: the ω host-path mechanism is written
+        for per-head keys and values, not the latent cache."""
+        n = x.shape[0]
+        b_a = max(1, min(self.plan.b_a, n))
+        bounds = [(lo, min(row0 + n, lo + b_a))
+                  for lo in range(row0, row0 + n, b_a)]
+        sizes = [hi - lo for lo, hi in bounds]
+        mb_xs = jax.lax.split(x, sizes)
+        mb_ps = ([pos] * len(sizes) if pos.ndim == 0
+                 else jax.lax.split(pos, sizes))
+        ckv = self.cache[li]["ckv"]
+        outs = []
+        for (lo, hi), mb_x, mb_pos in zip(bounds, mb_xs, mb_ps):
+            y, ckv = _mla_decode_module(self.cfg, lo, p, mb_x, ckv, mb_pos)
+            outs.append(y)
+            self.stats.attn_microbatches += 1
+            self.stats.device_attn_tokens += hi - lo
+        self.cache[li]["ckv"] = ckv
         return jnp.concatenate(outs, axis=0)
 
     @hot_path
@@ -1542,7 +1644,7 @@ class ModuleBatchingEngine:
         live in the store's pinned ``moe_shared`` set, so scoring it needs
         no expert bytes."""
         streamed = [l for l in self._moe_layers
-                    if self.store.streams_experts(l)]
+                    if self.store.streams_experts(l - self.n_lead)]
         pos = streamed.index(li)
         return streamed[(pos + 1) % len(streamed)]
 
@@ -1558,12 +1660,13 @@ class ModuleBatchingEngine:
         bit-identical to the whole-stack path for any predictor."""
         cfg = self.cfg
         E = cfg.num_experts
-        shared = self.store.moe_shared(li)
         nli = self._next_streamed_moe(li)
+        si, nsi = li - self.n_lead, nli - self.n_lead   # the store's index
+        shared = self.store.moe_shared(si)
         khat = self.store.predict_topk
         h, gates, idx, packed = _route_predict_module(
             cfg, khat, shared["norm2"], shared["router"],
-            self.store.moe_shared(nli)["router"], x,
+            self.store.moe_shared(nsi)["router"], x,
         )
         with sanitizer.allowed("expert-prefetch"):
             packed_np = np.asarray(packed)  # lint: allow[MG101] ONE planned readback per predictive MoE layer: routed-copy counts + predicted ids
@@ -1572,11 +1675,12 @@ class ModuleBatchingEngine:
             pred = np.asarray(list(self.predictor(nli, khat)), np.int64)  # lint: allow[MG101] host-list coercion of the injected predictor's ids, no device buffer involved
         else:
             pred = packed_np[E:]
-        wg, wu, wd = self.store.acquire_experts(li, used)
-        self.store.prefetch_experts(nli, pred)
+        wg, wu, wd = self.store.acquire_experts(si, used)
+        self.store.prefetch_experts(nsi, pred)
         y, kept, dropped, load = _grouped_ffn_module(
             cfg, self._expert_capacity(x.shape[0]), h, gates, idx,
             wg, wu, wd,
+            {"shared": shared["shared"]} if "shared" in shared else None,
         )
         self.stats.expert_launches += 1
         j = self._moe_index[li]
@@ -1618,6 +1722,8 @@ class ModuleBatchingEngine:
                     )
                     self.stats.expert_launches += 1
                     self.stats.expert_tokens += int(r.size)
+        if "shared" in moe:
+            y = y + _shared_module(moe["shared"], h)
         return y
 
     # -- chunked decode ---------------------------------------------------
@@ -1714,7 +1820,7 @@ class ModuleBatchingEngine:
             1 for _, f in self.schema if f == "moe"
         )
         self.stats.device_attn_tokens += n * T * sum(
-            1 for k, _ in self.schema if k == "attn"
+            1 for k, _ in self.schema if k in ("attn", "mla")
         )
         if host_cols is None:
             return toks

@@ -25,7 +25,19 @@ def dtype_bytes(cfg: ModelConfig) -> int:
 # ---------------------------------------------------------------------------
 # Per-layer weight sizes
 # ---------------------------------------------------------------------------
+def is_attention(cfg: ModelConfig, i: int) -> bool:
+    """Whether layer ``i`` keeps a per-token cache (attention or latent
+    attention)."""
+    return cfg.layer_kind(i) in ("attn", "mla")
+
+
 def attn_weight_bytes(cfg: ModelConfig) -> float:
+    if cfg.mla:
+        h, r, d = cfg.num_heads, cfg.kv_lora_rank, cfg.d_model
+        nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        return (d * h * (nope + rope) + d * (r + rope) + r
+                + r * h * (nope + cfg.v_head_dim)
+                + h * cfg.v_head_dim * d) * BYTES
     q = cfg.num_heads * cfg.head_dim
     kv = cfg.num_kv_heads * cfg.head_dim
     return (cfg.d_model * q + 2 * cfg.d_model * kv + q * cfg.d_model) * BYTES
@@ -52,12 +64,14 @@ def dense_ffn_weight_bytes(cfg: ModelConfig) -> float:
 
 
 def moe_layer_weight_bytes(cfg: ModelConfig) -> float:
-    """One MoE layer's streamable FFN weights: all expert stacks + the
-    router (stored f32).  This is the unit the streamed store fetches —
-    the grouped GEMM needs every expert of the layer at once."""
+    """One MoE layer's streamable FFN weights: all expert stacks, the
+    shared experts and the router (stored f32).  This is the unit the
+    streamed store fetches — the grouped GEMM needs every expert of the
+    layer at once."""
     if not cfg.has_moe:
         return 0.0
-    return cfg.num_experts * expert_weight_bytes(cfg) + cfg.d_model * cfg.num_experts * 4
+    return ((cfg.num_experts + cfg.num_shared_experts)
+            * expert_weight_bytes(cfg) + cfg.d_model * cfg.num_experts * 4)
 
 
 def ssm_weight_bytes(cfg: ModelConfig) -> float:
@@ -70,7 +84,10 @@ def model_bytes(cfg: ModelConfig) -> float:
 
 
 def kv_bytes_per_token_layer(cfg: ModelConfig) -> float:
-    """KV-cache bytes appended per token for one attention layer."""
+    """KV-cache bytes appended per token for one attention layer (one
+    latent row under MLA)."""
+    if cfg.mla:
+        return cfg.mla_cache_width * BYTES
     return 2 * cfg.num_kv_heads * cfg.head_dim * BYTES
 
 
@@ -78,9 +95,7 @@ def kv_page_frame_bytes(cfg: ModelConfig, page_tokens: int) -> float:
     """Bytes of ONE page frame across every attention layer (K + V):
     the allocation unit of the paged tiered cache
     (``serving.cache.KVPageTable.frame_bytes``)."""
-    n_attn = sum(
-        1 for i in range(cfg.num_layers) if cfg.layer_kind(i) == "attn"
-    )
+    n_attn = sum(1 for i in range(cfg.num_layers) if is_attention(cfg, i))
     return n_attn * page_tokens * kv_bytes_per_token_layer(cfg)
 
 
@@ -93,7 +108,7 @@ def kv_bytes_per_seq(cfg: ModelConfig, ctx: int, page_tokens: int = 0) -> float:
     ``page_tokens=32``)."""
     total = 0.0
     for i in range(cfg.num_layers):
-        if cfg.layer_kind(i) == "attn":
+        if is_attention(cfg, i):
             span = min(ctx, cfg.sliding_window) if cfg.sliding_window else ctx
             if page_tokens > 0:
                 span = -(-span // page_tokens) * page_tokens
@@ -168,7 +183,7 @@ class LayerCensus:
 
 
 def census(cfg: ModelConfig) -> LayerCensus:
-    n_attn = sum(1 for i in range(cfg.num_layers) if cfg.layer_kind(i) == "attn")
+    n_attn = sum(1 for i in range(cfg.num_layers) if is_attention(cfg, i))
     n_ssm = cfg.num_layers - n_attn
     n_moe = sum(1 for i in range(cfg.num_layers) if cfg.ffn_kind(i) == "moe")
     n_dense = sum(
@@ -210,7 +225,7 @@ def next_pow2(n: int) -> int:
 def mixer_weight_bytes(cfg: ModelConfig, kind: str) -> float:
     """Sequence-mixer module weights (norms included) for one layer."""
     norms = 2 * cfg.d_model * BYTES
-    if kind == "attn":
+    if kind in ("attn", "mla"):
         return attn_weight_bytes(cfg) + norms
     return ssm_weight_bytes(cfg) + norms
 
@@ -322,19 +337,24 @@ def plan_residency(cfg: ModelConfig, s_params: Optional[float]) -> ResidencyPlan
             base_weight_bytes(cfg), model_bytes(cfg),
             (True,) * L, (True,) * L,
         )
-    base = base_weight_bytes(cfg)
+    # the leading dense layers are pinned with the base weights (the store
+    # holds them outside its streamed layer index)
+    k = cfg.first_k_dense
+    base = base_weight_bytes(cfg) + sum(
+        mixer_weight_bytes(cfg, cfg.layer_kind(i))
+        + ffn_module_weight_bytes(cfg, cfg.ffn_kind(i)) for i in range(k))
     budget = max(0.0, float(s_params) - base)
-    mixer = [False] * L
-    ffn = [False] * L
+    mixer = [i < k for i in range(L)]
+    ffn = [i < k for i in range(L)]
     used = base
     # greedy order: mixers, dense FFNs, then expert stacks
     order = (
         [("mixer", i, mixer_weight_bytes(cfg, cfg.layer_kind(i)))
-         for i in range(L)]
+         for i in range(k, L)]
         + [("ffn", i, ffn_module_weight_bytes(cfg, "dense"))
-           for i in range(L) if cfg.ffn_kind(i) == "dense"]
+           for i in range(k, L) if cfg.ffn_kind(i) == "dense"]
         + [("ffn", i, ffn_module_weight_bytes(cfg, "moe"))
-           for i in range(L) if cfg.ffn_kind(i) == "moe"]
+           for i in range(k, L) if cfg.ffn_kind(i) == "moe"]
     )
     for which, i, nbytes in order:
         if nbytes <= 0.0:                  # no module => trivially resident

@@ -93,6 +93,12 @@ def validate_ep_shard(cfg: ModelConfig, sctx: ShardCtx) -> int:
             "model_axis; for single-device serving pass sctx=None"
         )
     n = sctx.model_size
+    if cfg.num_shared_experts or cfg.first_k_dense or cfg.mla:
+        raise ValueError(
+            "the expert-parallel engine serves routed experts behind plain "
+            "attention only: shared experts, leading dense layers and "
+            "latent attention are served single-device"
+        )
     if sctx.moe_dispatch not in ("a2a", "psum"):
         raise ValueError(
             f"moe_dispatch={sctx.moe_dispatch!r} is not a collective "

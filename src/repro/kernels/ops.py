@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from repro.kernels import expert_gemm as _eg
 from repro.kernels import decode_attention as _da
 from repro.kernels import flash_attention as _fa
+from repro.kernels import mla_decode as _mla
 from repro.kernels import ssd_scan as _ssd
 
 
@@ -94,6 +95,53 @@ def decode_attention(q, k, v, pos, interpret=None):
     return _da.decode_attention(
         q, k, v, pos, block_s=block_s, interpret=interpret
     )
+
+
+def _largest_divisor(n: int, options) -> int:
+    return next((o for o in options if n % o == 0), 1)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("rank", "scale", "use_kernel"))
+def mla_decode(q, c, pos, rank, scale, use_kernel=None):
+    """Absorbed latent-attention decode: (B,H,rank+rope) queries over the
+    paired latent cache (B,span/2,2*(rank+rope)) (``attention.mla_pack``)
+    -> (B,H,rank), row ``b`` attending positions ``<= pos[b]``.  The
+    Pallas kernel on real TPU; elsewhere the same math in XLA (scores and
+    the latent sum accumulate in float32, the probabilities enter the sum
+    in the cache's type, as in the kernel)."""
+    use_kernel = default_use_kernel() if use_kernel is None else use_kernel
+    B = q.shape[0]
+    span = 2 * c.shape[1]
+    if use_kernel:
+        # position blocks of an even size that divides the span (the whole
+        # span where none of these does; the span is always even)
+        block_t = _largest_divisor(span, (256, 128, 64, 32, 16))
+        return _mla.mla_decode(
+            q, c, pos, rank=rank, scale=scale,
+            block_b=_largest_divisor(B, (8, 4, 2)),
+            block_t=span if block_t == 1 else block_t,
+            interpret=default_interpret(),
+        )
+    rows = _unpack_latent(c, rank)                         # (B, span, C)
+    s = jnp.einsum("bhc,btc->bht", q, rows,
+                   preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(span)[None, :] <= jnp.asarray(pos, jnp.int32)[:, None]
+    s = jnp.where(valid[:, None, :], s, _mla.NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bht,btr->bhr", p.astype(c.dtype), rows[..., :rank],
+                   preferred_element_type=jnp.float32)
+    return o.astype(q.dtype)
+
+
+def _unpack_latent(c, rank: int):
+    """Paired cache rows (..., span/2, 2C) -> per-position rows
+    (..., span, C): the inverse of ``attention.mla_pack``."""
+    *lead, half, two_c = c.shape
+    rope = two_c // 2 - rank
+    lat = c[..., :2 * rank].reshape(*lead, 2 * half, rank)
+    pe = c[..., 2 * rank:].reshape(*lead, 2 * half, rope)
+    return jnp.concatenate([lat, pe], axis=-1)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "interpret"))

@@ -46,11 +46,13 @@ def executing_config(arch: str, layers: Optional[int] = None) -> ModelConfig:
     full = get_config(arch)
     cfg = replace(full, num_layers=int(layers))
     period = len(M.layer_pattern(full))
-    if not 1 <= cfg.num_layers <= full.num_layers \
-            or cfg.num_layers % period:
+    k = full.first_k_dense
+    if not k < cfg.num_layers <= full.num_layers \
+            or (cfg.num_layers - k) % period:
         raise ValueError(
-            f"--layers {layers}: {arch} has {full.num_layers} layers in "
-            f"periods of {period}; pick a multiple of {period}")
+            f"--layers {layers}: {arch} has {full.num_layers} layers, "
+            f"{k} leading dense then periods of {period}; pick {k} plus a "
+            f"multiple of {period}")
     return cfg
 
 

@@ -1,4 +1,5 @@
-"""Attention: GQA with RoPE; full-sequence (train/prefill) and decode paths.
+"""Attention: GQA with RoPE, and multi-head latent attention (MLA);
+full-sequence (train/prefill) and decode paths.
 
 Three implementations:
 
@@ -12,6 +13,16 @@ Three implementations:
 
 Decode uses a pre-allocated KV cache (full attention) or a circular window
 buffer (SWA).
+
+MLA (DeepSeek-V2, ``cfg.kv_lora_rank > 0``) caches one latent row per
+token and layer instead of per-head keys and values: the RMSNorm'd
+``kv_lora_rank`` latent and the one ``qk_rope_head_dim`` rope key shared by
+every head, ``C = r + rope`` values, stored two positions to a row as
+``{"ckv": (B, span / 2, 2C)}`` (``mla_pack``).  Prefill runs the
+decompressed form (every head's nope key and value up-projected from the
+latent by ``wkv_b``); decode runs the absorbed form (``W_UK`` folded into
+the query, ``W_UV`` applied to the attended latent), so no cached row is
+ever up-projected (``mla_decode``).
 """
 from __future__ import annotations
 
@@ -22,7 +33,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import apply_rope, dense_init
+from repro.models.layers import (
+    apply_rope,
+    apply_yarn_rope,
+    dense_init,
+    mla_softmax_scale,
+    rms_norm,
+)
 from repro.sharding.specs import ShardCtx
 
 NEG_INF = -1e30
@@ -349,3 +366,163 @@ def attn_decode(
     o = o.reshape(B, 1, cfg.num_heads * hd)
     y = o @ p["wo"]
     return ctx.shard(y, "batch", None, None), {"k": ck, "v": cv}
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2)
+# ---------------------------------------------------------------------------
+def init_mla_params(cfg: ModelConfig, key) -> Dict[str, jax.Array]:
+    """Projections in the published column order: ``wq`` per head nope then
+    rope dims, ``wkv_a`` the latent then the shared rope key, ``wkv_b`` per
+    head the nope key then the value."""
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dt = jnp.dtype(cfg.dtype)
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], (d, h * (nope + rope)), dtype=dt),
+        "wkv_a": dense_init(ks[1], (d, r + rope), dtype=dt),
+        "kv_norm": jnp.ones((r,), dt),
+        "wkv_b": dense_init(ks[2], (r, h * (nope + vd)), dtype=dt),
+        "wo": dense_init(ks[3], (h * vd, d), in_dim=h * vd, dtype=dt),
+    }
+
+
+def _mla_project(cfg: ModelConfig, p, x: jax.Array, positions: jax.Array):
+    """x (B, S, D) -> q_nope (B,S,H,nope), roped q_pe (B,S,H,rope) and the
+    cache row (B, S, C): the normalised latent, then the roped rope key."""
+    B, S, _ = x.shape
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = (x @ p["wq"]).reshape(B, S, H, nope + rope)
+    q_pe = apply_yarn_rope(q[..., nope:], positions, cfg)
+    ckv = x @ p["wkv_a"]                                    # (B, S, r+rope)
+    lat = rms_norm(ckv[..., :r], p["kv_norm"], cfg.norm_eps)
+    k_pe = apply_yarn_rope(ckv[..., None, r:], positions, cfg)[..., 0, :]
+    return q[..., :nope], q_pe, jnp.concatenate([lat, k_pe], axis=-1)
+
+
+def mla_forward(
+    cfg: ModelConfig,
+    p: Dict[str, jax.Array],
+    x: jax.Array,
+    ctx: ShardCtx = ShardCtx(),
+    positions: Optional[jax.Array] = None,
+    lengths: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Full-sequence MLA in the decompressed form.  Returns (output,
+    {"ckv": (B, S, C)}): the raw per-position cache rows.
+    ``lengths`` masks the padded keys of a ragged batch, as in
+    ``attn_forward``.  Scores are materialized per micro-batch."""
+    B, S, _ = x.shape
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    if positions is None:
+        positions = jnp.arange(S)[None, :]
+    q_nope, q_pe, row = _mla_project(cfg, p, x, positions)
+    kv = (row[..., :r] @ p["wkv_b"]).reshape(B, S, H, nope + vd)
+    k_pe = jnp.broadcast_to(row[..., None, r:], (B, S, H, rope))
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32)
+    s = s * mla_softmax_scale(cfg)
+    mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
+    s = jnp.where(mask[None, None], s, NEG_INF)
+    if lengths is not None:
+        kv_mask = jnp.arange(S)[None, :] < lengths[:, None]
+        s = jnp.where(kv_mask[:, None, None, :], s, NEG_INF)
+    pr = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhqk,bkhd->bqhd", pr.astype(kv.dtype), kv[..., nope:])
+    y = o.reshape(B, S, H * vd) @ p["wo"]
+    return ctx.shard_residual(y), {"ckv": row}
+
+
+def mla_pack(cfg: ModelConfig, rows: jax.Array, span: int) -> jax.Array:
+    """Per-position cache rows ``(..., S, C)`` -> the decode cache layout
+    ``(..., span / 2, 2C)``, padded/truncated to ``span`` (even) positions:
+    row ``k`` holds positions ``2k`` and ``2k+1`` as ``[lat[2k] |
+    lat[2k+1] | pe[2k] | pe[2k+1]]``, so a row is a whole number of
+    128-lane tiles at DeepSeek-V2's widths (2 x 576 = 1152) and every
+    slice of it the decode kernel takes is lane-aligned
+    (``kernels/mla_decode.py``)."""
+    r = cfg.kv_lora_rank
+    *lead, S, C = rows.shape
+    n = min(S, span)
+    rows = jnp.pad(rows[..., :n, :],
+                   [(0, 0)] * len(lead) + [(0, span - n), (0, 0)])
+    lat = rows[..., :r].reshape(*lead, span // 2, 2 * r)
+    pe = rows[..., r:].reshape(*lead, span // 2, 2 * (C - r))
+    return jnp.concatenate([lat, pe], axis=-1)
+
+
+def mla_span(max_seq: int) -> int:
+    """Cached positions of an MLA layer: ``max_seq`` rounded up to even."""
+    return max_seq + max_seq % 2
+
+
+def init_mla_cache(cfg: ModelConfig, batch: int, max_seq: int,
+                   dtype=None) -> Dict[str, jax.Array]:
+    dtype = dtype or jnp.dtype(cfg.dtype)
+    return {"ckv": jnp.zeros(
+        (batch, mla_span(max_seq) // 2, 2 * cfg.mla_cache_width), dtype)}
+
+
+def _mla_write(cfg: ModelConfig, c: jax.Array, slot: jax.Array,
+               row: jax.Array) -> jax.Array:
+    """Write each batch row's new cache row ``row`` (B, C) at its position
+    ``slot`` (B,) of the paired cache ``c`` (B, span/2, 2C), in place: the
+    pair row holding the position is read, its half for this position
+    replaced, and the whole pair row scattered back (one scatter of B
+    whole rows, as the GQA cache write is)."""
+    B = c.shape[0]
+    r = cfg.kv_lora_rank
+    rope = row.shape[-1] - r
+    rows, k = jnp.arange(B), slot // 2
+    odd = (slot % 2 == 1)[:, None]
+    cur = c[rows, k]                                        # (B, 2C)
+    lat, pe = row[:, :r].astype(c.dtype), row[:, r:].astype(c.dtype)
+    pair = jnp.concatenate([
+        jnp.where(odd, cur[:, :r], lat), jnp.where(odd, lat, cur[:, r:2 * r]),
+        jnp.where(odd, cur[:, 2 * r:2 * r + rope], pe),
+        jnp.where(odd, pe, cur[:, 2 * r + rope:])], axis=-1)
+    return c.at[rows, k].set(pair)
+
+
+def mla_decode(
+    cfg: ModelConfig,
+    p: Dict[str, jax.Array],
+    x: jax.Array,                       # (B, 1, D)
+    cache: Dict[str, jax.Array],
+    pos: jax.Array,                     # scalar or (B,) int32
+    ctx: ShardCtx = ShardCtx(),
+    use_kernel=None,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One decode step in the absorbed form over the latent cache: each
+    row writes its cache row at its own position, its query is folded
+    through ``W_UK`` into latent space, ``kernels.ops.mla_decode`` attends
+    the cached rows ``<= pos``, and ``W_UV`` then ``wo`` map the attended
+    latent out."""
+    from repro.kernels import ops as kernel_ops
+
+    B = x.shape[0]
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+    posv = jnp.broadcast_to(
+        jnp.atleast_1d(jnp.asarray(pos, jnp.int32)), (B,)
+    )
+    q_nope, q_pe, row = _mla_project(cfg, p, x, posv[:, None])
+    c = cache["ckv"]
+    slot = jnp.minimum(posv, 2 * c.shape[1] - 1)
+    c = _mla_write(cfg, c, slot, row[:, 0])
+    w_b = p["wkv_b"].reshape(r, H, nope + vd)
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_b[..., :nope],
+                       preferred_element_type=jnp.float32)
+    q = jnp.concatenate([q_lat.astype(c.dtype), q_pe[:, 0]], axis=-1)
+    o_lat = kernel_ops.mla_decode(q, c, posv, rank=r,
+                                  scale=mla_softmax_scale(cfg),
+                                  use_kernel=use_kernel)   # (B, H, r)
+    o = jnp.einsum("bhr,rhv->bhv", o_lat, w_b[..., nope:],
+                   preferred_element_type=jnp.float32).astype(x.dtype)
+    y = o.reshape(B, 1, H * vd) @ p["wo"]
+    return ctx.shard(y, "batch", None, None), {"ckv": c}

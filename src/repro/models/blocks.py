@@ -1,4 +1,5 @@
-"""Layer blocks: (attention | SSM) + (dense FFN | MoE) with pre-norm residuals."""
+"""Layer blocks: (attention | latent attention | SSM) + (dense FFN | MoE)
+with pre-norm residuals."""
 from __future__ import annotations
 
 from typing import Dict, Optional, Tuple
@@ -45,6 +46,8 @@ def init_layer_params(cfg: ModelConfig, kind: str, ffn_kind: str, key):
     p: Dict = {"norm1": jnp.ones((cfg.d_model,), dt)}
     if kind == "attn":
         p["attn"] = attn_mod.init_attn_params(cfg, ks[0])
+    elif kind == "mla":
+        p["mla"] = attn_mod.init_mla_params(cfg, ks[0])
     else:
         p["ssm"] = ssm_mod.init_ssm_params(cfg, ks[0])
     if ffn_kind == "moe":
@@ -77,6 +80,9 @@ def layer_forward(
     if kind == "attn":
         y, cache = attn_mod.attn_forward(cfg, p["attn"], h, ctx, positions,
                                          lengths)
+    elif kind == "mla":
+        y, cache = attn_mod.mla_forward(cfg, p["mla"], h, ctx, positions,
+                                        lengths)
     else:
         y, cache = ssm_mod.ssm_forward(cfg, p["ssm"], h, ctx, lengths)
     x = x + y
@@ -93,6 +99,8 @@ def layer_forward(
 def init_layer_cache(cfg: ModelConfig, kind: str, batch: int, max_seq: int):
     if kind == "attn":
         return attn_mod.init_kv_cache(cfg, batch, max_seq)
+    if kind == "mla":
+        return attn_mod.init_mla_cache(cfg, batch, max_seq)
     return ssm_mod.init_ssm_state(cfg, batch)
 
 
@@ -109,6 +117,8 @@ def layer_decode(
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
     if kind == "attn":
         y, cache = attn_mod.attn_decode(cfg, p["attn"], h, cache, pos, ctx)
+    elif kind == "mla":
+        y, cache = attn_mod.mla_decode(cfg, p["mla"], h, cache, pos, ctx)
     else:
         y, cache = ssm_mod.ssm_decode(cfg, p["ssm"], h, cache, ctx)
     x = x + y

@@ -28,19 +28,35 @@ from repro.models.layers import dense_init, rms_norm
 from repro.sharding.specs import ShardCtx
 
 
+def lead_pattern(cfg: ModelConfig) -> List[Tuple[str, str]]:
+    """(mixer kind, FFN kind) of each leading dense layer
+    (``first_k_dense``).  They run before the periodic stack, each with its
+    own parameters (``params["lead"]``, a list, not stacked)."""
+    return [(cfg.layer_kind(i), cfg.ffn_kind(i))
+            for i in range(cfg.first_k_dense)]
+
+
 def layer_pattern(cfg: ModelConfig) -> List[Tuple[str, str]]:
+    """The smallest repeating pattern of the layers after the leading
+    dense ones (``params["layers"]`` is stacked over its groups)."""
+    k = cfg.first_k_dense
+    n = cfg.num_layers - k
     g = cfg.attn_period if cfg.attn_period else 1
     if cfg.has_moe:
         g = math.lcm(g, cfg.moe_layer_period)
-    assert cfg.num_layers % g == 0, (cfg.name, cfg.num_layers, g)
-    pattern = [(cfg.layer_kind(i), cfg.ffn_kind(i)) for i in range(g)]
-    for i in range(cfg.num_layers):
-        assert (cfg.layer_kind(i), cfg.ffn_kind(i)) == pattern[i % g]
+    assert n > 0 and n % g == 0, (cfg.name, cfg.num_layers, k, g)
+    pattern = [(cfg.layer_kind(k + i), cfg.ffn_kind(k + i)) for i in range(g)]
+    for i in range(n):
+        assert (cfg.layer_kind(k + i), cfg.ffn_kind(k + i)) == pattern[i % g]
     return pattern
 
 
 def num_groups(cfg: ModelConfig) -> int:
-    return cfg.num_layers // len(layer_pattern(cfg))
+    return (cfg.num_layers - cfg.first_k_dense) // len(layer_pattern(cfg))
+
+
+def _lead_keys(cfg: ModelConfig, key) -> jax.Array:
+    return jax.random.split(jax.random.fold_in(key, 7), cfg.first_k_dense)
 
 
 # ---------------------------------------------------------------------------
@@ -65,6 +81,11 @@ def init_params(cfg: ModelConfig, key) -> Dict:
         "layers": layers,
         "final_norm": jnp.ones((cfg.d_model,), dt),
     }
+    if cfg.first_k_dense:
+        params["lead"] = [
+            init_layer_params(cfg, kind, ffn, k)
+            for (kind, ffn), k in zip(lead_pattern(cfg), _lead_keys(cfg, key))
+        ]
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(
             k_head, (cfg.d_model, cfg.vocab_size), dtype=dt
@@ -113,6 +134,12 @@ def init_params_host(cfg: ModelConfig, key) -> Dict:
         "layers": layers,
         "final_norm": np.ones((cfg.d_model,), dt),
     }
+    if cfg.first_k_dense:
+        init_one = jax.jit(init_layer_params, static_argnums=(0, 1, 2))
+        params["lead"] = [
+            jax.tree.map(np.asarray, init_one(cfg, kind, ffn, k))
+            for (kind, ffn), k in zip(lead_pattern(cfg), _lead_keys(cfg, key))
+        ]
     if not cfg.tie_embeddings:
         params["lm_head"] = np.asarray(
             draw(k_head, (cfg.d_model, cfg.vocab_size), dtype=dt)
@@ -151,7 +178,9 @@ def forward(
     remat_policy: str = "full",            # full | dots
     lengths: Optional[jax.Array] = None,   # (B,) true lengths (ragged batch)
 ):
-    """Returns (logits, aux_loss) — logits (B,S,V), (B,1,V), or final hidden.
+    """Returns (logits, aux_loss, caches) — logits (B,S,V), (B,1,V), or
+    final hidden.  ``caches`` lists one entry per leading dense layer, then
+    one per pattern slot stacked over groups.
 
     ``lengths`` marks the true length of each right-padded sequence.  Padded
     positions are masked out of attention and the SSM recurrence, and
@@ -165,6 +194,13 @@ def forward(
     positions = jnp.arange(S)[None, :]
     if lengths is not None:
         lengths = jnp.asarray(lengths, jnp.int32)
+    aux0 = jnp.zeros((), jnp.float32)
+    lead_caches = []
+    for (kind, ffn), lp in zip(lead_pattern(cfg), params.get("lead", [])):
+        x, cache, a = layer_forward(cfg, kind, ffn, lp, x, ctx, positions,
+                                    lengths)
+        lead_caches.append(cache)
+        aux0 = aux0 + a
 
     def body(carry, group_p):
         x, aux = carry
@@ -187,9 +223,8 @@ def forward(
         scan_body = jax.checkpoint(body)
     else:
         scan_body = body
-    (x, aux), caches = lax.scan(
-        scan_body, (x, jnp.zeros((), jnp.float32)), params["layers"]
-    )
+    (x, aux), caches = lax.scan(scan_body, (x, aux0), params["layers"])
+    caches = lead_caches + list(caches)
     if logits_mode == "none":
         return x, aux, caches
     if logits_mode == "last":
@@ -259,7 +294,9 @@ def prefill(
     """Returns (last-token logits (B,1,V), caches).
 
     Attention cache entries come back as the raw per-layer K/V of shape
-    (G, B, S, K, hd) (rope already applied); SSM entries as the final
+    (G, B, S, K, hd) (rope already applied), latent attention's as the
+    cache rows (G, B, S, C); a leading dense layer's entry has no G axis
+    and comes first.  SSM entries come back as the final
     recurrent state.  ``serving.kvcache`` converts these into decode-ready
     buffers (padding / ring alignment).  ``lengths`` (B,) makes a ragged
     (right-padded) batch exact: pads are masked and logits come from each
@@ -276,9 +313,12 @@ def prefill(
 # Decode
 # ---------------------------------------------------------------------------
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> List:
+    """One cache per leading dense layer, then one per pattern slot stacked
+    over groups."""
     pattern = layer_pattern(cfg)
     G = num_groups(cfg)
-    slots = []
+    slots = [init_layer_cache(cfg, kind, batch, max_seq)
+             for kind, _ in lead_pattern(cfg)]
     for kind, _ in pattern:
         c = init_layer_cache(cfg, kind, batch, max_seq)
         slots.append(
@@ -297,7 +337,12 @@ def decode_step(
 ):
     """One token for every sequence.  Returns (logits (B,V), new cache)."""
     pattern = layer_pattern(cfg)
+    lead = lead_pattern(cfg)
     x = _embed(cfg, params, tokens[:, None], None, ctx)
+    new_lead = []
+    for (kind, ffn), lp, c in zip(lead, params.get("lead", []), cache):
+        x, c = layer_decode(cfg, kind, ffn, lp, x, c, pos, ctx)
+        new_lead.append(c)
 
     def body(x, xs):
         group_p, group_c = xs
@@ -307,6 +352,6 @@ def decode_step(
             new_c.append(c)
         return x, new_c
 
-    x, new_cache = lax.scan(body, x, (params["layers"], cache))
+    x, new_cache = lax.scan(body, x, (params["layers"], cache[len(lead):]))
     logits = _logits(cfg, params, x, ctx)
-    return logits[:, 0], new_cache
+    return logits[:, 0], new_lead + list(new_cache)

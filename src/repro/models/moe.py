@@ -38,21 +38,42 @@ def init_moe_params(cfg: ModelConfig, key) -> Dict[str, jax.Array]:
     d, e, f = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
     dt = jnp.dtype(cfg.dtype)
     ks = jax.random.split(key, 4)
-    return {
+    p = {
         "router": dense_init(ks[0], (d, e), dtype=jnp.float32),
         "experts_w_gate": dense_init(ks[1], (e, d, f), in_dim=d, dtype=dt),
         "experts_w_up": dense_init(ks[2], (e, d, f), in_dim=d, dtype=dt),
         "experts_w_down": dense_init(ks[3], (e, f, d), in_dim=f, dtype=dt),
     }
+    if cfg.num_shared_experts:
+        fs = cfg.num_shared_experts * f
+        kk = jax.random.split(jax.random.fold_in(key, 1), 3)
+        p["shared"] = {
+            "w_gate": dense_init(kk[0], (d, fs), dtype=dt),
+            "w_up": dense_init(kk[1], (d, fs), dtype=dt),
+            "w_down": dense_init(kk[2], (fs, d), dtype=dt),
+        }
+    return p
 
 
 def route(cfg: ModelConfig, router_w: jax.Array, x: jax.Array):
-    """Top-k routing.  x: (..., D).  Returns (gates, idx, probs)."""
+    """Top-k routing.  x: (..., D).  Returns (gates, idx, probs).  The
+    top-k gates are renormalised to sum to one unless
+    ``cfg.norm_topk_prob`` is false, in which case they keep their softmax
+    values."""
     logits = x.astype(jnp.float32) @ router_w               # (..., E)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, idx = jax.lax.top_k(probs, cfg.experts_per_token)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     return gates, idx, probs
+
+
+def shared_experts(p: Dict, x: jax.Array) -> jax.Array:
+    """The always-on shared experts of a MoE layer (``p["shared"]``): one
+    SwiGLU of ``num_shared_experts * moe_d_ff`` over every row, computed
+    outside the routed dispatch."""
+    w = p["shared"]
+    return (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
 
 
 def load_balance_loss(cfg: ModelConfig, probs: jax.Array, idx: jax.Array):
@@ -453,6 +474,15 @@ def moe_apply_a2a(
 
 
 def moe_apply(cfg: ModelConfig, p, x, ctx: ShardCtx = ShardCtx()):
+    """The routed experts by ``ctx``'s dispatch, plus the shared experts
+    (if the layer has any)."""
+    y, aux = _routed_apply(cfg, p, x, ctx)
+    if "shared" in p:
+        y = y + shared_experts(p, x).astype(y.dtype)
+    return y, aux
+
+
+def _routed_apply(cfg: ModelConfig, p, x, ctx: ShardCtx):
     dispatch = getattr(ctx, "moe_dispatch", "psum")
     if ctx.mesh is not None and ctx.model_axis is not None:
         if dispatch == "grouped":

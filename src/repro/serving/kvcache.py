@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.analysis.registry import TraceKeySet, register_jit
 from repro.configs.base import ModelConfig
+from repro.models import attention as attn_mod
 from repro.models import model as model_mod
 
 
@@ -58,10 +59,14 @@ def cache_from_prefill(
     cfg: ModelConfig, caches: List, seq_len: int, max_seq: int
 ) -> List:
     """Convert prefill caches into decode-ready buffers of span ``max_seq``."""
-    pattern = model_mod.layer_pattern(cfg)
+    kinds = model_mod.lead_pattern(cfg) + model_mod.layer_pattern(cfg)
     out = []
-    for j, (kind, _) in enumerate(pattern):
+    for j, (kind, _) in enumerate(kinds):
         slot = caches[j]
+        if kind == "mla":
+            out.append({"ckv": attn_mod.mla_pack(
+                cfg, slot["ckv"], attn_mod.mla_span(max_seq))})
+            continue
         if kind != "attn":
             out.append(slot)                       # SSM state passes through
             continue
@@ -69,6 +74,15 @@ def cache_from_prefill(
         nk, nv = aligned_kv(cfg, slot["k"], slot["v"], span)
         out.append({"k": nk, "v": nv})
     return out
+
+
+@register_jit("kvcache.insert_latent", donated=("ckv",))
+@functools.partial(jax.jit, donate_argnames=("ckv",))
+def _insert_latent_module(ckv, rows, new):
+    """Write the decode-layout latent rows ``new`` (n, span/2, 2C) into batch
+    rows ``rows`` of the layer's cache, which is DONATED: updated in place,
+    never copied whole."""
+    return ckv.at[rows].set(new.astype(ckv.dtype))
 
 
 def insert_prefill_rows(
@@ -84,7 +98,12 @@ def insert_prefill_rows(
     layer-major prefill and ``scatter_prefill_rows``.
     """
     rows = jnp.asarray(rows)
-    if kind == "attn":
+    if kind == "mla":
+        span = 2 * layer_cache["ckv"].shape[1]
+        layer_cache["ckv"] = _insert_latent_module(
+            layer_cache["ckv"], rows,
+            attn_mod.mla_pack(cfg, entry["ckv"], span))
+    elif kind == "attn":
         span = layer_cache["k"].shape[1]
         nk, nv = aligned_kv(cfg, entry["k"], entry["v"], span)
         layer_cache["k"] = layer_cache["k"].at[rows].set(nk)
@@ -104,13 +123,17 @@ def scatter_prefill_rows(
     ``caches`` the raw ``model.prefill`` output for the newcomer micro-batch
     (stacked over groups).  See ``insert_prefill_rows`` for the invariant.
     """
+    lead = model_mod.lead_pattern(cfg)
     pattern = model_mod.layer_pattern(cfg)
-    n_pat = len(pattern)
-    G = len(cache) // n_pat
+    n_lead, n_pat = len(lead), len(pattern)
+    for li, (kind, _) in enumerate(lead):
+        cache[li] = insert_prefill_rows(cfg, kind, cache[li], caches[li],
+                                        rows)
+    G = (len(cache) - n_lead) // n_pat
     for g in range(G):
         for j, (kind, _) in enumerate(pattern):
-            li = g * n_pat + j
-            slot = jax.tree.map(lambda a: a[g], caches[j])
+            li = n_lead + g * n_pat + j
+            slot = jax.tree.map(lambda a: a[g], caches[n_lead + j])
             cache[li] = insert_prefill_rows(cfg, kind, cache[li], slot, rows)
     return cache
 
@@ -178,9 +201,10 @@ def snapshot_row(layer_cache: dict, row: int) -> dict:
 
     The checkpoint unit of request preemption: every decode-buffer entry
     is batch-leading (attn ``{"k","v"}: (B, span, K, hd)``; SSM
-    ``{"h","conv"}``), so one row per layer captures a sequence's full
-    recurrent state.  Rows come back as NumPy (host) arrays — checkpoints
-    live in host memory while the device slot is recycled.
+    ``{"h","conv"}``; MLA ``{"ckv"}: (B, span/2, 2C)``), so one row per layer
+    captures a sequence's full recurrent state.  Rows come back as NumPy
+    (host) arrays — checkpoints live in host memory while the device slot
+    is recycled.
     """
     return {key: np.asarray(val[row]) for key, val in layer_cache.items()}
 

@@ -223,6 +223,10 @@ class ServeReport:
     decode_s: float = 0.0
     decode_slot_steps: int = 0    # decode steps x batch slots executed
     wasted_slot_steps: int = 0    # slot-steps spent on finished/empty slots
+    decode_ctx_tokens: int = 0    # cached positions the live decode rows
+    #   attended in latent-attention (MLA) layers: per live slot-step, its
+    #   position + 1, summed over the MLA layers — the context the absorbed
+    #   decode kernel needs, whatever span the cache holds
     weight_htod_bytes: int = 0    # streamed weight bytes copied host->device
     prefetch_wait_s: float = 0.0  # stall waiting on weight transfers
     admission_deferrals: int = 0  # admissions blocked by the Eq. 2 KV budget
@@ -1213,6 +1217,7 @@ class Server:
         wave = self._wave
         counted = len(wave["slots"]) if wave is not None else self._b
         eos = self.serve.eos_id
+        n_mla = sum(1 for k, _ in self._engine.schema if k == "mla")
         for t in range(T):
             nxt = mat[:, t]
             live = [s for s in range(self._b)
@@ -1220,6 +1225,10 @@ class Server:
                     and not self._slot_handle[s].finished]
             self.report.decode_slot_steps += counted
             self.report.wasted_slot_steps += counted - len(live)
+            if n_mla and live:
+                # row s was fed at _pos[s] (the engine clamps at max_seq-1)
+                fed = np.minimum(self._pos[live], self._max_seq - 1)
+                self.report.decode_ctx_tokens += n_mla * int((fed + 1).sum())
             for s in live:
                 h = self._slot_handle[s]
                 tk = int(nxt[s])

@@ -47,13 +47,16 @@ from repro.core import workload as W
 from repro.models import model as model_mod
 
 # per-layer module split: the streaming granularity.  'mixer' is
-# norm1 + attention/SSM; 'ffn' is norm2 + (MoE stacks + router | dense FFN).
-_MIXER_KEYS = ("norm1", "attn", "ssm")
+# norm1 + attention/latent attention/SSM; 'ffn' is norm2 + (MoE stacks +
+# router | dense FFN).
+_MIXER_KEYS = ("norm1", "attn", "mla", "ssm")
 _FFN_KEYS = ("norm2", "moe", "ffn")
 
 
 def unstack_layers(cfg: ModelConfig, params: Dict) -> List[Tuple[str, str, Dict]]:
-    """Flatten group-stacked layer params into a per-layer list."""
+    """Flatten group-stacked layer params into a per-layer list (the
+    layers after the leading dense ones, which ``params["lead"]`` holds
+    unstacked)."""
     pattern = model_mod.layer_pattern(cfg)
     G = model_mod.num_groups(cfg)
     layers = []
@@ -329,8 +332,14 @@ class ParamStore:
         self.schema: List[Tuple[str, str]] = [(k, f) for k, f, _ in layers]
         # base params: always device-resident (embed / final_norm / lm_head)
         self.base: Dict = self._pin(
-            {k: v for k, v in params.items() if k != "layers"}
+            {k: v for k, v in params.items() if k not in ("layers", "lead")}
         )
+        # leading dense layers (``first_k_dense``): pinned like the base
+        # weights and outside the layer index, which counts the periodic
+        # stack only — ``acquire(0)`` is the first layer after them
+        self.lead_schema: List[Tuple[str, str]] = model_mod.lead_pattern(cfg)
+        self.lead: List[Dict] = [self._pin(p)
+                                 for p in params.get("lead", [])]
         # per-layer split into resident (device) and streamed (host) modules
         self._resident: List[Dict[str, Dict]] = []
         self._host: List[Dict[str, Dict]] = []
@@ -344,17 +353,20 @@ class ParamStore:
             ffnp = {k: v for k, v in slot.items() if k in _FFN_KEYS}
             res: Dict[str, Dict] = {}
             host: Dict[str, Dict] = {}
-            if self.residency.mixer_resident[li]:
+            mi = li + cfg.first_k_dense     # the residency plan's index
+            if self.residency.mixer_resident[mi]:
                 res["mixer"] = self._pin(mixer)
             else:
                 host["mixer"] = _to_host(mixer)
             if ffnp:
-                if self.residency.ffn_resident[li]:
+                if self.residency.ffn_resident[mi]:
                     res["ffn"] = self._pin(ffnp)
                 elif self.predict_topk > 0 and ffn == "moe":
                     self._moe_shared[li] = self._pin({
                         "norm2": ffnp["norm2"],
                         "router": ffnp["moe"]["router"],
+                        **({"shared": ffnp["moe"]["shared"]}
+                           if "shared" in ffnp["moe"] else {}),
                     })
                     self._experts_host[li] = {
                         k: np.asarray(ffnp["moe"][k])
@@ -470,7 +482,7 @@ class ParamStore:
 
     def resident_module_bytes(self) -> int:
         return (
-            _tree_bytes(self.base)
+            _tree_bytes(self.base) + _tree_bytes(self.lead)
             + sum(_tree_bytes(m) for res in self._resident
                   for m in res.values())
             + sum(_tree_bytes(m) for m in self._moe_shared.values())
@@ -562,7 +574,7 @@ class ParamStore:
         if li in self._moe_shared:
             shared = self._moe_shared[li]
             merged["norm2"] = shared["norm2"]
-            moe: Dict = {"router": shared["router"]}
+            moe: Dict = {k: v for k, v in shared.items() if k != "norm2"}
             if experts:
                 wg, wu, wd = self.acquire_experts(
                     li, range(self.cfg.num_experts), record=False

@@ -116,3 +116,19 @@ def test_grouped_ffn_module_compiles_at_full_width(one_chip, tpu_compile):
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert _fits(compiled)
+
+
+def test_mla_decode_kernel_compiles_at_deepseek_widths(one_chip, tpu_compile):
+    """The absorbed latent-attention decode of DeepSeek-V2-Lite at the
+    benchmark cell's batch and span: 512 rows, 16 heads, a 512-wide
+    latent and a 64-wide rope key over 1536 cached positions."""
+    B, H, r, rope, span = 512, 16, 512, 64, 1536
+    q = _spec(one_chip, (B, H, r + rope))
+    c = _spec(one_chip, (B, span // 2, 2 * (r + rope)))
+    pos = _spec(one_chip, (B,), jnp.int32)
+    compiled = jax.jit(
+        lambda *a: kernel_ops.mla_decode(*a, rank=r, scale=0.1)
+    ).lower(q, c, pos).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mla_decode" in text
+    assert _fits(compiled)
