@@ -242,25 +242,28 @@ def _expert_module(wg, wu, wd, h_chunk):
 
 
 def _grouped_expert_math(cfg, p, x, capacity):
-    """The whole MoE stage, traceable: norm -> route -> capacity-bucketed
-    gather -> grouped FFN -> weighted scatter-add, plus the shared experts
-    over every row where the layer has them.  Returns (y, kept,
-    dropped, load); the counters — including the (E,) per-expert routed
-    histogram feeding the planner's measured-skew b_e search — stay on
-    device.  Launched standalone by the per-module path
-    (``_grouped_expert_module``) and inlined by the fused decode chunk —
-    ONE implementation, so both paths are bit-identical."""
+    """The whole decode MoE stage, traceable: norm -> route -> ragged
+    gather by expert (``moe.ragged_dispatch``, at most ``capacity`` copies
+    an expert) -> grouped FFN -> weighted scatter-add, plus the shared
+    experts over every row where the layer has them.  Returns (y, kept,
+    dropped, load, rows); the counters — including the (E,) per-expert
+    routed histogram feeding the planner's measured-skew b_e search and
+    the rows the ragged kernel computes — stay on device.  Launched
+    standalone by the per-module path (``_grouped_expert_module``) and
+    inlined by the fused decode chunk — ONE implementation, so both paths
+    are bit-identical."""
     moe = p["moe"]
     h = rms_norm(x, p["norm2"], cfg.norm_eps)
     gates, idx, _ = moe_mod.route(cfg, moe["router"], h)
-    y, kept, dropped, load = moe_mod.grouped_dispatch(
+    y, kept, dropped, load = moe_mod.ragged_dispatch(
         cfg, h, gates, idx,
         moe["experts_w_gate"], moe["experts_w_up"], moe["experts_w_down"],
         capacity,
     )
     if "shared" in moe:
         y = y + moe_mod.shared_experts(moe, h)
-    return y, kept, dropped, load
+    return y, kept, dropped, load, moe_mod.ragged_rows(load, capacity,
+                                                      x.shape[0])
 
 
 _grouped_expert_module = _counted(
@@ -299,17 +302,19 @@ def _route_predict_module(cfg, khat, norm2_w, router_w, next_router_w, x):
 def _grouped_ffn_module(cfg, capacity, h, gates, idx, wg, wu, wd,
                         moe_pinned=None):
     """Grouped FFN over PRE-ROUTED tokens with externally assembled expert
-    stacks — the second half of the predictive-streamed MoE stage.  The
+    stacks — the second half of the predictive-streamed MoE stage, by the
+    ragged dispatch of ``_grouped_expert_math`` and with its returns.  The
     stacks carry true weights for every expert with a routed copy and the
     zeros filler elsewhere (``ParamStore.acquire_experts``), which is
-    bit-identical to the full stack: an unrouted expert's capacity rows are
-    all-zero and its outputs are never gathered back.  The shared experts
-    (pinned beside the router, ``moe_pinned``) are added over every row."""
-    y, kept, dropped, load = moe_mod.grouped_dispatch(
+    bit-identical to the full stack: an unrouted expert takes no rows and
+    its weights are never read.  The shared experts (pinned beside the
+    router, ``moe_pinned``) are added over every row."""
+    y, kept, dropped, load = moe_mod.ragged_dispatch(
         cfg, h, gates, idx, wg, wu, wd, capacity)
     if moe_pinned:
         y = y + moe_mod.shared_experts(moe_pinned, h)
-    return y, kept, dropped, load
+    return y, kept, dropped, load, moe_mod.ragged_rows(load, capacity,
+                                                      h.shape[0])
 
 
 @_counted
@@ -598,9 +603,10 @@ def _fused_decode_chunk(cfg, schema, tie, capacity, lo, pos_cap, use_topk,
       advancing in-carry, so seeded streams are bit-identical to
       per-module decode.
 
-    Returns ``(toks (n, T), cache, kept, dropped, load)`` — ``dropped`` a
-    per-MoE-layer (n_moe,) vector and ``load`` the (n_moe, E) per-expert
-    routed-copy histogram, both accumulated in-carry on device.
+    Returns ``(toks (n, T), cache, kept, dropped, load, rows)`` —
+    ``dropped`` a per-MoE-layer (n_moe,) vector, ``load`` the (n_moe, E)
+    per-expert routed-copy histogram and ``rows`` the ragged expert
+    kernel's computed rows, all accumulated in-carry on device.
     """
     n = tokens.shape[0]
     n_moe = sum(1 for _, f in schema if f == "moe")
@@ -614,7 +620,7 @@ def _fused_decode_chunk(cfg, schema, tie, capacity, lo, pos_cap, use_topk,
     bar = lax.optimization_barrier
 
     def tick(carry, _):
-        toks, pos, cache, steps, kept, dropped, load = carry
+        toks, pos, cache, steps, kept, dropped, load, rows = carry
         cache = list(cache)
         x = bar(jnp.take(base["embed"], toks, axis=0))
         posv = jnp.minimum(pos, pos_cap)
@@ -659,9 +665,11 @@ def _fused_decode_chunk(cfg, schema, tie, capacity, lo, pos_cap, use_topk,
                 cache[li] = {"h": nh, "conv": nc}
                 x = bar(x + y)
             if ffn == "moe":
-                y, kp, dr, ld = _grouped_expert_math(cfg, p, x, capacity)
-                y, kp, dr, ld = bar((y, kp, dr, ld))
+                y, kp, dr, ld, rw = _grouped_expert_math(cfg, p, x,
+                                                         capacity)
+                y, kp, dr, ld, rw = bar((y, kp, dr, ld, rw))
                 kept = kept + kp
+                rows = rows + rw
                 dropped = dropped.at[moe_j].add(dr)
                 load = load.at[moe_j].add(ld)
                 moe_j += 1
@@ -678,15 +686,16 @@ def _fused_decode_chunk(cfg, schema, tie, capacity, lo, pos_cap, use_topk,
         carry_tok = jnp.where(live, nxt, toks)     # dead rows hold stale tok
         carry_pos = pos + live.astype(pos.dtype)   # ...at their stale pos
         return (carry_tok, carry_pos, tuple(cache), steps + 1, kept,
-                dropped, load), nxt
+                dropped, load, rows), nxt
 
     zero = jnp.zeros((), jnp.int32)
     carry0 = (tokens, pos, tuple(cache), steps, zero,
-              jnp.zeros((n_moe,), jnp.int32), jnp.zeros((n_moe, E), jnp.int32))
-    (_, _, cache, _, kept, dropped, load), ys = lax.scan(
+              jnp.zeros((n_moe,), jnp.int32), jnp.zeros((n_moe, E), jnp.int32),
+              zero)
+    (_, _, cache, _, kept, dropped, load, rows), ys = lax.scan(
         tick, carry0, None, length=T
     )
-    return jnp.swapaxes(ys, 0, 1), cache, kept, dropped, load
+    return jnp.swapaxes(ys, 0, 1), cache, kept, dropped, load, rows
 
 
 # ---------------------------------------------------------------------------
@@ -734,6 +743,9 @@ class EngineStats:
     #                                      rows, per MoE layer per micro-batch
     prefill_routed_copies: int = 0       # routed copies of real prompt
     #                                      tokens those buffers carried
+    decode_expert_rows: int = 0          # rows the ragged decode expert
+    #                                      kernel computed (used tiles x
+    #                                      tile rows), per MoE layer per tick
 
 
 class ModuleBatchingEngine:
@@ -881,6 +893,7 @@ class ModuleBatchingEngine:
         n_moe = len(self._moe_layers)
         E = max(1, cfg.num_experts)
         self._kept_dev = jnp.zeros((), jnp.int32)
+        self._rows_dev = jnp.zeros((), jnp.int32)
         self._dropped_dev_l = [jnp.zeros((), jnp.int32)
                                for _ in range(n_moe)]
         self._load_dev_l = [jnp.zeros((E,), jnp.int32) for _ in range(n_moe)]
@@ -936,8 +949,11 @@ class ModuleBatchingEngine:
     def sync_stats(self) -> EngineStats:
         """Materialize the device-side expert counters (one host sync) and
         drain the store's transfer + predictive-streaming accounting."""
-        self.stats.expert_tokens += int(self._kept_dev)
+        kept, rows = jax.device_get((self._kept_dev, self._rows_dev))
+        self.stats.expert_tokens += int(kept)
+        self.stats.decode_expert_rows += int(rows)
         self._kept_dev = jnp.zeros((), jnp.int32)
+        self._rows_dev = jnp.zeros((), jnp.int32)
         n_moe = len(self._moe_layers)
         if n_moe:
             E = self._load_chunk_dev.shape[1]
@@ -1628,9 +1644,10 @@ class ModuleBatchingEngine:
             self.stats.a2a_bytes += nbytes
             self.stats.collective_dispatches += 1
         else:
-            y, kept, dropped, load = _grouped_expert_module(
+            y, kept, dropped, load, rows = _grouped_expert_module(
                 self.cfg, p, x, self._expert_capacity(x.shape[0])
             )
+            self._rows_dev = self._rows_dev + rows
         self.stats.expert_launches += 1
         j = self._moe_index[li]
         self._kept_dev = self._kept_dev + kept
@@ -1677,7 +1694,7 @@ class ModuleBatchingEngine:
             pred = packed_np[E:]
         wg, wu, wd = self.store.acquire_experts(si, used)
         self.store.prefetch_experts(nsi, pred)
-        y, kept, dropped, load = _grouped_ffn_module(
+        y, kept, dropped, load, rows = _grouped_ffn_module(
             cfg, self._expert_capacity(x.shape[0]), h, gates, idx,
             wg, wu, wd,
             {"shared": shared["shared"]} if "shared" in shared else None,
@@ -1685,6 +1702,7 @@ class ModuleBatchingEngine:
         self.stats.expert_launches += 1
         j = self._moe_index[li]
         self._kept_dev = self._kept_dev + kept
+        self._rows_dev = self._rows_dev + rows
         self._dropped_dev_l[j] = self._dropped_dev_l[j] + dropped
         self._load_dev_l[j] = self._load_dev_l[j] + load
         return y
@@ -1797,7 +1815,7 @@ class ModuleBatchingEngine:
         with sanitizer.allowed("decode-row-slice"):
             toks_d, posv_d = tokens[n_host:], posv[n_host:]
             livev_d = livev[n_host:]
-        toks, cache, kept, dropped, load = _fused_decode_chunk(
+        toks, cache, kept, dropped, load, rows = _fused_decode_chunk(
             self.cfg, tuple(self.schema), self.cfg.tie_embeddings, capacity,
             n_host, cap, use_topk, greedy_only, T,
             self.store.base, self._fused_layer_params(),
@@ -1806,6 +1824,7 @@ class ModuleBatchingEngine:
         )
         self.cache = list(cache)
         self._kept_dev = self._kept_dev + kept
+        self._rows_dev = self._rows_dev + rows
         self._dropped_chunk_dev = self._dropped_chunk_dev + dropped
         self._load_chunk_dev = self._load_chunk_dev + load
         sampler.advance(idx, T)

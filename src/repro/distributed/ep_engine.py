@@ -1,8 +1,10 @@
 """Expert-parallel MoE decode stage: pipelined all-to-all over the mesh.
 
-The single-device engine runs the MoE stage as ONE grouped-dispatch launch
-(``core.engine._grouped_expert_math``): norm2 -> route -> capacity-bucketed
-``(E, C, D)`` gather -> grouped FFN -> gate-weighted scatter-add.  This
+The single-device engine runs the decode MoE stage as ONE launch
+(``core.engine._grouped_expert_math``): norm2 -> route -> gather by expert
+(``models.moe.ragged_dispatch``, which computes what the capacity-bucketed
+``(E, C, D)`` ``grouped_dispatch`` computes) -> grouped FFN ->
+gate-weighted scatter-add.  This
 module is the mesh realization of the SAME stage for an engine whose
 ``ShardCtx`` carries a ``model`` axis:
 
@@ -386,12 +388,12 @@ def ep_expert_stage(engine, li: int, p, x, capacity=None, routed=None):
     cap = engine._expert_capacity(T) if capacity is None else capacity
     if n == 1:
         if routed is not None:
-            y, kept, dropped, load = engine_mod._grouped_ffn_module(
+            y, kept, dropped, load, _ = engine_mod._grouped_ffn_module(
                 engine.cfg, cap, *routed, p["moe"]["experts_w_gate"],
                 p["moe"]["experts_w_up"], p["moe"]["experts_w_down"],
             )
         else:
-            y, kept, dropped, load = engine_mod._grouped_expert_module(
+            y, kept, dropped, load, _ = engine_mod._grouped_expert_module(
                 engine.cfg, p, x, cap
             )
         return y, kept, dropped, load, 0

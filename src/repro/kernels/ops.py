@@ -65,6 +65,26 @@ def expert_ffn(x, wg, wu, wd, interpret=None):
     return y[:, :C, :]
 
 
+@functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
+def ragged_expert_ffn(x, tile_expert, n_used, wg, wu, wd, block_m,
+                      interpret=None):
+    """Fused gated expert FFN over a ragged ``(R, D)`` row buffer whose
+    ``block_m``-row tiles each belong to one expert (``tile_expert``); the
+    first ``n_used`` tiles are computed, the rest left unwritten.  Pads
+    the F dim to the tile as ``expert_ffn`` does.  The Pallas kernel alone:
+    the one caller, ``models.moe.ragged_dispatch``, takes the capacity
+    einsum instead where no TPU compiles it."""
+    interpret = default_interpret() if interpret is None else interpret
+    wgp = _pad_to(wg, 2, 128)
+    wup = _pad_to(wu, 2, 128)
+    wdp = _pad_to(wd, 1, 128)
+    return _eg.ragged_expert_ffn(
+        x, jnp.asarray(tile_expert, jnp.int32),
+        jnp.asarray(n_used, jnp.int32).reshape(1), wgp, wup, wdp,
+        block_m=block_m, interpret=interpret,
+    )
+
+
 @functools.partial(jax.jit, static_argnames=("use_kernel",))
 def grouped_expert_ffn(x, wg, wu, wd, use_kernel=None):
     """Backend-dispatched grouped expert FFN over (E, C, D) capacity buffers.

@@ -22,6 +22,16 @@ def expert_ffn_ref(x: jax.Array, wg, wu, wd) -> jax.Array:
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
+def ragged_expert_ffn_ref(x: jax.Array, tile_expert, n_used: int, wg, wu,
+                          wd, block_m: int) -> jax.Array:
+    """The fused FFN of each ``block_m``-row tile of ``x`` (R, D) with its
+    tile's expert, over the first ``n_used`` tiles: (n_used*block_m, D)."""
+    rows = n_used * block_m
+    xt = x[:rows].reshape(n_used, block_m, x.shape[-1])
+    te = jnp.asarray(tile_expert)[:n_used]
+    return expert_ffn_ref(xt, wg[te], wu[te], wd[te]).reshape(rows, -1)
+
+
 def decode_attention_ref(
     q: jax.Array,       # (B, H, D)
     k: jax.Array,       # (B, S, K, D)

@@ -151,6 +151,93 @@ def grouped_dispatch(
     return y, kept, jnp.int32(T * k) - kept, load
 
 
+def ragged_tile(T: int) -> int:
+    """Row tile of the ragged decode dispatch for ``T`` rows: 128, or ``T``
+    rounded up to 16 when smaller.  At ``T <= 128`` every expert's copies
+    fit one tile, since a row routes to an expert at most once."""
+    return min(128, -(-T // 16) * 16)
+
+
+def ragged_buffer_rows(T: int, k: int, E: int, capacity: int) -> int:
+    """Static row count of the ragged buffer: each expert's kept copies
+    rounded up to whole tiles, at most ``T*k + E*(tm-1)`` rows and at most
+    ``E`` full capacity buffers of ``min(capacity, T)`` rows."""
+    tm = ragged_tile(T)
+    up = lambda n: -(-n // tm) * tm
+    return up(min(T * k + E * (tm - 1), E * up(min(capacity, T))))
+
+
+def _ragged_tiles(load: jax.Array, capacity: int, tm: int) -> jax.Array:
+    """(E,) row tiles each expert takes: its kept copies over ``tm``."""
+    return -(-jnp.minimum(load, capacity) // tm)
+
+
+def ragged_rows(load: jax.Array, capacity: int, T: int) -> jax.Array:
+    """Rows the ragged decode kernel computes for one call whose (E,)
+    routed-copy histogram is ``load``: used tiles x the row tile."""
+    tm = ragged_tile(T)
+    return jnp.sum(_ragged_tiles(load, capacity, tm)) * tm
+
+
+def ragged_dispatch(
+    cfg: ModelConfig,
+    xt: jax.Array,          # (T, D) tokens
+    gates: jax.Array,       # (T, k)
+    idx: jax.Array,         # (T, k) expert ids
+    wg, wu, wd,             # (E, ·, ·) expert weights
+    capacity: int,
+    use_kernel=None,
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """``grouped_dispatch`` with the routed copies packed by expert into
+    row tiles instead of an ``(E, capacity, D)`` buffer: decode's expert
+    stage.  Same arguments, same drops (a copy whose arrival slot is at or
+    past ``capacity``), same returns.
+
+    Expert ``e``'s kept copies take ``ceil(kept_e / tm)`` tiles of
+    ``tm = ragged_tile(T)`` rows at an exclusive-cumsum offset; the
+    ragged kernel (``kernels.ops.ragged_expert_ffn``) computes only the
+    used tiles and reads each expert's weights once per tile, so once a
+    call wherever its copies fit a tile.  The tile -> expert map and the
+    used count stay on device.  Where no TPU compiles the kernel, the
+    capacity einsum of ``grouped_dispatch`` runs instead: same values."""
+    from repro.kernels import ops as kernel_ops
+
+    use_kernel = (kernel_ops.default_use_kernel() if use_kernel is None
+                  else use_kernel)
+    if not use_kernel:
+        return grouped_dispatch(cfg, xt, gates, idx, wg, wu, wd, capacity,
+                                use_kernel=False)
+    T, D = xt.shape
+    E = cfg.num_experts
+    k = cfg.experts_per_token
+    tm = ragged_tile(T)
+    R = ragged_buffer_rows(T, k, E, capacity)
+    flat_idx = idx.reshape(-1)                              # (T*k,)
+    flat_gate = gates.reshape(-1)
+    slot = _arrival_slots(flat_idx, E)
+    keep = slot < capacity
+    load = jnp.zeros((E,), jnp.int32).at[flat_idx].add(1)
+    tiles = _ragged_tiles(load, capacity, tm)
+    ends = jnp.cumsum(tiles)                                # (E,)
+    row = (ends - tiles)[flat_idx] * tm + slot
+    tok = jnp.arange(T * k) // k
+    buf = jnp.zeros((R, D), xt.dtype).at[jnp.where(keep, row, R)].set(
+        xt[tok], mode="drop")
+    # tile t belongs to the first expert whose tiles end past it
+    tile_expert = jnp.minimum(
+        jnp.sum(jnp.arange(R // tm)[:, None] >= ends[None, :], axis=1),
+        E - 1).astype(jnp.int32)
+    out = kernel_ops.ragged_expert_ffn(buf, tile_expert, ends[-1], wg, wu,
+                                       wd, block_m=tm)
+    # dropped copies read no row: rows past the used tiles are unwritten
+    back = out[jnp.where(keep, row, 0)]                     # (T*k, D)
+    back = jnp.where(keep[:, None],
+                     back * flat_gate[:, None].astype(back.dtype), 0)
+    y = jnp.zeros((T, D), xt.dtype).at[tok].add(back.astype(xt.dtype))
+    kept = jnp.sum(keep.astype(jnp.int32))
+    return y, kept, jnp.int32(T * k) - kept, load
+
+
 def moe_apply_grouped(
     cfg: ModelConfig,
     p: Dict[str, jax.Array],

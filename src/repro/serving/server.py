@@ -240,6 +240,9 @@ class ServeReport:
     prefill_capacity_rows: int = 0  # grouped-prefill expert buffer rows
     prefill_routed_copies: int = 0  # routed copies of real prompt tokens
     #   in them (1 - copies/rows is prefill's expert padding)
+    decode_expert_rows: int = 0   # rows the ragged decode expert kernel
+    #   computed (used tiles x tile rows), summed over MoE layers and
+    #   ticks; over decode_slot_steps x top_k, how well it packs
     _expert_dropped: int = 0      # drops counted outside BatchResults
     # predictive per-expert streaming + imbalance accounting (grouped path)
     expert_dropped_by_layer: Optional[np.ndarray] = None  # (n_moe,) drops
@@ -515,7 +518,7 @@ class Server:
         # engine-stat totals already drained into the report
         self._seen = {"drop": 0, "htod": 0, "wait": 0.0, "kvh": 0, "kvd": 0,
                       "ph": 0, "pm": 0, "lh": 0, "a2a": 0, "cd": 0,
-                      "retr": 0, "tmo": 0, "pcr": 0, "prc": 0}
+                      "retr": 0, "tmo": 0, "pcr": 0, "prc": 0, "der": 0}
         # online capacity re-plan (replan_skew): the hottest expert's share
         # at the last (re-)plan; None until the first measurement
         self._replan_share: Optional[float] = None
@@ -704,6 +707,8 @@ class Server:
                                               - self._seen["pcr"])
         self.report.prefill_routed_copies += (st.prefill_routed_copies
                                               - self._seen["prc"])
+        self.report.decode_expert_rows += (st.decode_expert_rows
+                                           - self._seen["der"])
         # cumulative engine totals — one engine per server, so the report's
         # arrays are simply the latest snapshot (copies: the engine keeps
         # accumulating into its own buffers)
@@ -725,7 +730,8 @@ class Server:
                       "retr": st.transfer_retries,
                       "tmo": st.transfer_timeouts,
                       "pcr": st.prefill_capacity_rows,
-                      "prc": st.prefill_routed_copies}
+                      "prc": st.prefill_routed_copies,
+                      "der": st.decode_expert_rows}
         return d_drop
 
     def _maybe_replan(self) -> None:

@@ -9,10 +9,17 @@ Covers the PR's contract:
   Pallas kernel itself;
 * a decode step issues exactly one grouped launch per MoE layer;
 * prefill can share the same grouped implementation via
-  ``ShardCtx(moe_dispatch='grouped')``.
+  ``ShardCtx(moe_dispatch='grouped')``;
+* decode's ragged dispatch (``moe.ragged_dispatch``, its kernel in
+  interpret mode) equals the capacity dispatch, keeps the fused chunk
+  token-identical to per-module decode at 64 experts, and counts the rows
+  its kernel computes.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from dataclasses import replace
 
@@ -191,3 +198,100 @@ def test_grouped_prefill_shares_decode_path():
     d = float(jnp.max(jnp.abs(lg.astype(jnp.float32) -
                               lr.astype(jnp.float32)))) / scale
     assert d < 0.05, d
+
+
+@pytest.mark.parametrize("T,cap", [(40, 40), (40, 3), (200, 200), (200, 30)])
+def test_ragged_dispatch_equals_grouped(T, cap):
+    """Same y, kept, dropped and load as the capacity dispatch, dropless
+    (capacity = T) and where copies drop; both kernels in interpret mode."""
+    cfg = get_config("olmoe-1b-7b", smoke=True)
+    p = moe_mod.init_moe_params(cfg, KEY)
+    xt = (jax.random.normal(KEY, (T, cfg.d_model)) * 0.3).astype(jnp.bfloat16)
+    gates, idx, _ = moe_mod.route(cfg, p["router"], xt)
+    args = (cfg, xt, gates, idx, p["experts_w_gate"], p["experts_w_up"],
+            p["experts_w_down"], cap)
+    got = moe_mod.ragged_dispatch(*args, use_kernel=True)
+    want = moe_mod.grouped_dispatch(*args, use_kernel=True)
+    for g, w in zip(got, want):
+        assert jnp.array_equal(g, w)
+    assert (int(got[2]) > 0) == (cap < T)
+    # off the TPU the ragged dispatch is the capacity einsum itself
+    auto = moe_mod.ragged_dispatch(*args)
+    fallback = moe_mod.grouped_dispatch(*args, use_kernel=False)
+    for g, w in zip(auto, fallback):
+        assert jnp.array_equal(g, w)
+
+
+def _tiny_deepseek_64():
+    """The tiny DeepSeek of tests/test_deepseek.py (1 dense + 2 MoE
+    layers, shared experts, un-renormalised gates) at DeepSeek-V2-Lite's
+    64 routed experts, top-6."""
+    return replace(
+        get_config("deepseek-v2-lite"), name="deepseek-v2-lite-tiny",
+        num_layers=3, d_model=64, vocab_size=256, num_heads=4,
+        num_kv_heads=4, head_dim=48, kv_lora_rank=32, qk_nope_head_dim=32,
+        qk_rope_head_dim=16, v_head_dim=32, d_ff=128, num_experts=64,
+        experts_per_token=6, moe_d_ff=32, num_shared_experts=2)
+
+
+def test_ragged_fused_chunk_matches_per_module_at_64_experts(monkeypatch):
+    """The fused chunk inlines the per-module decode's ragged MoE stage:
+    token for token the same, with the ragged kernel (interpret mode)
+    running in both, and the same kernel rows counted."""
+    traced = []
+
+    def with_kernel(*a, **kw):
+        traced.append(1)
+        return ragged(*a, **kw, use_kernel=True)
+
+    ragged = moe_mod.ragged_dispatch
+    monkeypatch.setattr(moe_mod, "ragged_dispatch", with_kernel)
+    # a name of its own: no jitted module traced without the kernel is reused
+    cfg = replace(_tiny_deepseek_64(), name="deepseek-v2-lite-tiny-ragged")
+    params = M.init_params(cfg, KEY)
+    toks = jax.random.randint(jax.random.PRNGKey(2), (4, 8), 0,
+                              cfg.vocab_size)
+    plan = Plan(B=4, b_a=2, b_e=4, omega=0.0, decode_chunk=4)
+    out, rows = [], []
+    for fused in (False, True):
+        eng = ModuleBatchingEngine(cfg, params, plan, max_seq=16,
+                                   fused_decode=fused)
+        out.append(np.asarray(eng.generate(toks, 6)))
+        rows.append(eng.stats.decode_expert_rows)
+        assert eng.stats.fused_dispatches == (2 if fused else 0)
+    assert traced
+    assert np.array_equal(out[0], out[1])
+    assert rows[0] == rows[1] > 0
+
+
+@pytest.mark.parametrize("streamed", [False, True])
+def test_serve_report_counts_decode_expert_rows(streamed):
+    """With every router zeroed each row routes to experts 0..k-1, so a
+    tick's ragged kernel computes k tiles of ragged_tile(4) = 16 rows in
+    each MoE layer; the report sums that over layers and ticks, from the
+    fused chunk and from the predictive-streamed per-module stage."""
+    from repro.serving.server import (Request, ServeConfig, Server,
+                                      StreamConfig)
+
+    cfg = _tiny_deepseek_64()
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, a: (jnp.zeros_like(a)
+                         if "router" in jax.tree_util.keystr(path) else a),
+        M.init_params(cfg, KEY))
+    server = Server(cfg, params,
+                    Plan(B=4, b_a=2, b_e=4, omega=0.0, decode_chunk=4),
+                    ServeConfig(max_seq=32, max_batch=4),
+                    StreamConfig(stream_weights=True, resident_bytes=0,
+                                 predict_topk=6) if streamed
+                    else StreamConfig())
+    for n in (7, 12, 5, 9):
+        server.submit(Request(prompt=np.arange(n, dtype=np.int32),
+                              decode_len=6))
+    while server.step():
+        pass
+    rep = server.finalize()
+    assert (rep.weight_htod_bytes > 0) == streamed
+    ticks = rep.decode_slot_steps // 4
+    assert ticks > 0
+    assert moe_mod.ragged_tile(4) == 16
+    assert rep.decode_expert_rows == 2 * ticks * cfg.experts_per_token * 16

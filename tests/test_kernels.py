@@ -58,6 +58,58 @@ def test_expert_ffn_padding_path():
     assert jnp.max(jnp.abs(got - want)) < 1e-4
 
 
+# T rows routed over E experts: each case gives the copies of each expert
+# (at most T, since a row routes to an expert once).  The row tile is
+# ``moe.ragged_tile(T)`` and the buffer ``moe.ragged_buffer_rows`` rows, as
+# the decode dispatch lays them out.
+RAGGED_CASES = {
+    "experts-with-no-copies": (24, [24, 0, 5, 0]),
+    "one-expert-over-a-tile": (200, [150, 30, 0]),
+    "all-copies-on-one-expert": (64, [64, 0, 0, 0]),
+    "trailing-unused-tiles": (40, [3, 2, 1, 0]),
+    "T-below-128": (100, [60, 100, 40]),
+    "T-above-128": (300, [130, 300, 170, 0]),
+}
+
+
+@pytest.mark.parametrize("case", list(RAGGED_CASES))
+def test_ragged_expert_ffn(case):
+    """Each used tile is its expert's FFN (the oracle over the same tiles);
+    experts without copies take no tile, an expert over the tile takes
+    two, and tiles past the used count are not computed."""
+    from repro.models import moe as moe_mod
+
+    T, copies = RAGGED_CASES[case]
+    E, D, F = len(copies), 256, 256
+    k = -(-sum(copies) // T)
+    tm = moe_mod.ragged_tile(T)
+    R = moe_mod.ragged_buffer_rows(T, k, E, T)
+    tiles = [-(-c // tm) for c in copies]
+    n_used = sum(tiles)
+    assert n_used * tm <= R
+    if case == "trailing-unused-tiles":
+        assert n_used < R // tm
+    tile_expert = [e for e, n in enumerate(tiles) for _ in range(n)]
+    tile_expert += [E - 1] * (R // tm - n_used)
+    ks = jax.random.split(KEY, 4)
+    x = _rand((R, D), jnp.bfloat16, 0.3, ks[0])
+    # rows past each expert's copies are the dispatch's zeros
+    live = jnp.concatenate([
+        jnp.arange(t * tm) < c for c, t in zip(copies, tiles)
+    ] + [jnp.zeros((R - n_used * tm,), bool)])
+    x = jnp.where(live[:, None], x, 0)
+    wg = _rand((E, D, F), jnp.bfloat16, 0.05, ks[1])
+    wu = _rand((E, D, F), jnp.bfloat16, 0.05, ks[2])
+    wd = _rand((E, F, D), jnp.bfloat16, 0.05, ks[3])
+    te = jnp.asarray(tile_expert, jnp.int32)
+    got = ops.ragged_expert_ffn(x, te, n_used, wg, wu, wd, block_m=tm,
+                                interpret=True)
+    want = ref.ragged_expert_ffn_ref(x, te, n_used, wg, wu, wd, tm)
+    got = got[:n_used * tm].astype(jnp.float32)
+    assert jnp.max(jnp.abs(got - want.astype(jnp.float32))) < TOL[jnp.bfloat16]
+    assert not jnp.any(got[~live[:n_used * tm]])   # empty rows compute 0
+
+
 # ---------------------------------------------------------------------------
 # Flash decode attention
 # ---------------------------------------------------------------------------

@@ -89,6 +89,33 @@ def test_expert_ffn_kernel_compiles_at_mixtral_widths(one_chip, tpu_compile):
     assert _fits(compiled)
 
 
+@pytest.mark.parametrize("T,E,k,D,F", [
+    (512, 64, 6, 2048, 1408),      # DeepSeek-V2-Lite decode, batch 512
+    (64, 8, 2, 4096, 14336),       # Mixtral-8x7B resident decode, batch 64
+    (192, 8, 2, 4096, 14336),      # Mixtral-8x7B offload decode, batch 192
+], ids=["deepseek-512", "mixtral-64", "mixtral-192"])
+def test_ragged_expert_ffn_compiles_at_decode_widths(one_chip, tpu_compile,
+                                                     T, E, k, D, F):
+    """The ragged decode kernel over the buffer ``ragged_dispatch`` lays
+    out for a dropless batch of ``T`` rows (capacity = T)."""
+    from repro.models import moe as moe_mod
+
+    tm = moe_mod.ragged_tile(T)
+    R = moe_mod.ragged_buffer_rows(T, k, E, T)
+    x = _spec(one_chip, (R, D))
+    tiles = _spec(one_chip, (R // tm,), jnp.int32)
+    used = _spec(one_chip, (1,), jnp.int32)
+    wg = _spec(one_chip, (E, D, F))
+    wd = _spec(one_chip, (E, F, D))
+    compiled = jax.jit(
+        lambda *a: kernel_ops.ragged_expert_ffn(*a, block_m=tm,
+                                                interpret=False)
+    ).lower(x, tiles, used, wg, wg, wd).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%expert_ffn" in text
+    assert _fits(compiled)
+
+
 def test_attn_decode_module_compiles_at_full_width(one_chip, tpu_compile):
     cfg = get_config("mixtral-8x7b")
     B, span = 16, 544
