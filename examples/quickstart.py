@@ -31,7 +31,7 @@ def main() -> None:
     prompts = jax.random.randint(
         jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size
     )
-    plan = Plan(B=B, b_a=4, b_e=64, omega=0.5)
+    plan = Plan(B=B, b_a=4, b_e=64, omega=0.0)
     engine = ModuleBatchingEngine(cfg, params, plan, max_seq=S + DEC)
     tokens = engine.generate(prompts, DEC)
     print("\nengine generated", tokens.shape, "tokens")

@@ -5,39 +5,34 @@ This is the real thing, not the cost model: given a model's parameters and a
 batched computations —
 
 * the attention module consumes micro-batches of ``b_a`` sequences; outputs
-  accumulate in host memory until all ``B`` sequences are ready;
-* a fraction ``ω`` of each attention batch takes the *host-path* mechanism
-  (``core.host_attention``: the paper's BF16-consistent FP32 arithmetic).
-  Like every module it is a jit on the engine's device, so on a TPU those
-  rows run on the chip; moving them to the host CPU is not built yet;
-* the sparse-MoE stage runs as ONE **grouped dispatch**: routed tokens are
-  gathered on device into an ``(E, C, D)`` capacity buffer (``C`` = the
-  plan's per-expert token budget ``b_e``), pushed through a single grouped
-  FFN launch (Pallas on TPU, XLA einsum elsewhere — ``kernels.ops``), and
+  accumulate until all ``B`` sequences are ready.  Every row attends on the
+  engine's device: the paper's host-CPU attention share ω is modelled by
+  the planner but not built here, so an engine refuses a plan with ω > 0;
+* the decode MoE stage runs as ONE **ragged grouped dispatch**
+  (``moe.ragged_dispatch``): each expert's routed copies are gathered on
+  device in whole row tiles, pushed through a single grouped FFN launch
+  (Pallas on TPU, XLA einsum elsewhere — ``kernels.ops``), and
   scatter-added back weighted by their gates.  Routing indices never leave
-  the device, so a decode step issues no host syncs; routed copies beyond
-  capacity are dropped and accounted in ``EngineStats``;
+  the device, so a decode step issues no host syncs; copies beyond the
+  plan's per-expert budget ``b_e`` are dropped and accounted in
+  ``EngineStats``;
 * dense modules (SSM blocks, shared FFNs, lm_head) run at full batch.
 
 **Fused donated decode (the §4/Fig. 5 few-large-launches thesis applied to
 the decode hot path).**  When every weight is device-resident
-(``ParamStore.fully_resident``) and the expert path is ``'grouped'``, decode
-leaves the per-module dispatch loop entirely: ``decode_chunk`` runs embed →
-the whole layer schema → head → per-slot sampling as ONE jitted launch
-(``_fused_decode_chunk``), with the KV/SSM cache pytree passed in and out
-under buffer DONATION and written in place via ``lax.dynamic_update_slice``
-— no functional whole-cache copies survive.  A ``lax.scan`` over ``T``
-decode ticks keeps the sampled tokens, per-slot positions and sampler
+(``ParamStore.fully_resident``), decode leaves the per-module dispatch loop
+entirely: ``decode_chunk`` runs embed → the whole layer schema → head →
+per-slot sampling as ONE jitted launch (``_fused_decode_chunk``), with the
+KV/SSM cache pytree passed in and out under buffer DONATION and written in
+place — no functional whole-cache copies survive.  A ``lax.scan`` over
+``T`` decode ticks keeps the sampled tokens, per-slot positions and sampler
 token-indices entirely in-carry on device, so steady-state decode costs one
 Python dispatch per ``T`` tokens instead of O(layers·modules·T).  Path
-selection is automatic: streamed residency keeps the per-layer loop (the
-htod prefetch needs the layer boundary to hide behind), ``expert_path=
-'loop'`` keeps the oracle loop, and the ω host-attention rows are kept
-OUTSIDE the fused launch — rows ``[0, round(ω·B))`` decode through the
-per-module host-path modules while the remaining rows ride the fused
-launch (batch rows are independent, so the split is exact).  Fused and
-per-module decode are property-tested token-for-token identical
-(tests/test_fused_decode.py, tests/test_properties.py).
+selection is automatic (``fused_eligible``): streamed residency, host-tier
+KV pages and a mesh keep the per-layer loop (the htod prefetch needs the
+layer boundary to hide behind).  Fused and per-module decode are
+property-tested token-for-token identical (tests/test_fused_decode.py,
+tests/test_properties.py).
 
 **Donation contract.**  The engine OWNS the cache pytree between ticks:
 ``decode_chunk`` (and the per-micro-batch attention/SSM modules, and
@@ -55,20 +50,20 @@ mixers/norms, then expert stacks — ``workload.plan_residency``, the same
 policy the planner's cost model charges misses with) and keeps the rest
 host-side, served through a double-buffered in-flight window sized by
 ``Plan.s_expert``: the engine issues the async htod prefetch of layer
-*l+1*'s streamed modules before launching layer *l*'s FFN/grouped GEMM, so
-the copy hides behind compute with no host syncs.  Streamed generation is
-token-for-token identical to fully-resident generation (property-tested in
-tests/test_weights.py); transfer bytes and stall seconds are folded into
-``EngineStats`` by ``sync_stats()``.
+*l+1*'s streamed modules as soon as layer *l*'s acquire returns, so the
+copy hides behind *l*'s compute with no host syncs.  Streamed generation
+is token-for-token identical to fully-resident generation (property-tested
+in tests/test_weights.py); transfer bytes and stall seconds are folded
+into ``EngineStats`` by ``sync_stats()``.
 
 Prefill shares the layer-major structure: each layer's weights are acquired
 ONCE and reused across all ``b_a``-sequence micro-batches (module-based
-batching's weight amortization), and the MoE stage runs through the same
-grouped dispatch as decode (``grouped_prefill=True``, the default) with the
-capacity auto-raised to the micro-batch token count so no routed copy is
-ever dropped; ``grouped_prefill=False`` opts prefill back into the exact
-dense-combine reference MoE, and ``expert_path='loop'`` opts decode into
-the seed's sequential per-expert loop.
+batching's weight amortization), and each MoE layer splits into a
+mixer+route launch and a grouped-FFN launch whose capacity is the next
+power of two over the micro-batch's measured max expert load, so no routed
+copy is ever dropped (``grouped_prefill=True``, the default);
+``grouped_prefill=False`` opts prefill back into the exact dense-combine
+reference MoE.
 
 Outputs are bit-compatible with the reference ``models.decode_step`` up to
 bf16 accumulation order (asserted in tests/test_engine.py).  Every module is
@@ -93,7 +88,6 @@ from repro.analysis.spans import span
 from repro.configs.base import ModelConfig
 from repro.core import workload as W
 from repro.core.dag_builder import Plan
-from repro.core.host_attention import host_decode_attention
 from repro.models import attention as attn_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
@@ -138,7 +132,7 @@ def _counted(fn):
 @functools.partial(jax.jit, static_argnames=("cfg", "lo"),
                    donate_argnames=("k", "v"))
 def _attn_decode_module(cfg, lo, p, x_mb, k, v, pos):
-    """Device-path decode attention over batch rows ``[lo, lo+n)``.
+    """Decode attention over the micro-batch of batch rows ``[lo, lo+n)``.
 
     ``k``/``v`` are the layer's FULL ``(B, span, ...)`` cache buffers,
     DONATED: the micro-batch's rows are sliced out, updated, and written
@@ -172,73 +166,15 @@ def _mla_decode_module(cfg, lo, p, x_mb, ckv, pos):
 
 
 @_counted
-@register_jit("engine.attn_decode_host", donated=("k", "v"))
-@functools.partial(jax.jit, static_argnames=("cfg", "lo"),
-                   donate_argnames=("k", "v"))
-def _attn_decode_host_module(cfg, lo, p, x_mb, k, v, pos):
-    """Host-path attention: the paper's BF16-consistent mechanism (§B)
-    for the ω rows.  The whole module — projections and mechanism — is a
-    jit on the engine's device (the chip, on a TPU), not on the host CPU.
-    Same donated row-block cache contract as ``_attn_decode_module``."""
-    from repro.models.layers import apply_rope
-
-    B = x_mb.shape[0]
-    h = rms_norm(x_mb[:, None, :], p["norm1"], cfg.norm_eps)
-    q, k_new, v_new = attn_mod._project_qkv(cfg, p["attn"], h)
-    posv = jnp.broadcast_to(
-        jnp.atleast_1d(jnp.asarray(pos, jnp.int32)), (B,)
-    )                                                       # (B,) ragged-safe
-    posb = posv[:, None]
-    q = apply_rope(q, posb, cfg.rope_theta)
-    k_new = apply_rope(k_new, posb, cfg.rope_theta)
-    span = k.shape[1]
-    slot = jnp.where(cfg.sliding_window > 0, posv % span,
-                     jnp.minimum(posv, span - 1))
-    rows = jnp.arange(B)
-    ck = lax.dynamic_slice_in_dim(k, lo, B, axis=0)
-    cv = lax.dynamic_slice_in_dim(v, lo, B, axis=0)
-    ck = ck.at[rows, slot].set(k_new[:, 0])
-    cv = cv.at[rows, slot].set(v_new[:, 0])
-    out = host_decode_attention(q[:, 0], ck, cv, posv)      # (B, H, D) f32
-    o = out.reshape(B, 1, cfg.num_heads * cfg.head_dim).astype(x_mb.dtype)
-    y = o @ p["attn"]["wo"]
-    k = lax.dynamic_update_slice_in_dim(k, ck, lo, axis=0)
-    v = lax.dynamic_update_slice_in_dim(v, cv, lo, axis=0)
-    return y[:, 0], k, v
-
-
-@_counted
 @register_jit("engine.ssm_decode", donated=("h", "conv"))
-@functools.partial(jax.jit, static_argnames=("cfg", "lo"),
+@functools.partial(jax.jit, static_argnames=("cfg",),
                    donate_argnames=("h", "conv"))
-def _ssm_decode_module(cfg, lo, p, x, h, conv):
-    """SSM decode over batch rows ``[lo, lo+n)`` with the state buffers
-    donated and written back as row blocks (same contract as attention)."""
-    n = x.shape[0]
-    sh = lax.dynamic_slice_in_dim(h, lo, n, axis=0)
-    sc = lax.dynamic_slice_in_dim(conv, lo, n, axis=0)
+def _ssm_decode_module(cfg, p, x, h, conv):
+    """SSM decode over every batch row, with the state buffers donated and
+    returned updated (the cache contract of the attention modules)."""
     z = rms_norm(x[:, None, :], p["norm1"], cfg.norm_eps)
-    y, state = ssm_mod.ssm_decode(cfg, p["ssm"], z, {"h": sh, "conv": sc})
-    h = lax.dynamic_update_slice_in_dim(h, state["h"], lo, axis=0)
-    conv = lax.dynamic_update_slice_in_dim(conv, state["conv"], lo, axis=0)
-    return y[:, 0], h, conv
-
-
-@_counted
-@register_jit("engine.router")
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _router_module(cfg, router_w, h):
-    return moe_mod.route(cfg, router_w, h)
-
-
-@_counted
-@register_jit("engine.expert")
-@jax.jit
-def _expert_module(wg, wu, wd, h_chunk):
-    """One expert over a chunk of tokens (the 'loop' oracle path's unit)."""
-    g = h_chunk @ wg
-    u = h_chunk @ wu
-    return (jax.nn.silu(g) * u) @ wd
+    y, state = ssm_mod.ssm_decode(cfg, p["ssm"], z, {"h": h, "conv": conv})
+    return y[:, 0], state["h"], state["conv"]
 
 
 def _grouped_expert_math(cfg, p, x, capacity):
@@ -318,26 +254,11 @@ def _grouped_ffn_module(cfg, capacity, h, gates, idx, wg, wu, wd,
 
 
 @_counted
-@register_jit("engine.shared_experts")
-@jax.jit
-def _shared_module(shared, h):
-    """The shared experts over every row (the loop oracle's unit)."""
-    return moe_mod.shared_experts({"shared": shared}, h)
-
-
-@_counted
 @register_jit("engine.ffn")
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _ffn_module(cfg, p, x):
     h = rms_norm(x, p["norm2"], cfg.norm_eps)
     return ffn_apply(p["ffn"], h)
-
-
-@_counted
-@register_jit("engine.norm2")
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _norm2_module(cfg, p, x):
-    return rms_norm(x, p["norm2"], cfg.norm_eps)
 
 
 def _head_math(cfg, tie, params, x):
@@ -436,7 +357,7 @@ def _prefill_moe_ffn_module(cfg, capacity, moe_p, x, xt, gates, idx):
                    donate_argnames=("pk", "pv"))
 def _paged_attn_decode_module(cfg, span, p, x_mb, pk, pv, ek, ev, frames,
                               pos, wpage, wframe):
-    """Device-path decode attention over paged KV.
+    """Decode attention over paged KV.
 
     ``pk``/``pv`` are the layer's DONATED device page pools
     ``(P+1, pt, K, hd)`` (frame ``P`` is the null write sink); ``ek``/``ev``
@@ -486,50 +407,6 @@ def _paged_attn_decode_module(cfg, span, p, x_mb, pk, pv, ek, ev, frames,
 
 
 @_counted
-@register_jit("engine.paged_attn_host")
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _paged_attn_host_module(cfg, p, x_mb, gk, gv, pos):
-    """Host-path attention over GATHERED page rows: identical math to
-    ``_attn_decode_host_module`` (projections + rope on device, the §B
-    BF16-consistent mechanism via ``host_decode_attention``), but the
-    cache rows arrive pre-assembled from the host/device page pools
-    instead of sliced from a contiguous buffer.  Returns the written
-    ``k_new``/``v_new`` so the engine mirrors them into the right frame."""
-    from repro.models.layers import apply_rope
-
-    B = x_mb.shape[0]
-    h = rms_norm(x_mb[:, None, :], p["norm1"], cfg.norm_eps)
-    q, k_new, v_new = attn_mod._project_qkv(cfg, p["attn"], h)
-    posv = jnp.broadcast_to(
-        jnp.atleast_1d(jnp.asarray(pos, jnp.int32)), (B,)
-    )
-    posb = posv[:, None]
-    q = apply_rope(q, posb, cfg.rope_theta)
-    k_new = apply_rope(k_new, posb, cfg.rope_theta)
-    span = gk.shape[1]
-    slot = jnp.where(cfg.sliding_window > 0, posv % span,
-                     jnp.minimum(posv, span - 1))
-    rows = jnp.arange(B)
-    ck = gk.at[rows, slot].set(k_new[:, 0])
-    cv = gv.at[rows, slot].set(v_new[:, 0])
-    out = host_decode_attention(q[:, 0], ck, cv, posv)
-    o = out.reshape(B, 1, cfg.num_heads * cfg.head_dim).astype(x_mb.dtype)
-    y = o @ p["attn"]["wo"]
-    return y[:, 0], k_new[:, 0], v_new[:, 0]
-
-
-@_counted
-@register_jit("engine.paged_slot_write", donated=("pk", "pv"))
-@functools.partial(jax.jit, donate_argnames=("pk", "pv"))
-def _paged_slot_write_module(pk, pv, frames, offs, kvals, vvals):
-    """Single-slot pool writes for host-path rows whose written page
-    spilled onto a device frame; padded to a fixed width with null-frame
-    sentinels (the sink absorbs the padding writes)."""
-    return (pk.at[frames, offs].set(kvals),
-            pv.at[frames, offs].set(vvals))
-
-
-@_counted
 @register_jit("engine.suffix_layer")
 @functools.partial(jax.jit, static_argnames=("cfg", "ffn", "sctx"))
 def _suffix_layer_module(cfg, ffn, sctx, p, x, pk, pv, pos0):
@@ -569,11 +446,11 @@ def _suffix_layer_module(cfg, ffn, sctx, p, x, pk, pv, pos0):
 @register_jit("engine.fused_decode_chunk", donated=("cache",))
 @functools.partial(
     jax.jit,
-    static_argnames=("cfg", "schema", "tie", "capacity", "lo", "pos_cap",
+    static_argnames=("cfg", "schema", "tie", "capacity", "pos_cap",
                      "use_topk", "greedy_only", "T"),
     donate_argnames=("cache",),
 )
-def _fused_decode_chunk(cfg, schema, tie, capacity, lo, pos_cap, use_topk,
+def _fused_decode_chunk(cfg, schema, tie, capacity, pos_cap, use_topk,
                         greedy_only, T, base, layer_params, tokens, pos,
                         live, cache, keys, steps, temps, topks):
     """The fused donated decode macro-step: embed → every layer of the
@@ -586,12 +463,10 @@ def _fused_decode_chunk(cfg, schema, tie, capacity, lo, pos_cap, use_topk,
       per-row scatter on the aliased buffers, so no whole-cache copy is
       ever materialized, and the caller's previous cache arrays are
       invalidated (the engine owns the pytree between ticks).
-    * ``tokens``/``pos`` are the ``n`` fused rows' current tokens and
-      positions (rows ``[lo, lo+n)`` of the batch — the ω host-path rows
-      ``[0, lo)`` stay OUTSIDE this launch); both advance in-carry, with
-      positions clamped at ``pos_cap`` exactly like the per-module
-      scheduler tick.
-    * ``live`` (n,) bool marks rows owned by an unfinished request: a dead
+    * ``tokens``/``pos`` are the batch rows' current tokens and positions;
+      both advance in-carry, with positions clamped at ``pos_cap`` exactly
+      like the per-module scheduler tick.
+    * ``live`` (B,) bool marks rows owned by an unfinished request: a dead
       (recycled/free) row's carry is HELD — it re-feeds its stale token at
       its stale position every tick, exactly like per-tick stepping, where
       the scheduler never updates a free slot's ``_cur``/``_pos``.  This is
@@ -603,12 +478,11 @@ def _fused_decode_chunk(cfg, schema, tie, capacity, lo, pos_cap, use_topk,
       advancing in-carry, so seeded streams are bit-identical to
       per-module decode.
 
-    Returns ``(toks (n, T), cache, kept, dropped, load, rows)`` —
+    Returns ``(toks (B, T), cache, kept, dropped, load, rows)`` —
     ``dropped`` a per-MoE-layer (n_moe,) vector, ``load`` the (n_moe, E)
     per-expert routed-copy histogram and ``rows`` the ragged expert
     kernel's computed rows, all accumulated in-carry on device.
     """
-    n = tokens.shape[0]
     n_moe = sum(1 for _, f in schema if f == "moe")
     E = max(1, cfg.num_experts)
     # optimization barriers mark the per-module boundaries inside the one
@@ -628,40 +502,23 @@ def _fused_decode_chunk(cfg, schema, tie, capacity, lo, pos_cap, use_topk,
         for li, (kind, ffn) in enumerate(schema):
             p = layer_params[li]
             if kind == "attn":
-                k, v = cache[li]["k"], cache[li]["v"]
                 h = rms_norm(x[:, None, :], p["norm1"], cfg.norm_eps)
-                ck = lax.dynamic_slice_in_dim(k, lo, n, axis=0)
-                cv = lax.dynamic_slice_in_dim(v, lo, n, axis=0)
-                y, upd = attn_mod.attn_decode(
-                    cfg, p["attn"], h, {"k": ck, "v": cv}, posv
-                )
-                nk = lax.dynamic_update_slice_in_dim(k, upd["k"], lo, 0)
-                nv = lax.dynamic_update_slice_in_dim(v, upd["v"], lo, 0)
-                y, nk, nv = bar((y[:, 0], nk, nv))
+                y, upd = attn_mod.attn_decode(cfg, p["attn"], h, cache[li],
+                                              posv)
+                y, nk, nv = bar((y[:, 0], upd["k"], upd["v"]))
                 cache[li] = {"k": nk, "v": nv}
                 x = bar(x + y)
             elif kind == "mla":
-                c = cache[li]["ckv"]
                 h = rms_norm(x[:, None, :], p["norm1"], cfg.norm_eps)
-                cc = lax.dynamic_slice_in_dim(c, lo, n, axis=0)
-                y, upd = attn_mod.mla_decode(
-                    cfg, p["mla"], h, {"ckv": cc}, posv
-                )
-                nc = lax.dynamic_update_slice_in_dim(c, upd["ckv"], lo, 0)
-                y, nc = bar((y[:, 0], nc))
+                y, upd = attn_mod.mla_decode(cfg, p["mla"], h, cache[li],
+                                             posv)
+                y, nc = bar((y[:, 0], upd["ckv"]))
                 cache[li] = {"ckv": nc}
                 x = bar(x + y)
             else:
-                hs, cs = cache[li]["h"], cache[li]["conv"]
-                sh = lax.dynamic_slice_in_dim(hs, lo, n, axis=0)
-                sc = lax.dynamic_slice_in_dim(cs, lo, n, axis=0)
                 z = rms_norm(x[:, None, :], p["norm1"], cfg.norm_eps)
-                y, st = ssm_mod.ssm_decode(
-                    cfg, p["ssm"], z, {"h": sh, "conv": sc}
-                )
-                nh = lax.dynamic_update_slice_in_dim(hs, st["h"], lo, 0)
-                nc = lax.dynamic_update_slice_in_dim(cs, st["conv"], lo, 0)
-                y, nh, nc = bar((y[:, 0], nh, nc))
+                y, st = ssm_mod.ssm_decode(cfg, p["ssm"], z, cache[li])
+                y, nh, nc = bar((y[:, 0], st["h"], st["conv"]))
                 cache[li] = {"h": nh, "conv": nc}
                 x = bar(x + y)
             if ffn == "moe":
@@ -707,9 +564,6 @@ class EngineStats:
     expert_launches: int = 0             # grouped: one per MoE layer per step
     expert_tokens: int = 0               # routed token-copies processed
     expert_tokens_dropped: int = 0       # routed copies over the b_e capacity
-    host_attn_tokens: int = 0            # ω rows through the host-path
-    #                                      mechanism (core.host_attention);
-    #                                      it runs on the engine's device
     device_attn_tokens: int = 0          # rows through attn_decode
     weight_htod_bytes: int = 0           # streamed weight bytes copied htod
     prefetch_wait_s: float = 0.0         # stall waiting on weight transfers
@@ -751,32 +605,27 @@ class EngineStats:
 class ModuleBatchingEngine:
     """Executes a batching ``Plan`` over a real model.
 
-    ``expert_path`` selects the MoE stage implementation:
+    **MoE stage.**  Decode runs one jitted ragged grouped-dispatch launch
+    per MoE layer (or one collective dispatch on a mesh ``sctx``); routing
+    stays on device and ``plan.b_e`` bounds the routed copies an expert
+    takes.  Prefill shares the grouped implementation
+    (``grouped_prefill=True``, the default) at a capacity no smaller than
+    the micro-batch's measured max expert load, so zero
+    ``expert_tokens_dropped`` at prefill by construction; pass
+    ``grouped_prefill=False`` for the exact-reference dense-combine prefill.
 
-    * ``'grouped'`` (default) — one jitted grouped-dispatch launch per MoE
-      layer; routing stays on device, ``plan.b_e`` is the per-expert token
-      capacity ``C`` of the ``(E, C, D)`` dispatch buffer.  Prefill shares
-      the same grouped implementation (``grouped_prefill=True``, the
-      default) with the capacity auto-raised to the micro-batch token count
-      (never below, so zero ``expert_tokens_dropped`` at prefill by
-      construction); pass ``grouped_prefill=False`` for the exact-reference
-      dense-combine prefill.
-    * ``'loop'`` — the seed's host-scheduled sequential per-expert loop,
-      kept as the numerical oracle (syncs routing to host every step).
-
-    ``grouped_prefill`` is independent of ``expert_path`` (prefill and
-    decode paths are selected separately), so a loop-decode engine still
-    shares the grouped prefill numerics by default and grouped-vs-loop
-    generation stays token-for-token comparable.
+    **Attention.**  Every batch row attends on the engine's device, in
+    micro-batches of ``plan.b_a`` rows on the per-module path.  The
+    planner's host-attention share ω is not built (ROADMAP A8): a plan
+    with ω > 0 is refused.
 
     **Fused decode selection.**  ``decode_chunk``/``decode_step_sampled``
     take the fused one-launch path automatically when ``fused_decode=True``
-    (default), the expert path is grouped, and the store is fully resident
-    (``fused_eligible()``).  Streamed residency falls back to the
-    per-module loop (the prefetch needs the layer boundary); the ω
-    host-attention rows always decode per-module, outside the fused
-    launch.  ``fused_decode=False`` forces the per-module path — the
-    oracle the fused path is property-tested against.
+    (default), there is no mesh, and the store and the KV pages are fully
+    resident (``fused_eligible()``).  Streamed residency falls back to the
+    per-module loop (the prefetch needs the layer boundary).
+    ``fused_decode=False`` forces the per-module path — the oracle the
+    fused path is property-tested against.
 
     **Weight residency.**  All module stages read parameters through
     ``self.store`` (a ``serving.weights.ParamStore``).  By default every
@@ -793,7 +642,6 @@ class ModuleBatchingEngine:
         params: Dict,
         plan: Plan,
         max_seq: int = 512,
-        expert_path: str = "grouped",
         grouped_prefill: bool = True,
         store: Optional[ParamStore] = None,
         stream_weights: bool = False,
@@ -805,11 +653,15 @@ class ModuleBatchingEngine:
         ep_chunks: int = 1,
         ep_serial: bool = False,
     ) -> None:
-        assert expert_path in ("grouped", "loop"), expert_path
+        if plan.omega > 0:
+            raise ValueError(
+                f"plan.omega={plan.omega}: host-side attention is not built "
+                "(ROADMAP A8); every row attends on the engine's device, so "
+                "plan with omega=0"
+            )
         self.cfg = cfg
         self.plan = plan
         self.max_seq = max_seq
-        self.expert_path = expert_path
         self.grouped_prefill = grouped_prefill
         self.fused_decode = fused_decode
         # mesh engine (ShardCtx threading — the moe_dispatch='a2a'/'psum'
@@ -826,12 +678,6 @@ class ModuleBatchingEngine:
             from repro.distributed.ep_engine import validate_ep_shard
 
             validate_ep_shard(cfg, self.sctx)
-            if expert_path != "grouped":
-                raise ValueError(
-                    "a mesh ShardCtx replaces the grouped MoE stage with "
-                    "the collective dispatch; expert_path='loop' is "
-                    "single-device only"
-                )
             if self.sctx.moe_dispatch == "a2a" and plan.predict_topk > 0:
                 raise ValueError(
                     "moe_dispatch='a2a' does not compose with predictive "
@@ -1029,16 +875,12 @@ class ModuleBatchingEngine:
         """Insert a micro-batch's raw prefill cache into batch rows ``rows``
         of layer ``li``'s decode buffer (``kvcache.insert_prefill_rows``) —
         or, under paging, into the rows' page frames (allocated on first
-        touch; the ω host-attention rows prefer the host tier so the page
-        placement generalizes the math-path split)."""
+        touch, device tier first)."""
         from repro.serving.kvcache import aligned_kv, insert_prefill_rows
 
         if kind == "attn" and self.pages is not None:
             rows_l = [int(r) for r in np.asarray(rows).reshape(-1)]
-            n_host = int(round(self.plan.omega * (self._batch or len(rows_l))))
-            self.pages.ensure_rows(
-                rows_l, prefer_host=[r < n_host for r in rows_l]
-            )
+            self.pages.ensure_rows(rows_l)
             if not self.pages.fully_resident:
                 nk, nv = aligned_kv(
                     self.cfg, entry["k"], entry["v"], self.pages.span
@@ -1072,11 +914,7 @@ class ModuleBatchingEngine:
         (defer / demote / shrink) instead of aborting mid-wave."""
         if self.pages is None:
             return
-        rows_l = [int(r) for r in np.asarray(rows).reshape(-1)]
-        n_host = int(round(self.plan.omega * (self._batch or len(rows_l))))
-        self.pages.ensure_rows(
-            rows_l, prefer_host=[r < n_host for r in rows_l]
-        )
+        self.pages.ensure_rows([int(r) for r in np.asarray(rows).reshape(-1)])
 
     # -- preemption checkpoints -------------------------------------------
     def checkpoint_slot(self, slot: int) -> List[Dict[str, np.ndarray]]:
@@ -1327,16 +1165,15 @@ class ModuleBatchingEngine:
     # -- path selection ---------------------------------------------------
     def fused_eligible(self) -> bool:
         """True when decode can take the fused one-launch path: fused
-        decode enabled, grouped expert dispatch, and EVERY weight resident
-        on device (streamed layers keep the per-layer dispatch loop so the
-        htod prefetch has a layer boundary to overlap with).  Same contract
-        for KV pages: a fully-device-resident page pool (Mode A) keeps the
-        fused path BIT-identical, any host-tier page falls back to the
-        per-layer loop like streamed weights.  A mesh engine (``sctx``)
-        always decodes per-module: the collective MoE stage needs its own
-        launch boundary between the attention and FFN stages."""
-        return (self.fused_decode and self.expert_path == "grouped"
-                and self.sctx is None
+        decode enabled and EVERY weight resident on device (streamed layers
+        keep the per-layer dispatch loop so the htod prefetch has a layer
+        boundary to overlap with).  Same contract for KV pages: a
+        fully-device-resident page pool (Mode A) keeps the fused path
+        BIT-identical, any host-tier page falls back to the per-layer loop
+        like streamed weights.  A mesh engine (``sctx``) always decodes
+        per-module: the collective MoE stage needs its own launch boundary
+        between the attention and FFN stages."""
+        return (self.fused_decode and self.sctx is None
                 and self.store.fully_resident
                 and (self.pages is None or self.pages.fully_resident))
 
@@ -1360,23 +1197,21 @@ class ModuleBatchingEngine:
         dispatches *l*'s attention / SSM and FFN / grouped-GEMM launches
         (the last layer's wraps to layer 0 for the next step).  (The
         fused one-launch path lives in ``decode_chunk``; this method is
-        the per-module oracle and the streamed/loop execution path.)
+        the per-module oracle and the streamed execution path.)
         """
         stale = self._stale_snapshot()
         with sanitizer.allowed("decode-inputs"):
             pos = jnp.asarray(pos, jnp.int32)
             tokens = jnp.asarray(tokens)
         with sanitizer.decode_region():
-            logits = self._decode_rows(tokens, pos, 0)
+            logits = self._decode_rows(tokens, pos)
         self._poison_stale(stale)
         return logits
 
     @hot_path
-    def _decode_rows(self, tokens, pos, row0: int, pos_host=None) -> jax.Array:
-        """Per-module decode over batch rows ``[row0, row0+n)`` — ``tokens``
-        and ``pos`` are the rows' own (n,)/scalar arrays.  The full-batch
-        ``decode_step`` is ``row0=0``; the fused path calls it with the ω
-        host segment so host-path rows decode outside the fused launch.
+    def _decode_rows(self, tokens, pos, pos_host=None) -> jax.Array:
+        """Per-module decode over every batch row — ``tokens`` (B,) and
+        ``pos`` (B,)/scalar.
 
         ``pos_host`` is the rows' positions as a host (numpy) mirror —
         Mode B paging does host-side position math for its page-table
@@ -1391,29 +1226,28 @@ class ModuleBatchingEngine:
         for li, (kind, ffn) in enumerate(self.schema):
             with span("engine.layer", layer=li, phase="decode",
                       mixer=kind, ffn=ffn):
-                x = self._decode_layer(li, kind, ffn, x, pos, row0, pos_host)
+                x = self._decode_layer(li, kind, ffn, x, pos, pos_host)
         return _head_module(cfg, cfg.tie_embeddings, self.store.base, x)
 
     @hot_path
-    def _decode_layer(self, li, kind, ffn, x, pos, row0: int,
-                      pos_host) -> jax.Array:
+    def _decode_layer(self, li, kind, ffn, x, pos, pos_host) -> jax.Array:
         """Layer ``li`` of ``_decode_rows``."""
         cfg = self.cfg
         # predictive-streamed MoE layers skip the full expert-stack
         # assembly in acquire(): the stage fetches only the experts the
         # router actually used (plus LRU hits) and prefetches the
         # predicted set for the next streamed MoE layer
-        predictive = (ffn == "moe" and self.expert_path == "grouped"
+        predictive = (ffn == "moe"
                       and self.store.streams_experts(li - self.n_lead))
         p = self._acquire(li, experts=not predictive)
         self._prefetch(li + 1)          # l+1's copy rides under l's stages
         if kind == "attn":
-            x = x + self._attention_stage(li, p, x, pos, row0, pos_host)
+            x = x + self._attention_stage(li, p, x, pos, pos_host)
         elif kind == "mla":
-            x = x + self._mla_stage(li, p, x, pos, row0)
+            x = x + self._mla_stage(li, p, x, pos)
         else:
             y, h, conv = _ssm_decode_module(
-                cfg, row0, p, x, self.cache[li]["h"], self.cache[li]["conv"]
+                cfg, p, x, self.cache[li]["h"], self.cache[li]["conv"]
             )
             self.cache[li] = {"h": h, "conv": conv}
             x = x + y
@@ -1423,77 +1257,36 @@ class ModuleBatchingEngine:
             if predictive:
                 x = x + self._expert_stage_predictive(li, x)
             else:
-                x = x + self._expert_stage(li, p, x)
+                x = x + self._expert_stage_grouped(li, p, x)
         elif cfg.d_ff > 0 and "ffn" in p:
             x = x + _ffn_module(cfg, p, x)
         return x
 
     # -- module stages ---------------------------------------------------
-    def _attention_stage(self, li, p, x, pos, row0: int = 0,
-                         pos_host=None) -> jax.Array:
-        """Micro-batched attention with the ω host/device split.
-
-        The first ``round(ω·B)`` sequences of the FULL batch take the host
-        path.  A micro-batch straddling that boundary is split at it, so
-        the realized host fraction is exactly ``round(ω·B)/B`` instead of
-        silently rounding a whole micro-batch onto the device path.
+    def _attention_stage(self, li, p, x, pos, pos_host=None) -> jax.Array:
+        """Micro-batched attention, ``plan.b_a`` rows a launch.
 
         The cache buffers are threaded through the donated row-block
         modules — each micro-batch's rows are updated in place; no
         whole-cache functional copy is made.
         """
         if self.pages is not None and not self.pages.fully_resident:
-            return self._paged_attention_stage(li, p, x, pos, row0, pos_host)
-        cfg, plan = self.cfg, self.plan
-        n = x.shape[0]
-        B = self._batch or n
-        n_host = int(round(plan.omega * B))
-        outs = []
-        b_a = max(1, min(plan.b_a, n))
+            return self._paged_attention_stage(li, p, x, pos, pos_host)
+        bounds, mb_xs, mb_ps = self._microbatches(x, pos)
         k, v = self.cache[li]["k"], self.cache[li]["v"]
-        bounds = []
-        lo, end = row0, row0 + n
-        while lo < end:
-            hi = min(end, lo + b_a)
-            if lo < n_host < hi:
-                hi = n_host                    # split the straddling batch
-            bounds.append((lo, hi))
-            lo = hi
-        # one split at static bounds: eager basic slicing would upload
-        # each micro-batch's start index, and on the chip that upload
-        # waits behind the next layer's weight copy in flight
-        sizes = [hi - lo for lo, hi in bounds]
-        mb_xs = jax.lax.split(x, sizes)
-        mb_ps = ([pos] * len(sizes) if pos.ndim == 0
-                 else jax.lax.split(pos, sizes))
+        outs = []
         for (lo, hi), mb_x, mb_pos in zip(bounds, mb_xs, mb_ps):
-            fn = (
-                _attn_decode_host_module if hi <= n_host
-                else _attn_decode_module
-            )
-            y, k, v = fn(cfg, lo, p, mb_x, k, v, mb_pos)
+            y, k, v = _attn_decode_module(self.cfg, lo, p, mb_x, k, v, mb_pos)
             outs.append(y)
             self.stats.attn_microbatches += 1
-            if hi <= n_host:
-                self.stats.host_attn_tokens += hi - lo
-            else:
-                self.stats.device_attn_tokens += hi - lo
+            self.stats.device_attn_tokens += hi - lo
         self.cache[li]["k"], self.cache[li]["v"] = k, v
         return jnp.concatenate(outs, axis=0)
 
-    def _mla_stage(self, li, p, x, pos, row0: int = 0) -> jax.Array:
+    def _mla_stage(self, li, p, x, pos) -> jax.Array:
         """Micro-batched absorbed latent-attention decode over the layer's
-        latent cache, threaded through the donated row-block module.  Every
-        row takes the device path: the ω host-path mechanism is written
-        for per-head keys and values, not the latent cache."""
-        n = x.shape[0]
-        b_a = max(1, min(self.plan.b_a, n))
-        bounds = [(lo, min(row0 + n, lo + b_a))
-                  for lo in range(row0, row0 + n, b_a)]
-        sizes = [hi - lo for lo, hi in bounds]
-        mb_xs = jax.lax.split(x, sizes)
-        mb_ps = ([pos] * len(sizes) if pos.ndim == 0
-                 else jax.lax.split(pos, sizes))
+        latent cache, threaded through the donated row-block module."""
+        bounds, mb_xs, mb_ps = self._microbatches(x, pos)
         ckv = self.cache[li]["ckv"]
         outs = []
         for (lo, hi), mb_x, mb_pos in zip(bounds, mb_xs, mb_ps):
@@ -1504,26 +1297,34 @@ class ModuleBatchingEngine:
         self.cache[li]["ckv"] = ckv
         return jnp.concatenate(outs, axis=0)
 
+    def _microbatches(self, x, pos):
+        """The attention micro-batches of ``plan.b_a`` rows: their
+        ``[lo, hi)`` bounds, and ``x`` and ``pos`` cut at them by one split
+        at static sizes — eager basic slicing would upload each
+        micro-batch's start index, and on the chip that upload waits
+        behind the next layer's weight copy in flight."""
+        n = x.shape[0]
+        b_a = max(1, min(self.plan.b_a, n))
+        bounds = [(lo, min(n, lo + b_a)) for lo in range(0, n, b_a)]
+        sizes = [hi - lo for lo, hi in bounds]
+        mb_ps = ([pos] * len(sizes) if pos.ndim == 0
+                 else jax.lax.split(pos, sizes))
+        return bounds, jax.lax.split(x, sizes), mb_ps
+
     @hot_path
-    def _paged_attention_stage(self, li, p, x, pos, row0: int = 0,
+    def _paged_attention_stage(self, li, p, x, pos,
                                pos_host=None) -> jax.Array:
         """Mode B decode attention (host-tier pages present).
 
-        The ω MATH-path split is unchanged from ``_attention_stage`` — rows
-        ``[0, round(ω·B))`` run the host-attention mechanism, the rest the
-        device mechanism — so paged decode stays token-identical to the
-        contiguous engine.  Page placement only decides where the KV BYTES
-        live: device rows gather their span from the device pool plus the
-        layer's streamed host frames in ONE launch (the htod copy prefetched
-        a layer ahead, like streamed weights); host rows assemble their
-        pages host-side and mirror their written slot back into whichever
-        tier owns the written page.
+        Page placement only decides where the KV BYTES live: every row
+        gathers its span from the device pool plus the layer's streamed
+        host frames in ONE launch (the htod copy prefetched a layer ahead,
+        like streamed weights), and a row whose written page lives on the
+        host tier has its new slot mirrored there.  So paged decode stays
+        token-identical to the contiguous engine.
         """
-        cfg, plan = self.cfg, self.plan
-        pages = self.pages
+        cfg, pages = self.cfg, self.pages
         n = x.shape[0]
-        B = self._batch or n
-        n_host_rows = int(round(plan.omega * B))
         if pos_host is None:                # direct call; planned readback
             with sanitizer.allowed("decode-pos-host-mirror"):
                 pos_host = np.asarray(pos, np.int32)  # lint: allow[MG101] planned once-per-tick position readback for the page table
@@ -1537,82 +1338,29 @@ class ModuleBatchingEngine:
             wslot = np.minimum(pos_np, span - 1)
         wpage = wslot // pt
         woff = wslot % pt
-        rows_all = np.arange(row0, row0 + n)
-        nh = int((rows_all < n_host_rows).sum())   # host rows form a prefix
-        K, hd = cfg.num_kv_heads, cfg.head_dim
-        outs = []
-        if nh:
-            with sanitizer.allowed("paged-host-rows"):
-                gk = np.zeros((nh, span, K, hd), pages._dtype)
-                gv = np.zeros_like(gk)
-                for i in range(nh):
-                    gk[i], gv[i] = pages.read_row(li, int(rows_all[i]), span)
-                y_h, k_new_h, v_new_h = _paged_attn_host_module(
-                    cfg, p, x[:nh], jnp.asarray(gk), jnp.asarray(gv),
-                    jnp.asarray(pos_np[:nh]),
-                )
-                outs.append(y_h)
-                k_np, v_np = np.asarray(k_new_h), np.asarray(v_new_h)  # lint: allow[MG101] host rows own the written slot; planned readback
-                dev_writes = []
-                for i in range(nh):
-                    f = int(pages.page_map[int(rows_all[i]), int(wpage[i])])
-                    if f >= pages.device_frames:
-                        pages.write_host_slot(
-                            li, f - pages.device_frames, int(woff[i]),
-                            k_np[i], v_np[i],
-                        )
-                    elif f >= 0:        # ω row spilled onto a device frame
-                        dev_writes.append((f, int(woff[i]), i))
-                if dev_writes:
-                    width = max(8, -(-len(dev_writes) // 8) * 8)
-                    fr = np.full(width, pages.device_frames, np.int32)  # null
-                    off = np.zeros(width, np.int32)
-                    ksel = np.zeros((width, K, hd), k_np.dtype)
-                    vsel = np.zeros_like(ksel)
-                    for j, (f, o, i) in enumerate(dev_writes):
-                        fr[j], off[j] = f, o
-                        ksel[j], vsel[j] = k_np[i], v_np[i]
-                    pk, pv = _paged_slot_write_module(
-                        pages.pool_k[li], pages.pool_v[li],
-                        jnp.asarray(fr), jnp.asarray(off),
-                        jnp.asarray(ksel), jnp.asarray(vsel),
+        rows = list(range(n))
+        wframe, host_writes = pages.write_targets(rows, wpage)
+        with sanitizer.allowed("paged-index-upload"):
+            frames = jnp.asarray(pages.gather_indices(rows))
+            posd = jnp.asarray(pos_np)
+            wpaged = jnp.asarray(wpage)
+            wframed = jnp.asarray(wframe)
+        ek, ev = pages.acquire(li)
+        y, pk, pv, k_new, v_new = _paged_attn_decode_module(
+            cfg, span, p, x, pages.pool_k[li], pages.pool_v[li],
+            ek, ev, frames, posd, wpaged, wframed,
+        )
+        pages.pool_k[li], pages.pool_v[li] = pk, pv
+        if host_writes:                 # a row's written page is host-side
+            with sanitizer.allowed("paged-host-writeback"):
+                k_np, v_np = np.asarray(k_new), np.asarray(v_new)  # lint: allow[MG101] written page lives on the host tier; planned readback
+                for i, hf in host_writes:
+                    pages.write_host_slot(
+                        li, hf, int(woff[i]), k_np[i], v_np[i]
                     )
-                    pages.pool_k[li], pages.pool_v[li] = pk, pv
-            self.stats.attn_microbatches += 1
-            self.stats.host_attn_tokens += nh
-        nd = n - nh
-        if nd:
-            didx = [int(r) for r in rows_all[nh:]]
-            wframe, host_writes = pages.write_targets(didx, wpage[nh:])
-            with sanitizer.allowed("paged-index-upload"):
-                frames = jnp.asarray(pages.gather_indices(didx))
-                posd = jnp.asarray(pos_np[nh:])
-                wpaged = jnp.asarray(wpage[nh:])
-                wframed = jnp.asarray(wframe)
-            with sanitizer.allowed("decode-row-slice"):
-                xd = x[nh:]
-            ek, ev = pages.acquire(li)
-            y_d, pk, pv, k_new, v_new = _paged_attn_decode_module(
-                cfg, span, p, xd, pages.pool_k[li], pages.pool_v[li],
-                ek, ev, frames, posd, wpaged, wframed,
-            )
-            pages.pool_k[li], pages.pool_v[li] = pk, pv
-            if host_writes:             # device row's written page is host-side
-                with sanitizer.allowed("paged-host-writeback"):
-                    k_np, v_np = np.asarray(k_new), np.asarray(v_new)  # lint: allow[MG101] written page lives on the host tier; planned readback
-                    for i, hf in host_writes:
-                        pages.write_host_slot(
-                            li, hf, int(woff[nh + i]), k_np[i], v_np[i]
-                        )
-            outs.append(y_d)
-            self.stats.attn_microbatches += 1
-            self.stats.device_attn_tokens += nd
-        return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
-
-    def _expert_stage(self, li, p, x) -> jax.Array:
-        if self.expert_path == "grouped":
-            return self._expert_stage_grouped(li, p, x)
-        return self._expert_stage_loop(p, x)
+        self.stats.attn_microbatches += 1
+        self.stats.device_attn_tokens += n
+        return y
 
     def _mesh_moe(self, li, p, x, capacity: int, routed) -> jax.Array:
         """A prefill micro-batch's MoE stage on a mesh engine: the
@@ -1634,7 +1382,7 @@ class ModuleBatchingEngine:
     def _expert_stage_grouped(self, li, p, x) -> jax.Array:
         """One grouped-dispatch launch for the whole MoE stage: routing,
         gather, expert FFNs and combine all stay on device (§4.2 realized
-        as a single module launch instead of a host-scheduled expert loop).
+        as a single module launch).
         A mesh engine routes the same stage through the collective dispatch
         (``repro.distributed.ep_engine``) — counters keep one meaning."""
         if self.sctx is not None:
@@ -1707,43 +1455,6 @@ class ModuleBatchingEngine:
         self._load_dev_l[j] = self._load_dev_l[j] + load
         return y
 
-    def _expert_stage_loop(self, p, x) -> jax.Array:
-        """Sequential per-expert execution (the seed path, kept as the test
-        oracle).  Chunks each expert's gathered tokens by b_e; syncs routing
-        to the host every step — the launch pathology the grouped path
-        removes."""
-        cfg, plan = self.cfg, self.plan
-        moe = p["moe"]
-        h = _norm2_module(cfg, p, x)
-        gates, idx, _ = _router_module(cfg, moe["router"], h)
-        with sanitizer.allowed("expert-loop-oracle"):
-            idx_np = np.asarray(idx)                 # host-side scheduling
-            gates_np = np.asarray(gates)
-            y = jnp.zeros_like(x)
-            b_e = max(1, plan.b_e)
-            for e in range(cfg.num_experts):
-                rows, which = np.nonzero(idx_np == e)
-                if rows.size == 0:
-                    continue
-                w = gates_np[rows, which]
-                for lo in range(0, rows.size, b_e):
-                    r = rows[lo : lo + b_e]
-                    g = w[lo : lo + b_e]
-                    ye = _expert_module(
-                        moe["experts_w_gate"][e],
-                        moe["experts_w_up"][e],
-                        moe["experts_w_down"][e],
-                        h[r],
-                    )
-                    y = y.at[r].add(
-                        ye * jnp.asarray(g)[:, None].astype(ye.dtype)
-                    )
-                    self.stats.expert_launches += 1
-                    self.stats.expert_tokens += int(r.size)
-        if "shared" in moe:
-            y = y + _shared_module(moe["shared"], h)
-        return y
-
     # -- chunked decode ---------------------------------------------------
     def decode_chunk(self, tokens, pos, sampler, T: int,
                      live=None) -> jax.Array:
@@ -1751,13 +1462,11 @@ class ModuleBatchingEngine:
         the ``(B, T)`` token matrix (column *t* is tick *t*'s tokens, fed
         back as tick *t+1*'s input).
 
-        Fused one-launch path when ``fused_eligible()``: device rows ride
-        ONE donated ``_fused_decode_chunk`` launch; the ω host-attention
-        rows ``[0, round(ω·B))`` decode per-module OUTSIDE the launch
-        (rows are independent, so the split is exact up to expert-capacity
-        drops, which are per-dispatch).  Otherwise every row takes the
-        per-module path, one tick at a time.  Positions are clamped at
-        ``max_seq - 1`` exactly like the scheduler's per-tick clamp.
+        Fused one-launch path when ``fused_eligible()``: every row rides
+        ONE donated ``_fused_decode_chunk`` launch.  Otherwise every row
+        takes the per-module path, one tick at a time.  Positions are
+        clamped at ``max_seq - 1`` exactly like the scheduler's per-tick
+        clamp.
 
         ``live`` (B,) bool marks rows owned by unfinished requests (None =
         all).  Dead rows re-feed their stale token/position every tick —
@@ -1780,46 +1489,29 @@ class ModuleBatchingEngine:
     @hot_path
     def _decode_chunk_guarded(self, tokens, pos, sampler, T: int,
                               live=None) -> jax.Array:
-        B = tokens.shape[0]
         if not (self.fused_eligible() and self.cache is not None):
-            return self._chunk_rows_per_module(tokens, pos, sampler, T, 0, B,
-                                               live)
-        n_host = int(round(self.plan.omega * B))
-        if n_host >= B:
-            return self._chunk_rows_per_module(tokens, pos, sampler, T, 0, B,
-                                               live)
-        host_cols = None
-        if n_host:
-            # host-path rows first: their per-module modules update cache
-            # rows [0, n_host) before the fused launch donates the buffers
-            host_cols = self._chunk_rows_per_module(
-                tokens, pos, sampler, T, 0, n_host, live
-            )
-        n = B - n_host
+            return self._chunk_rows_per_module(tokens, pos, sampler, T, live)
+        B = tokens.shape[0]
         posv = jnp.broadcast_to(jnp.atleast_1d(pos), (B,)).astype(jnp.int32)
         with sanitizer.allowed("decode-inputs"):
-            livev = (jnp.ones((B,), bool) if live is None
-                     else jnp.asarray(live, bool))
-        idx = np.arange(n_host, B)
+            livev = jnp.ones((B,), bool) if live is None else live
+        idx = np.arange(B)
         with sanitizer.allowed("sampler-state"):
             keys, steps, temps, topks = sampler.state(idx)
             keys_d, steps_d = jnp.asarray(keys), jnp.asarray(steps)
             temps_d, topks_d = jnp.asarray(temps), jnp.asarray(topks)
         use_topk = bool((topks > 0).any())
         greedy_only = not bool((temps > 0).any())
-        capacity = self._expert_capacity(n)
+        capacity = self._expert_capacity(B)
         cap = self.max_seq - 1
-        key = (n, n_host, T, capacity, cap, use_topk, greedy_only)
+        key = (B, T, capacity, cap, use_topk, greedy_only)
         if self._fused_keys.add(key):
             self.stats.decode_retraces += 1
-        with sanitizer.allowed("decode-row-slice"):
-            toks_d, posv_d = tokens[n_host:], posv[n_host:]
-            livev_d = livev[n_host:]
         toks, cache, kept, dropped, load, rows = _fused_decode_chunk(
             self.cfg, tuple(self.schema), self.cfg.tie_embeddings, capacity,
-            n_host, cap, use_topk, greedy_only, T,
+            cap, use_topk, greedy_only, T,
             self.store.base, self._fused_layer_params(),
-            toks_d, posv_d, livev_d, tuple(self.cache),
+            tokens, posv, livev, tuple(self.cache),
             keys_d, steps_d, temps_d, topks_d,
         )
         self.cache = list(cache)
@@ -1833,45 +1525,39 @@ class ModuleBatchingEngine:
         # the fused launch bundles the per-module work units into one
         # dispatch — keep their accounting equivalent to the per-module
         # path: one grouped-dispatch evaluation per MoE layer per tick,
-        # and every fused row is a device-path attention token per attn
-        # layer per tick (host rows were counted by their per-module pass)
+        # and every row is an attention token per attention layer per tick
         self.stats.expert_launches += T * sum(
             1 for _, f in self.schema if f == "moe"
         )
-        self.stats.device_attn_tokens += n * T * sum(
+        self.stats.device_attn_tokens += B * T * sum(
             1 for k, _ in self.schema if k in ("attn", "mla")
         )
-        if host_cols is None:
-            return toks
-        return jnp.concatenate([host_cols, toks], axis=0)
+        return toks
 
     @hot_path
     def _chunk_rows_per_module(self, tokens, pos, sampler, T: int,
-                               lo: int, hi: int, live=None) -> jax.Array:
-        """Per-module chunk fallback over batch rows ``[lo, hi)``: ``T``
-        sequential decode ticks, each sampled through the caller's
-        ``BatchSampler`` (the streamed / loop-path / host-row execution).
-        Dead rows (``live`` False) hold their stale token/position, like
-        per-tick stepping.
+                               live=None) -> jax.Array:
+        """Per-module chunk fallback over every batch row: ``T`` sequential
+        decode ticks, each sampled through the caller's ``BatchSampler``
+        (the streamed / paged / mesh execution).  Dead rows (``live``
+        False) hold their stale token/position, like per-tick stepping.
 
         The per-tick position advance is HOST math: mixing the Python tick
-        index into device arithmetic (``posr + t``) was an implicit scalar
+        index into device arithmetic (``pos + t``) was an implicit scalar
         h2d transfer every tick — the exact pathology the sanitizer exists
         to catch.  Instead a numpy mirror advances on the host and ONE
-        planned (n,)-vector upload per tick feeds the modules; the uploaded
+        planned (B,)-vector upload per tick feeds the modules; the uploaded
         aval (int32, same shape) is identical, so trace keys are unchanged.
         The mirror also rides down to Mode B paging as ``pos_host``."""
-        slots = np.arange(lo, hi)
-        with sanitizer.allowed("decode-row-slice"):
-            cur = tokens[lo:hi]
-            posr = pos if pos.ndim == 0 else pos[lo:hi]
-            lv = None if live is None else jnp.asarray(live, bool)[lo:hi]
-        if lv is not None and posr.ndim == 0:
-            posr = jnp.broadcast_to(posr, (hi - lo,))
+        B = tokens.shape[0]
+        slots = np.arange(B)
+        cur, posr = tokens, pos
+        if live is not None and posr.ndim == 0:
+            posr = jnp.broadcast_to(posr, (B,))
         with sanitizer.allowed("decode-pos-host-mirror"):
             pos_np = np.asarray(posr, np.int32)  # lint: allow[MG101] one planned readback per chunk; host mirror drives tick advance
-            adv_np = (None if lv is None
-                      else np.asarray(lv, np.int32))  # lint: allow[MG101] live mask readback, once per chunk
+            adv_np = (None if live is None
+                      else np.asarray(live, np.int32))  # lint: allow[MG101] live mask readback, once per chunk
         cap = self.max_seq - 1
         cols = []
         for t in range(T):
@@ -1880,10 +1566,10 @@ class ModuleBatchingEngine:
             ).astype(np.int32)
             with sanitizer.allowed("decode-pos-upload"):
                 pt = jnp.asarray(pt_np)
-            lg = self._decode_rows(cur, pt, lo, pt_np)
+            lg = self._decode_rows(cur, pt, pt_np)
             sampled = sampler.sample(lg, slots)
             cols.append(sampled)
-            cur = sampled if lv is None else jnp.where(lv, sampled, cur)
+            cur = sampled if live is None else jnp.where(live, sampled, cur)
         return jnp.stack(cols, axis=1)
 
     def decode_step_sampled(self, tokens: jax.Array, pos, sampler,

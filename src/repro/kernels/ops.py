@@ -91,8 +91,7 @@ def grouped_expert_ffn(x, wg, wu, wd, use_kernel=None):
 
     One launch covers every expert: the fused Pallas kernel on real TPU, an
     einsum-based XLA path elsewhere.  The fallback uses the same op sequence
-    as a per-expert ``x @ w`` chain (bf16 intermediates), so it is
-    bit-compatible with the engine's sequential-loop oracle; the Pallas
+    as a per-expert ``x @ w`` chain (bf16 intermediates); the Pallas
     kernel keeps an f32 VMEM accumulator and agrees to bf16 rounding
     (tests/test_kernels.py).
     """

@@ -1,8 +1,8 @@
 """JAX's persistent compilation cache, switched on by the entry points.
 
 Only entry points call ``enable_compile_cache`` (``chip_smoke.py``,
-``repro.launch.serve``, ``benchmarks/run.py``); importing a library module
-or running the tests never turns the cache on.
+``repro.launch.serve``); importing a library module or running the tests
+never turns the cache on.
 """
 from __future__ import annotations
 

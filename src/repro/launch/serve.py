@@ -86,9 +86,9 @@ def plan_serving(
     """The paper's search on the config that executes, at the batch it
     serves (``batch`` caps the accumulated batch B).
 
-    The search's ω is pinned to 0: the engine's host-path attention is a
-    jit on the engine's device, so the host-CPU overlap the cost model
-    would credit ω with does not exist yet (ROADMAP A8).  A mesh serves
+    The search's ω is pinned to 0: the engine attends every row on its
+    device and refuses a plan with ω > 0, since the host-CPU attention the
+    cost model would credit ω with is not built (ROADMAP A8).  A mesh serves
     fully resident, so it plans no predictive streaming."""
     res = planner.search_decode(
         cfg, hw, ctx=ctx, B=batch, use_cpu_attention=False,
@@ -121,9 +121,6 @@ def main(argv=None) -> None:
     ap.add_argument("--decode-len", type=int, default=16)
     ap.add_argument("--batch", type=int, default=8,
                     help="accumulated batch B the engine allocates")
-    ap.add_argument("--expert-path", default="grouped",
-                    choices=("grouped", "loop"),
-                    help="MoE stage: grouped dispatch vs per-expert loop")
     ap.add_argument("--scheduler", default="static",
                     choices=("static", "continuous"),
                     help="static accumulated batches vs continuous in-flight "
@@ -280,7 +277,7 @@ def main(argv=None) -> None:
           f"{res.plan.s_expert/1e9:.1f}GB)")
     print(f"predicted decode throughput: {res.estimate.throughput:.0f} tok/s")
     print(f"fused decode chunk T={plan.decode_chunk} "
-          f"({args.scheduler} cadence at B={plan.B}); omega={plan.omega}")
+          f"({args.scheduler} cadence at B={plan.B})")
 
     # 2. weights on the host, placed by the store the server executes
     params = M.init_params_host(cfg, jax.random.PRNGKey(0))
@@ -338,7 +335,7 @@ def main(argv=None) -> None:
               f"ep_chunks={plan.ep_chunks}")
     serve_cfg = ServeConfig(
         scheduler=args.scheduler, decode_len=args.decode_len,
-        eos_id=args.eos_id, expert_path=args.expert_path,
+        eos_id=args.eos_id,
         hw=hw if args.scheduler == "continuous" else None,
         kv_page_tokens=args.kv_page_tokens, device_kv_gb=args.device_kv_gb,
         prefix_cache=args.prefix_cache, sctx=sctx,

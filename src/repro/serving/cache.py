@@ -17,11 +17,9 @@ pages the KV cache into fixed-size ``page_tokens`` blocks behind a
   per-layer loop (exactly like streamed weights): each attention layer's
   host frames stream device-ward through the SAME double-buffered async
   ``device_put`` window ``ParamStore`` uses for weights
-  (``serving.weights.StreamWindow``), the gather reassembles each row's
-  ``span`` from device pool + streamed frames, and the ω host-attention
-  rows read their pages host-side — per-page placement generalizes the ω
-  split (host rows prefer host frames; device rows prefer device frames;
-  either spills into the other tier).
+  (``serving.weights.StreamWindow``), and the gather reassembles each
+  row's ``span`` from device pool + streamed frames.  Frames are allocated
+  device tier first, spilling into the host tier.
 
 On top of the page table, ``PrefixStore`` caches shared prompt prefixes at
 page granularity: a hit is admitted by copying stored page rows instead of
@@ -191,42 +189,35 @@ class KVPageTable:
         )
 
     # -- allocation ------------------------------------------------------
-    def _alloc_frame(self, prefer_host: bool) -> int:
-        a, b = ((self._free_host, self._free_dev) if prefer_host
-                else (self._free_dev, self._free_host))
-        first_is_host = prefer_host
-        if a:
-            f = a.pop()
-            return self.device_frames + f if first_is_host else f
-        if not b:
+    def _alloc_frame(self) -> int:
+        """A free frame: the device tier first, then the host tier."""
+        if self._free_dev:
+            return self._free_dev.pop()
+        if not self._free_host:
             raise faults.PageAllocOOM(
                 "page table out of frames (batch rows exceed capacity?)")
-        f = b.pop()
-        return f if first_is_host else self.device_frames + f
+        return self.device_frames + self._free_host.pop()
 
-    def ensure_rows(self, rows: Sequence[int],
-                    prefer_host: Optional[Sequence[bool]] = None) -> None:
+    def ensure_rows(self, rows: Sequence[int]) -> None:
         """Allocate page frames for ``rows`` (no-op for already-allocated
-        rows — re-inserting into a live slot reuses its placement).
-        ``prefer_host[i]`` biases row ``i`` toward the host tier (the ω
-        host-attention rows); either tier spills into the other.
+        rows — re-inserting into a live slot reuses its placement), device
+        frames first, spilling into the host tier when they run out.
 
         Allocation is transactional per row: on ``PageAllocOOM`` (real
         frame exhaustion, or an injected fault from the armed plan) the
         partially-allocated row is rolled back before the error
         propagates, so the admission layer can defer/degrade and retry
         without leaking frames."""
-        for i, r in enumerate(rows):
+        for r in rows:
             if self.page_map[r, 0] >= 0:
                 continue
             fp = faults.current()
             if fp is not None and fp.page_oom():
                 raise faults.PageAllocOOM(
                     f"injected page-alloc OOM (row {r})")
-            ph = bool(prefer_host[i]) if prefer_host is not None else False
             try:
                 for pp in range(self.pages_per_seq):
-                    self.page_map[r, pp] = self._alloc_frame(ph)
+                    self.page_map[r, pp] = self._alloc_frame()
             except faults.PageAllocOOM:
                 self.free_rows([r])
                 raise
@@ -309,7 +300,7 @@ class KVPageTable:
     def read_row(self, li: int, row: int, n: int) -> Tuple[np.ndarray,
                                                            np.ndarray]:
         """Gather the first ``n`` token slots of ``row``'s layer-``li`` KV
-        as numpy (prefix capture / host-path assembly)."""
+        as numpy (prefix capture / preemption checkpoints)."""
         pt = self.page_tokens
         K, hd = self.cfg.num_kv_heads, self.cfg.head_dim
         out_k = np.zeros((self.pages_per_seq * pt, K, hd), self._dtype)

@@ -43,7 +43,6 @@ def serve_dataset(
     plan: Plan,
     decode_len: int,
     max_seq: Optional[int] = None,
-    expert_path: str = "grouped",
     scheduler: str = "static",
     pad_id: int = 0,
     eos_id: Optional[int] = None,
@@ -74,9 +73,7 @@ def serve_dataset(
     ``scheduler`` selects static accumulated waves vs continuous in-flight
     batching.  Per-request ``decode_len`` is honored (``decode_len`` is the
     fallback for requests with a zero/None field); ``eos_id`` finishes a
-    sequence early.  ``expert_path`` selects the engine's MoE stage
-    ('grouped' = one on-device dispatch per MoE layer, 'loop' = the
-    sequential per-expert oracle).
+    sequence early.
 
     ``stream_weights=True`` executes through the streamed parameter store:
     only the greedy ``resident_bytes`` set (default ``plan.s_params``) is
@@ -111,7 +108,7 @@ def serve_dataset(
         serve=ServeConfig(
             scheduler=scheduler, decode_len=decode_len, max_seq=max_seq,
             max_prompt_len=max_prompt_len, pad_id=pad_id, eos_id=eos_id,
-            expert_path=expert_path, grouped_prefill=grouped_prefill, hw=hw,
+            grouped_prefill=grouped_prefill, hw=hw,
             kv_page_tokens=kv_page_tokens, device_kv_gb=device_kv_gb,
             prefix_cache=prefix_cache, sctx=sctx, ep_chunks=ep_chunks,
             faults=faults,

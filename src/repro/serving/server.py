@@ -103,7 +103,6 @@ class ServeConfig:
     max_prompt_len: Optional[int] = None
     pad_id: int = 0
     eos_id: Optional[int] = None
-    expert_path: str = "grouped"
     grouped_prefill: bool = True
     hw: Optional[HardwareProfile] = None
     decode_chunk: Optional[int] = None   # fused chunk T cap (None = plan's);
@@ -141,7 +140,6 @@ class ServeConfig:
 
     def __post_init__(self) -> None:
         assert self.scheduler in ("static", "continuous"), self.scheduler
-        assert self.expert_path in ("grouped", "loop"), self.expert_path
         assert self.kv_page_tokens >= 0, self.kv_page_tokens
         if self.prefix_cache:
             assert self.kv_page_tokens > 0, (
@@ -161,7 +159,9 @@ class ServeConfig:
         **overrides,
     ) -> "ServeConfig":
         """Size the serving config from the planner: runs
-        ``planner.search_decode(cfg, hw, ctx)`` and pins ``max_batch`` to
+        ``planner.search_decode(cfg, hw, ctx)`` with ω pinned to 0, as
+        ``launch.serve.plan_serving`` pins it (the engine attends every row
+        on its device and refuses ω > 0), and pins ``max_batch`` to
         the plan's B, ``max_seq`` to ``ctx``, and ``hw`` for Eq. 2 gated
         admission — so the server allocates its engine up front instead of
         from whatever happens to be queued at the first step.  ``B`` caps
@@ -173,6 +173,7 @@ class ServeConfig:
         from repro.core.planner import search_decode
 
         plan = search_decode(cfg, hw, ctx, B=B, scheduler=scheduler,
+                             use_cpu_attention=False,
                              decode_len=overrides.get("decode_len")).plan
         kw = dict(scheduler=scheduler, max_seq=ctx, max_batch=plan.B,
                   hw=hw, plan=plan)
@@ -655,7 +656,6 @@ class Server:
             )
         self._engine = ModuleBatchingEngine(
             self.cfg, self.params, self.plan, max_seq=self._max_seq,
-            expert_path=self.serve.expert_path,
             grouped_prefill=self.serve.grouped_prefill, store=self._store,
             cache_config=self._cache_config(),
             sctx=self.serve.sctx, ep_chunks=self.serve.ep_chunks,

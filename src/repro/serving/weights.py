@@ -563,7 +563,7 @@ class ParamStore:
         For predictive-streamed MoE layers, ``experts=False`` returns only
         the mixer + pinned norm2/router — the decode hot path assembles the
         expert stacks itself via ``acquire_experts`` after reading back the
-        routed set.  ``experts=True`` (prefill, loop oracle) assembles the
+        routed set.  ``experts=True`` (prefill) assembles the
         FULL expert stack, bit-identical to whole-stack streaming."""
         merged: Dict = {}
         for tree in self._resident[li].values():

@@ -218,15 +218,14 @@ PROMPTS = [np.arange(n, dtype=np.int32) * 7 % 256 for n in (7, 12, 5, 9)]
 
 
 def test_every_serving_path_serves_the_same_tokens():
-    """Fused decode, the expert loop oracle, per-expert predictive
-    streaming (per-module decode, the shared experts pinned beside the
-    router) and the continuous scheduler agree token for token, and
-    greedy decoding equals the model's own forward in float32."""
+    """Fused decode, per-expert predictive streaming (per-module decode,
+    the shared experts pinned beside the router) and the continuous
+    scheduler agree token for token, and greedy decoding equals the
+    model's own forward in float32."""
     cfg = tiny("float32")
     params = M.init_params(cfg, KEY)
     with jax.default_matmul_precision("highest"):
         fused, _ = _serve(cfg, params, PROMPTS)
-        loop, _ = _serve(cfg, params, PROMPTS, expert_path="loop")
         cont, _ = _serve(cfg, params, PROMPTS, scheduler="continuous")
         streamed, rep = _serve(cfg, params, PROMPTS, StreamConfig(
             stream_weights=True, resident_bytes=0, predict_topk=3))
@@ -238,7 +237,7 @@ def test_every_serving_path_serves_the_same_tokens():
             seqs[i, :len(p) + 6] = np.concatenate([p, t])
         lg, _, _ = M.forward(cfg, params, jnp.asarray(seqs))
         best = np.asarray(jnp.argmax(lg, axis=-1))
-    assert fused == loop == cont == streamed
+    assert fused == cont == streamed
     assert rep.weight_htod_bytes > 0
     for i, p in enumerate(PROMPTS):
         assert list(best[i, len(p) - 1:len(p) + 5]) == fused[i]

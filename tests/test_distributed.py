@@ -264,9 +264,6 @@ def test_mesh_engine_rejects_unsupported_combos():
     with pytest.raises(ValueError, match="predict_topk"):
         ModuleBatchingEngine(cfg, params,
                              replace(plan, predict_topk=2), sctx=sctx)
-    with pytest.raises(ValueError, match="expert_path"):
-        ModuleBatchingEngine(cfg, params, plan, sctx=sctx,
-                             expert_path="loop")
     with pytest.raises(ValueError, match="moe_dispatch"):
         validate_ep_shard(cfg, replace(sctx, moe_dispatch="grouped"))
     # num_experts % n needs n > 1 to fire — exercised in the mesh

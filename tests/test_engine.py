@@ -32,7 +32,7 @@ def test_engine_logits_match_reference(arch):
     noise is chaotically amplified through top-k routing flips.  f32 makes
     the comparison tight (~1e-6), i.e. a STRICTER structural-equivalence
     check; bf16 behavior is covered by the engine-vs-engine token-exactness
-    tests (ragged generate, grouped-vs-loop, streamed-vs-resident)."""
+    tests (ragged generate, fused-vs-per-module, streamed-vs-resident)."""
     from dataclasses import replace
 
     cfg = replace(get_config(arch, smoke=True), dtype="float32")
@@ -54,41 +54,18 @@ def test_engine_logits_match_reference(arch):
     assert float(d1) / scale < 1e-4, d1
 
 
-def test_engine_host_attention_path():
-    """ω=1 (all attention on the host path, §B numerics) stays consistent."""
+@pytest.mark.parametrize("b_a", [2, 4, 6])
+def test_engine_microbatch_counts(b_a):
     cfg, params, toks = _setup("mixtral-8x7b")
-    eng_dev = ModuleBatchingEngine(
-        cfg, params, Plan(B=B, b_a=B, b_e=64, omega=0.0), max_seq=S + DEC
-    )
-    eng_host = ModuleBatchingEngine(
-        cfg, params, Plan(B=B, b_a=B, b_e=64, omega=1.0), max_seq=S + DEC
-    )
-    eng_dev.prefill(toks)
-    eng_host.prefill(toks)
-    nxt = toks[:, 0]
-    l_dev = eng_dev.decode_step(nxt, S)
-    l_host = eng_host.decode_step(nxt, S)
-    scale = float(jnp.max(jnp.abs(l_dev.astype(jnp.float32)))) + 1e-6
-    d = float(jnp.max(jnp.abs(l_dev.astype(jnp.float32) -
-                              l_host.astype(jnp.float32)))) / scale
-    assert d < 0.06, d      # paper §B: BF16-consistent host arithmetic
-    assert eng_host.stats.host_attn_tokens > 0
-    assert eng_host.stats.device_attn_tokens == 0
-
-
-def test_engine_microbatch_counts():
-    cfg, params, toks = _setup("mixtral-8x7b")
-    plan = Plan(B=B, b_a=2, b_e=B, omega=0.5)   # capacity B: no drops
+    plan = Plan(B=B, b_a=b_a, b_e=B, omega=0.0)   # capacity B: no drops
     eng = ModuleBatchingEngine(cfg, params, plan, max_seq=S + DEC)
     eng.prefill(toks)
     eng.stats.attn_microbatches = 0
     eng.decode_step(toks[:, 0], S)
     n_attn_layers = sum(1 for k, _, _ in eng.layers if k == "attn")
-    # host/device segments micro-batched separately (the ω boundary splits
-    # a straddling micro-batch so the realized host fraction is exact)
-    n_host = int(round(plan.omega * B))
-    n_mb = -(-n_host // 2) + -(-(B - n_host) // 2)
-    assert eng.stats.attn_microbatches == n_attn_layers * n_mb
+    # every row attends on the device, in micro-batches of b_a rows
+    assert eng.stats.attn_microbatches == n_attn_layers * -(-B // b_a)
+    assert eng.stats.device_attn_tokens == n_attn_layers * B
     # grouped dispatch: exactly ONE expert launch per MoE layer per step,
     # and every routed token-copy was processed (no capacity drops)
     n_moe_layers = sum(1 for _, f, _ in eng.layers if f == "moe")
@@ -155,21 +132,13 @@ def test_engine_ragged_prefill_unpadded_logits_gather():
     assert jnp.array_equal(lg[B - 1], lg_solo[0])
 
 
-def test_omega_split_realized_host_fraction():
-    """A micro-batch straddling round(ω·B) is split at the boundary, so the
-    realized host fraction equals round(ω·B)/B exactly (the seed ran the
-    straddling micro-batch entirely on the device path)."""
-    cfg, params, toks = _setup("mixtral-8x7b")
-    for omega in (0.5, 0.25, 0.75):
-        eng = ModuleBatchingEngine(
-            cfg, params, Plan(B=B, b_a=4, b_e=B, omega=omega), max_seq=S + DEC
-        )
-        eng.prefill(toks)
-        eng.decode_step(toks[:, 0], S)
-        n_attn = sum(1 for k, _, _ in eng.layers if k == "attn")
-        want_host = int(round(omega * B)) * n_attn
-        assert eng.stats.host_attn_tokens == want_host, omega
-        assert eng.stats.device_attn_tokens == B * n_attn - want_host
+@pytest.mark.parametrize("omega", [0.5, 1.0])
+def test_engine_refuses_host_attention_share(omega):
+    """The host-CPU attention the planner models as ω is not built, so an
+    engine refuses a plan with ω > 0 instead of serving it some other way."""
+    cfg, params, _ = _setup("mixtral-8x7b")
+    with pytest.raises(ValueError, match="host-side attention is not built"):
+        ModuleBatchingEngine(cfg, params, Plan(B=B, b_a=2, b_e=B, omega=omega))
 
 
 def test_unstack_layers_roundtrip():

@@ -1,8 +1,8 @@
 """Grouped-expert dispatch: the engine's vectorized MoE stage.
 
 Covers the PR's contract:
-* grouped vs per-expert-loop engines are token-for-token identical when the
-  per-expert capacity ``b_e`` admits every routed token (loop = oracle);
+* the grouped engine generates the greedy tokens of the model's own
+  forward when the per-expert capacity ``b_e`` admits every routed token;
 * capacity overflow drops are counted in ``EngineStats`` and never crash;
 * the XLA einsum fallback of ``kernels.ops.grouped_expert_ffn`` agrees with
   the Pallas kernel oracle (kernels/ref.py) and with the interpret-mode
@@ -42,26 +42,30 @@ def _setup(arch):
     return cfg, params, toks
 
 
-@pytest.mark.parametrize("arch", ["olmoe-1b-7b", "mixtral-8x7b"])
+@pytest.mark.parametrize("arch", ["olmoe-1b-7b", "mixtral-8x7b",
+                                  "deepseek-v2-lite"])
 def test_grouped_matches_loop_token_for_token(arch):
-    """The acceptance bar: grouped generate == loop-oracle generate."""
-    cfg, params, toks = _setup(arch)
+    """The acceptance bar: grouped generate == the greedy argmax of the
+    model's own forward (``M.forward``, dense-combine MoE), token for token,
+    in float32.  The dense-combine reference does not go through the
+    grouped dispatch under test.  (The name is kept from when the oracle
+    was the engine's per-expert loop.)"""
+    cfg = replace(get_config(arch, smoke=True), dtype="float32")
+    params = M.init_params(cfg, KEY)
+    toks = jax.random.randint(jax.random.PRNGKey(2), (B, S), 0, cfg.vocab_size)
     plan = Plan(B=B, b_a=2, b_e=B, omega=0.0)     # capacity B: no drops
-    eng_g = ModuleBatchingEngine(cfg, params, plan, max_seq=S + DEC,
-                                 expert_path="grouped")
-    eng_l = ModuleBatchingEngine(cfg, params, plan, max_seq=S + DEC,
-                                 expert_path="loop")
-    out_g = eng_g.generate(toks, DEC)
-    out_l = eng_l.generate(toks, DEC)
-    assert jnp.array_equal(out_g, out_l), (
-        float(jnp.mean((out_g == out_l).astype(jnp.float32)))
-    )
-    assert eng_g.stats.expert_tokens_dropped == 0
-    # grouped issues one launch per MoE layer per decode step; the loop
-    # oracle issues at least one per non-empty expert
-    n_moe = sum(1 for _, f, _ in eng_g.layers if f == "moe")
-    assert eng_g.stats.expert_launches == n_moe * (DEC - 1)
-    assert eng_l.stats.expert_launches >= eng_g.stats.expert_launches
+    eng = ModuleBatchingEngine(cfg, params, plan, max_seq=S + DEC)
+    with jax.default_matmul_precision("highest"):
+        out = np.asarray(eng.generate(toks, DEC))
+        # causal, so one pass over prompt + served tokens scores each one
+        seqs = jnp.concatenate([toks, jnp.asarray(out)], axis=1)
+        lg, _, _ = M.forward(cfg, params, seqs)
+    best = np.asarray(jnp.argmax(lg, axis=-1))[:, S - 1:S + DEC - 1]
+    assert np.array_equal(best, out), float(np.mean(best == out))
+    assert eng.stats.expert_tokens_dropped == 0
+    # one grouped launch per MoE layer per decode step
+    n_moe = sum(1 for _, f, _ in eng.layers if f == "moe")
+    assert eng.stats.expert_launches == n_moe * (DEC - 1)
 
 
 def test_capacity_overflow_is_counted():

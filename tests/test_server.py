@@ -337,3 +337,20 @@ def test_chunked_steps_match_per_tick_under_capacity_drops():
     for x, y in zip(results[1].request_results, results[4].request_results):
         assert np.array_equal(x.tokens, y.tokens), x.index
     assert results[4].expert_tokens_dropped == results[1].expert_tokens_dropped
+
+
+def test_from_plan_plans_no_host_attention():
+    """``ServeConfig.from_plan`` plans ω = 0, like ``plan_serving``: the
+    engine attends every row on its device and refuses ω > 0.  Here the
+    cost model alone would credit host attention with most rows."""
+    from repro.core.engine import ModuleBatchingEngine
+    from repro.core.hardware import A5000_C2
+    from repro.core.planner import search_decode
+
+    cfg, params = _mixtral()
+    assert search_decode(cfg, A5000_C2, 64, B=4,
+                         scheduler="continuous").plan.omega > 0
+    sc = ServeConfig.from_plan(cfg, A5000_C2, ctx=64, scheduler="continuous",
+                               B=4, decode_len=4)
+    assert sc.plan.omega == 0.0
+    ModuleBatchingEngine(cfg, params, sc.plan, max_seq=64)

@@ -282,19 +282,3 @@ def test_continuous_admission_gated_by_host_kv_budget():
     with pytest.raises(ValueError, match="Eq. 2"):
         serve_dataset(cfg, params, reqs, plan, 4, scheduler="continuous",
                       hw=hw_tiny)
-
-
-def test_scheduler_expert_path_choice():
-    """serve_dataset surfaces the grouped-vs-loop engine choice and both
-    paths serve identical tokens."""
-    from repro.core.dag_builder import Plan
-    from repro.data.datasets import DatasetSpec, synthetic_requests
-    from repro.serving.scheduler import serve_dataset
-
-    cfg = get_config("olmoe-1b-7b", smoke=True)
-    params = M.init_params(cfg, KEY)
-    reqs = synthetic_requests(DatasetSpec("tiny", 4, 8, 4), cfg.vocab_size)
-    plan = Plan(B=4, b_a=2, b_e=8, omega=0.0)
-    rep_g = serve_dataset(cfg, params, reqs, plan, 4, expert_path="grouped")
-    rep_l = serve_dataset(cfg, params, reqs, plan, 4, expert_path="loop")
-    assert np.array_equal(rep_g.results[0].tokens, rep_l.results[0].tokens)

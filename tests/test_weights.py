@@ -62,16 +62,17 @@ def test_streamed_generate_matches_resident(arch):
     assert eng.stats.expert_tokens_dropped == 0
 
 
-@pytest.mark.parametrize("expert_path", ["grouped", "loop"])
+@pytest.mark.parametrize("arch", ["mixtral-8x7b", "deepseek-v2-lite"])
 @pytest.mark.parametrize("prefetch", [True, False])
-def test_streamed_matches_resident_both_expert_paths(expert_path, prefetch):
-    """Streaming is orthogonal to the MoE path: grouped and loop decode,
-    overlapped and serial fetch, all reproduce the resident tokens."""
-    cfg, params, toks = _setup("mixtral-8x7b")
-    ref, _ = _generate(cfg, params, toks, expert_path=expert_path)
-    got, eng = _generate(cfg, params, toks, expert_path=expert_path,
-                         stream_weights=True, resident_bytes=0.0,
-                         prefetch=prefetch)
+def test_streamed_matches_resident_both_expert_paths(arch, prefetch):
+    """Streaming is orthogonal to the layer's kinds: overlapped and serial
+    fetch reproduce the resident tokens for grouped-query attention and
+    for latent attention with shared experts behind a leading dense
+    layer."""
+    cfg, params, toks = _setup(arch)
+    ref, _ = _generate(cfg, params, toks)
+    got, eng = _generate(cfg, params, toks, stream_weights=True,
+                         resident_bytes=0.0, prefetch=prefetch)
     assert jnp.array_equal(ref, got)
     assert eng.stats.weight_htod_bytes > 0
 
@@ -120,45 +121,49 @@ def test_streamed_single_layer_model():
     assert eng.stats.weight_htod_bytes > 0
 
 
-@pytest.mark.parametrize("expert_path", ["grouped", "loop"])
-def test_decode_issues_next_copy_before_attention(expert_path, monkeypatch):
-    """Per-module streamed decode issues layer l+1's copy right after
-    ``acquire(l)`` returns and before l's attention stage; the last layer
-    wraps to layer 0.  At each issue the window holds no other layer (only
-    l, just landed, and l+1 are on device), and no acquire demand-fetches."""
-    cfg, params, toks = _setup("mixtral-8x7b")
-    L = cfg.num_layers
-    assert L == 2
+@pytest.mark.parametrize("arch", ["mixtral-8x7b", "deepseek-v2-lite"])
+def test_decode_issues_next_copy_before_attention(arch, monkeypatch):
+    """Per-module streamed decode issues streamed layer l+1's copy right
+    after ``acquire(l)`` returns and before l's attention stage (grouped
+    query or latent); the last layer wraps to the first.  At each issue the
+    window holds no other layer (only l, just landed, and l+1 are on
+    device), and no acquire demand-fetches.  DeepSeek's leading dense layer
+    is pinned, outside the store's index, so it acquires nothing."""
+    cfg, params, toks = _setup(arch)
     eng = ModuleBatchingEngine(
         cfg, params, Plan(B=B, b_a=2, b_e=B, omega=0.0), max_seq=S + DEC,
-        expert_path=expert_path, stream_weights=True, resident_bytes=0.0,
+        stream_weights=True, resident_bytes=0.0,
     )
     store = eng.store
+    L = len(store.schema)                   # the store's streamed layers
+    assert L == 2 and eng.n_lead == (arch == "deepseek-v2-lite")
     store.prefetch(0)           # else prefill's first acquire demand-fetches
     logits = eng.prefill(toks)
     assert store.demand_fetches == 0
 
-    events, inflight_at_issue = [], []
+    events, inflight_at_issue = [], {}
     acquire, prefetch = store.acquire, store.prefetch
-    attention = eng._attention_stage
 
-    def rec_acquire(li, *a, **kw):
-        out = acquire(li, *a, **kw)
-        events.append(("acquire", li))
+    def rec_acquire(si, *a, **kw):
+        out = acquire(si, *a, **kw)
+        events.append(("acquire", si))
         return out
 
-    def rec_prefetch(li):
-        events.append(("prefetch", li))
-        inflight_at_issue.append(sorted(store._inflight))
-        prefetch(li)
+    def rec_prefetch(si):
+        inflight_at_issue[len(events)] = sorted(store._inflight)
+        events.append(("prefetch", si % L))
+        prefetch(si)
 
-    def rec_attention(li, *a, **kw):
-        events.append(("attention", li))
-        return attention(li, *a, **kw)
+    def recorder(stage):
+        def rec(li, *a, **kw):
+            events.append(("attention", li))
+            return stage(li, *a, **kw)
+        return rec
 
     monkeypatch.setattr(store, "acquire", rec_acquire)
     monkeypatch.setattr(store, "prefetch", rec_prefetch)
-    monkeypatch.setattr(eng, "_attention_stage", rec_attention)
+    for name in ("_attention_stage", "_mla_stage"):
+        monkeypatch.setattr(eng, name, recorder(getattr(eng, name)))
 
     for t in range(3):
         events.clear()
@@ -166,34 +171,39 @@ def test_decode_issues_next_copy_before_attention(expert_path, monkeypatch):
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         logits = eng.decode_step(tok, S + t)
         assert [e for e in events if e[0] == "acquire"] == [
-            ("acquire", li) for li in range(L)]
-        for li in range(L):
-            a = events.index(("acquire", li))
-            p = events.index(("prefetch", li + 1))   # wraps to layer 0
-            assert a < p < events.index(("attention", li)), events
-        assert inflight_at_issue == [[]] * L
+            ("acquire", si) for si in range(L)]
+        for si in range(L):
+            a = events.index(("acquire", si))
+            p = events.index(("prefetch", (si + 1) % L), a)  # wraps to 0
+            assert a < p < events.index(("attention", si + eng.n_lead)), \
+                events
+            assert inflight_at_issue[p] == [], (p, inflight_at_issue)
         assert sorted(store._inflight) == [0]        # layer 0, next step's
         assert store.demand_fetches == 0
 
 
-@pytest.mark.parametrize("omega", [0.0, 0.5])
+@pytest.mark.parametrize("arch", ["mixtral-8x7b", "deepseek-v2-lite"])
 @pytest.mark.parametrize("vector_pos", [False, True])
-def test_attention_stage_uploads_nothing(omega, vector_pos):
-    """The attention stage cuts its micro-batches at static bounds, so no
-    start index goes host-to-device: on the chip such an upload waits
-    behind the next layer's weight copy, which is then in flight."""
-    cfg, params, toks = _setup("mixtral-8x7b")
+def test_attention_stage_uploads_nothing(arch, vector_pos):
+    """The attention stage (grouped query, or latent for DeepSeek) cuts its
+    micro-batches at static bounds, so no start index goes host-to-device:
+    on the chip such an upload waits behind the next layer's weight copy,
+    which is then in flight."""
+    cfg, params, toks = _setup(arch)
     eng = ModuleBatchingEngine(
-        cfg, params, Plan(B=B, b_a=3, b_e=B, omega=omega), max_seq=S + DEC,
+        cfg, params, Plan(B=B, b_a=3, b_e=B, omega=0.0), max_seq=S + DEC,
     )
     eng.prefill(toks)
     x = jnp.ones((B, cfg.d_model), jnp.bfloat16)
     pos = jnp.full((B,), S, jnp.int32) if vector_pos else jnp.asarray(S)
-    p = eng.store.acquire(0)
+    p = eng._acquire(0)
+    stage = (eng._mla_stage if eng.schema[0][0] == "mla"
+             else eng._attention_stage)
     with jax.transfer_guard_host_to_device("disallow"):
-        y = eng._attention_stage(0, p, x, pos)
+        y = stage(0, p, x, pos)
     assert y.shape == x.shape
-    assert eng.stats.host_attn_tokens == round(omega * B)
+    assert eng.stats.attn_microbatches == 2 + 2   # prefill: 3 + 1 rows; decode
+    assert eng.stats.device_attn_tokens == B
 
 
 # ---------------------------------------------------------------------------
