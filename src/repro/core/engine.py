@@ -59,9 +59,10 @@ into ``EngineStats`` by ``sync_stats()``.
 Prefill shares the layer-major structure: each layer's weights are acquired
 ONCE and reused across all ``b_a``-sequence micro-batches (module-based
 batching's weight amortization), and each MoE layer splits into a
-mixer+route launch and a grouped-FFN launch whose capacity is the next
-power of two over the micro-batch's measured max expert load, so no routed
-copy is ever dropped (``grouped_prefill=True``, the default);
+mixer+route launch and a ragged grouped-FFN launch over the routed copies
+of the micro-batch's real prompt tokens, at a capacity of its token count,
+so no routed copy is ever dropped and no host read sizes the launch
+(``grouped_prefill=True``, the default);
 ``grouped_prefill=False`` opts prefill back into the exact dense-combine
 reference MoE.
 
@@ -198,8 +199,8 @@ def _grouped_expert_math(cfg, p, x, capacity):
     )
     if "shared" in moe:
         y = y + moe_mod.shared_experts(moe, h)
-    return y, kept, dropped, load, moe_mod.ragged_rows(load, capacity,
-                                                      x.shape[0])
+    return y, kept, dropped, load, moe_mod.ragged_rows(
+        load, capacity, x.shape[0], cfg.experts_per_token)
 
 
 _grouped_expert_module = _counted(
@@ -249,8 +250,8 @@ def _grouped_ffn_module(cfg, capacity, h, gates, idx, wg, wu, wd,
         cfg, h, gates, idx, wg, wu, wd, capacity)
     if moe_pinned:
         y = y + moe_mod.shared_experts(moe_pinned, h)
-    return y, kept, dropped, load, moe_mod.ragged_rows(load, capacity,
-                                                      h.shape[0])
+    return y, kept, dropped, load, moe_mod.ragged_rows(
+        load, capacity, h.shape[0], cfg.experts_per_token)
 
 
 @_counted
@@ -272,6 +273,14 @@ _head_module = _counted(
         functools.partial(jax.jit, static_argnames=("cfg", "tie"))(_head_math)
     )
 )
+
+
+@jax.jit
+def _last_positions(x, lengths):
+    """Row ``b``'s hidden state at its true last prompt token, position
+    ``lengths[b] - 1``, in one launch (eager indexing uploads its
+    constants)."""
+    return x[jnp.arange(x.shape[0]), lengths - 1]
 
 
 @_counted
@@ -300,15 +309,11 @@ def _prefill_layer_module(cfg, kind, ffn, sctx, p, x, positions, lengths):
 @functools.partial(jax.jit, static_argnames=("cfg", "kind"))
 def _prefill_mixer_route_module(cfg, kind, p, x, positions, lengths):
     """Mixer half of a grouped-prefill MoE layer, plus routing: norm1 ->
-    attention/SSM -> residual -> norm2 -> route.  Splitting the layer here
-    lets the engine read back the micro-batch's measured max per-expert
-    load (ONE planned scalar per layer per micro-batch) and size the
-    grouped FFN's capacity to the next power-of-two bucket >= it, instead
-    of pinning capacity to the full micro-batch token count.  Zero-drop —
-    and therefore bit-identity with the single-launch layer — holds for
-    ANY capacity >= the max load: every routed copy keeps its slot, and
-    buffer rows beyond the load are zero-padded lanes whose outputs are
-    never gathered back."""
+    attention/SSM -> residual -> norm2 -> route.  Also returns the
+    micro-batch's max per-expert load, which a mesh engine reads back to
+    size its collective dispatch's capacity at the next power of two over
+    it (zero-drop holds for ANY capacity >= the max load); the
+    single-device stage needs no capacity read."""
     aux = jnp.zeros((), jnp.float32)
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
     if kind == "attn":
@@ -330,22 +335,29 @@ def _prefill_mixer_route_module(cfg, kind, p, x, positions, lengths):
 @_counted
 @register_jit("engine.prefill_moe_ffn")
 @functools.partial(jax.jit, static_argnames=("cfg", "capacity"))
-def _prefill_moe_ffn_module(cfg, capacity, moe_p, x, xt, gates, idx):
-    """Grouped-FFN half of the split prefill MoE layer, at the measured
-    pow2-bucketed ``capacity``.  Same dispatch math as ``moe_apply_grouped``
-    (route happened in the mixer half), so the residual-added output is
-    bit-identical to the unsplit layer whenever no copy drops — guaranteed
-    by capacity >= the measured max load.  The shared experts, where the
-    layer has them, run here over every row, outside the grouped kernel."""
-    y, _, dropped, _ = moe_mod.grouped_dispatch(
+def _prefill_moe_ffn_module(cfg, capacity, moe_p, x, xt, gates, idx,
+                            lengths=None):
+    """Expert half of the split prefill MoE layer: the ragged dispatch
+    (``moe.ragged_dispatch``) of the routed copies the mixer half chose,
+    at ``capacity`` >= the micro-batch's token count, so no copy drops.
+    With ``lengths`` (the micro-batch's prompt lengths) only the copies of
+    real positions take kernel rows; a pad position's expert output is
+    zero, and nothing reads it (its key is masked, its logits unread).
+    The shared experts, where the layer has them, run here over every row,
+    outside the ragged kernel.  Returns the residual-added output, the
+    dropped copies and the rows the ragged kernel computed."""
+    B, S, D = x.shape
+    valid = (None if lengths is None
+             else (jnp.arange(S)[None, :] < lengths[:, None]).reshape(-1))
+    y, _, dropped, load = moe_mod.ragged_dispatch(
         cfg, xt, gates, idx,
         moe_p["experts_w_gate"], moe_p["experts_w_up"],
-        moe_p["experts_w_down"], capacity,
+        moe_p["experts_w_down"], capacity, valid=valid,
     )
     if "shared" in moe_p:
         y = y + moe_mod.shared_experts(moe_p, xt)
-    B, S, D = x.shape
-    return x + y.reshape(B, S, D).astype(x.dtype), dropped
+    rows = moe_mod.ragged_rows(load, capacity, B * S, cfg.experts_per_token)
+    return x + y.reshape(B, S, D).astype(x.dtype), dropped, rows
 
 
 # ---------------------------------------------------------------------------
@@ -593,8 +605,10 @@ class EngineStats:
     #                                      (weight + expert + KV-page windows)
     transfer_timeouts: int = 0           # watchdog-expired acquire waits
     #                                      recovered by demand re-fetch
-    prefill_capacity_rows: int = 0       # grouped-prefill (E, cap) buffer
-    #                                      rows, per MoE layer per micro-batch
+    prefill_expert_rows: int = 0         # rows the prefill expert kernel
+    #                                      computed (ragged: used tiles x
+    #                                      tile rows; mesh: E x capacity),
+    #                                      per MoE layer per micro-batch
     prefill_routed_copies: int = 0       # routed copies of real prompt
     #                                      tokens those buffers carried
     decode_expert_rows: int = 0          # rows the ragged decode expert
@@ -608,9 +622,9 @@ class ModuleBatchingEngine:
     **MoE stage.**  Decode runs one jitted ragged grouped-dispatch launch
     per MoE layer (or one collective dispatch on a mesh ``sctx``); routing
     stays on device and ``plan.b_e`` bounds the routed copies an expert
-    takes.  Prefill shares the grouped implementation
-    (``grouped_prefill=True``, the default) at a capacity no smaller than
-    the micro-batch's measured max expert load, so zero
+    takes.  Prefill shares the ragged implementation
+    (``grouped_prefill=True``, the default) over its real prompt tokens at
+    a capacity of the micro-batch's token count, so zero
     ``expert_tokens_dropped`` at prefill by construction; pass
     ``grouped_prefill=False`` for the exact-reference dense-combine prefill.
 
@@ -740,6 +754,7 @@ class ModuleBatchingEngine:
         E = max(1, cfg.num_experts)
         self._kept_dev = jnp.zeros((), jnp.int32)
         self._rows_dev = jnp.zeros((), jnp.int32)
+        self._prefill_rows_dev = jnp.zeros((), jnp.int32)
         self._dropped_dev_l = [jnp.zeros((), jnp.int32)
                                for _ in range(n_moe)]
         self._load_dev_l = [jnp.zeros((E,), jnp.int32) for _ in range(n_moe)]
@@ -795,11 +810,14 @@ class ModuleBatchingEngine:
     def sync_stats(self) -> EngineStats:
         """Materialize the device-side expert counters (one host sync) and
         drain the store's transfer + predictive-streaming accounting."""
-        kept, rows = jax.device_get((self._kept_dev, self._rows_dev))
+        kept, rows, prows = jax.device_get(
+            (self._kept_dev, self._rows_dev, self._prefill_rows_dev))
         self.stats.expert_tokens += int(kept)
         self.stats.decode_expert_rows += int(rows)
+        self.stats.prefill_expert_rows += int(prows)
         self._kept_dev = jnp.zeros((), jnp.int32)
         self._rows_dev = jnp.zeros((), jnp.int32)
+        self._prefill_rows_dev = jnp.zeros((), jnp.int32)
         n_moe = len(self._moe_layers)
         if n_moe:
             E = self._load_chunk_dev.shape[1]
@@ -871,11 +889,13 @@ class ModuleBatchingEngine:
                     init_layer_cache(self.cfg, kind, batch, self.max_seq)
                 )
 
-    def _write_cache_rows(self, li: int, kind: str, entry: Dict, rows) -> None:
+    def _write_cache_rows(self, li: int, kind: str, entry: Dict, rows,
+                          rows_dev=None) -> None:
         """Insert a micro-batch's raw prefill cache into batch rows ``rows``
-        of layer ``li``'s decode buffer (``kvcache.insert_prefill_rows``) —
-        or, under paging, into the rows' page frames (allocated on first
-        touch, device tier first)."""
+        of layer ``li``'s decode buffer (``kvcache.insert_prefill_rows``,
+        given ``rows_dev``, the same rows on the device, where the caller
+        has them) — or, under paging, into the rows' page frames
+        (allocated on first touch, device tier first)."""
         from repro.serving.kvcache import aligned_kv, insert_prefill_rows
 
         if kind == "attn" and self.pages is not None:
@@ -888,7 +908,8 @@ class ModuleBatchingEngine:
                 self.pages.insert_rows(li, nk, nv, rows_l)
                 return
         self.cache[li] = insert_prefill_rows(
-            self.cfg, kind, self.cache[li], entry, rows
+            self.cfg, kind, self.cache[li], entry,
+            rows if rows_dev is None else rows_dev
         )
 
     def evict_slots(self, rows) -> None:
@@ -1042,9 +1063,16 @@ class ModuleBatchingEngine:
         b_a = max(1, min(plan.b_a, n))
         spans = [(lo, min(n, lo + b_a)) for lo in range(0, n, b_a)]
         positions = jnp.arange(S)[None, :]
+        # cut the per-micro-batch inputs once, at static bounds: an eager
+        # row slice uploads its start index, and the layer loop would make
+        # one such upload (and one of its cache rows) per layer and
+        # micro-batch
+        cut = [lo for lo, _ in spans[1:]]
+        lens_mb = None if lengths is None else jnp.split(lengths, cut)
+        rows_mb = jnp.split(jnp.asarray(rows, jnp.int32), cut)
         xs = []
-        for lo, hi in spans:
-            x = _embed_module(cfg, self.store.base["embed"], tokens[lo:hi])
+        for (lo, hi), tok in zip(spans, jnp.split(tokens, cut)):
+            x = _embed_module(cfg, self.store.base["embed"], tok)
             if frontend_emb is not None:
                 fe = frontend_emb[lo:hi]
                 F = fe.shape[1]
@@ -1054,7 +1082,8 @@ class ModuleBatchingEngine:
             with span("engine.layer", layer=li, phase="prefill",
                       mixer=kind, ffn=ffn):
                 xs = self._prefill_layer(li, kind, ffn, xs, spans, rows,
-                                         positions, lengths, lens_host, S)
+                                         rows_mb, positions, lens_mb,
+                                         lens_host, S)
         self.stats.attn_microbatches += len(spans)
         # each micro-batch's last real position, without a copy of the
         # whole wave's activations
@@ -1062,53 +1091,59 @@ class ModuleBatchingEngine:
             h_last = jnp.concatenate([x[:, -1] for x in xs], axis=0)
         else:
             h_last = jnp.concatenate(
-                [x[jnp.arange(hi - lo), lengths[lo:hi] - 1]
-                 for (lo, hi), x in zip(spans, xs)], axis=0)
+                [_last_positions(x, ln) for x, ln in zip(xs, lens_mb)],
+                axis=0)
         return _head_module(cfg, cfg.tie_embeddings, self.store.base, h_last)
 
-    def _prefill_layer(self, li, kind, ffn, xs, spans, rows, positions,
-                       lengths, lens_host, S) -> List[jax.Array]:
-        """Layer ``li`` of ``prefill_slots`` over every micro-batch."""
+    def _prefill_layer(self, li, kind, ffn, xs, spans, rows, rows_mb,
+                       positions, lens_mb, lens_host, S) -> List[jax.Array]:
+        """Layer ``li`` of ``prefill_slots`` over every micro-batch
+        (``rows_mb``/``lens_mb``: each micro-batch's cache rows and prompt
+        lengths, already on the device)."""
         cfg = self.cfg
         p = self._acquire(li)
         self._prefetch(li + 1)          # hide l+1's copy behind this layer
-        # grouped-prefill MoE layers split into mixer+route / grouped-FFN
-        # launches so the FFN capacity can be the next pow2 bucket over
-        # the micro-batch's MEASURED max expert load instead of the full
-        # token count — smaller (E, C, D) buffers, zero drops preserved
+        # grouped-prefill MoE layers split into mixer+route / expert
+        # launches: on one device the expert launch dispatches ragged over
+        # the real tokens' copies at a capacity of the micro-batch's token
+        # count, read back nowhere; a mesh engine reads the measured max
+        # expert load to size its collective dispatch at the next pow2
         split_moe = ffn == "moe" and self.grouped_prefill
         outs = []
         for m, (lo, hi) in enumerate(spans):
             # drop the caller's reference as the micro-batch is consumed:
             # the wave's activations are then held once, not twice
             x, xs[m] = xs[m], None
-            ln = None if lengths is None else lengths[lo:hi]
+            ln = None if lens_mb is None else lens_mb[m]
             if split_moe:
                 x_mid, entry, xt, gates, idx, max_load, _ = (
                     _prefill_mixer_route_module(
                         cfg, kind, p, x, positions, ln
                     )
                 )
-                with sanitizer.allowed("prefill-capacity-probe"):
-                    cap = W.next_pow2(int(np.asarray(max_load)))
                 tokens = ((hi - lo) * S if lens_host is None
                           else int(lens_host[lo:hi].sum()))
-                self.stats.prefill_capacity_rows += cfg.num_experts * cap
                 self.stats.prefill_routed_copies += (
                     cfg.experts_per_token * tokens)
                 if self.sctx is not None:
+                    with sanitizer.allowed("prefill-capacity-probe"):
+                        cap = W.next_pow2(int(np.asarray(max_load)))
+                    self.stats.prefill_expert_rows += cfg.num_experts * cap
                     y = x_mid + self._mesh_moe(li, p, x_mid, cap,
                                                (xt, gates, idx))
                 else:
-                    y, _ = _prefill_moe_ffn_module(
-                        cfg, cap, p["moe"], x_mid, xt, gates, idx
+                    y, _, kernel_rows = _prefill_moe_ffn_module(
+                        cfg, (hi - lo) * S, p["moe"], x_mid, xt, gates,
+                        idx, ln
                     )
+                    self._prefill_rows_dev = (self._prefill_rows_dev
+                                              + kernel_rows)
             else:
                 sctx = self._prefill_sctx((hi - lo) * S)
                 y, entry, _ = _prefill_layer_module(
                     cfg, kind, ffn, sctx, p, x, positions, ln
                 )
-            self._write_cache_rows(li, kind, entry, rows[lo:hi])
+            self._write_cache_rows(li, kind, entry, rows[lo:hi], rows_mb[m])
             outs.append(y)
         return outs
 

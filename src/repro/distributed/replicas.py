@@ -241,7 +241,7 @@ class ReplicaServer:
             m.kv_htod_bytes += r.kv_htod_bytes
             m.kv_dtoh_bytes += r.kv_dtoh_bytes
             m.prefill_tokens += r.prefill_tokens
-            m.prefill_capacity_rows += r.prefill_capacity_rows
+            m.prefill_expert_rows += r.prefill_expert_rows
             m.prefill_routed_copies += r.prefill_routed_copies
             m._expert_dropped += r._expert_dropped
             m.expert_pred_hits += r.expert_pred_hits

@@ -13,8 +13,8 @@ Three kernels:
   buffer: each row tile belongs to one expert (a scalar-prefetched tile ->
   expert map), so an expert whose routed copies fit one tile has its
   weights read once a call, and tiles past the used count are neither
-  fetched nor computed.  Decode's expert stage (``models.moe``
-  ``ragged_dispatch``).
+  fetched nor computed.  The expert stage of decode and of prefill
+  (``models.moe`` ``ragged_dispatch``).
 
 Both kernels are validated against kernels/ref.py in interpret mode across
 shape/dtype sweeps (tests/test_kernels.py); ``kernels/ops.py`` holds the
@@ -146,6 +146,10 @@ def expert_ffn(
 # ---------------------------------------------------------------------------
 # Ragged fused gated expert FFN
 # ---------------------------------------------------------------------------
+_VMEM_SCOPED = 16 * 2**20   # Mosaic's default scoped VMEM limit on a v5e
+_VMEM_SPARE = 4 * 2**20     # left over for Mosaic's own scratch
+
+
 def _ragged_expert_ffn_kernel(tile_expert_ref, n_used_ref, x_ref, wg_ref,
                               wu_ref, wd_ref, o_ref, acc_ref, *, n_f: int):
     """Grid (R/tm, F/bf): row tile ``i`` runs expert ``tile_expert[i]``'s
@@ -215,6 +219,11 @@ def ragged_expert_ffn(
     def wd_map(i, j, te, n):
         return (te[last(i, n)], f_block(i, j, n), 0)
 
+    # double-buffered x, out and weight blocks, and the f32 accumulator;
+    # past Mosaic's default scoped limit (prefill's 256-row tiles at
+    # Mixtral's width) the kernel asks for what it holds plus the spare
+    held = (4 * block_m * D + 6 * D * block_f) * x.dtype.itemsize \
+        + 4 * block_m * D + _VMEM_SPARE
     return pl.pallas_call(
         functools.partial(_ragged_expert_ffn_kernel, n_f=n_f),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -231,9 +240,10 @@ def ragged_expert_ffn(
         ),
         out_shape=jax.ShapeDtypeStruct((R, D), x.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=None if held <= _VMEM_SCOPED else held),
         interpret=interpret,
-        # the trace names the kernel's device op by this: the decode expert
+        # the trace names the kernel's device op by this: the ragged expert
         # kernel keeps the capacity kernel's name
         name="expert_ffn",
     )(tile_expert, n_used, x, wg, wu, wd)

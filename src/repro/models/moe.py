@@ -114,6 +114,7 @@ def grouped_dispatch(
     wg, wu, wd,             # (E, ·, ·) expert weights
     capacity: int,
     use_kernel=None,
+    valid: Optional[jax.Array] = None,   # (T,) bool: real tokens
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """The engine's expert module (paper §4.2), fully on device.
 
@@ -126,6 +127,10 @@ def grouped_dispatch(
     ``dropped`` device scalars plus ``load``, the (E,) per-expert routed-copy
     histogram counted BEFORE capacity drops (what the planner's measured
     ``b_e`` search consumes) — no host sync happens here.
+
+    ``valid`` marks the real tokens of a right-padded batch: a copy of an
+    invalid token takes no slot, is neither kept nor dropped nor counted in
+    ``load``, and its output row is zero.
     """
     from repro.kernels import ops as kernel_ops
 
@@ -134,8 +139,8 @@ def grouped_dispatch(
     k = cfg.experts_per_token
     flat_idx = idx.reshape(-1)                              # (T*k,)
     flat_gate = gates.reshape(-1)
-    slot = _arrival_slots(flat_idx, E)
-    keep = slot < capacity
+    slot, keep, load, routed = _place_copies(flat_idx, E, k, capacity,
+                                             valid)
     slot_c = jnp.minimum(slot, capacity - 1)
     tok = jnp.arange(T * k) // k
     buf = jnp.zeros((E, capacity, D), xt.dtype)
@@ -147,14 +152,42 @@ def grouped_dispatch(
     back = back * (keep[:, None] * flat_gate[:, None]).astype(back.dtype)
     y = jnp.zeros((T, D), xt.dtype).at[tok].add(back.astype(xt.dtype))
     kept = jnp.sum(keep.astype(jnp.int32))
-    load = jnp.zeros((E,), jnp.int32).at[flat_idx].add(1)
-    return y, kept, jnp.int32(T * k) - kept, load
+    return y, kept, routed - kept, load
 
 
-def ragged_tile(T: int) -> int:
-    """Row tile of the ragged decode dispatch for ``T`` rows: 128, or ``T``
-    rounded up to 16 when smaller.  At ``T <= 128`` every expert's copies
-    fit one tile, since a row routes to an expert at most once."""
+def _place_copies(flat_idx: jax.Array, E: int, k: int, capacity: int,
+                  valid=None):
+    """Each routed copy's arrival slot at its expert and whether it is
+    kept (slot < ``capacity``), with the (E,) routed-copy histogram and
+    its total.  Copies of tokens ``valid`` marks False take no slot, are
+    not kept and are not counted."""
+    if valid is None:
+        slot = _arrival_slots(flat_idx, E)
+        return (slot, slot < capacity,
+                jnp.zeros((E,), jnp.int32).at[flat_idx].add(1),
+                jnp.int32(flat_idx.shape[0]))
+    real = jnp.repeat(valid, k)                             # (T*k,)
+    slot = _arrival_slots(flat_idx, E, mask=real)
+    ones = real.astype(jnp.int32)
+    return (slot, real & (slot < capacity),
+            jnp.zeros((E,), jnp.int32).at[flat_idx].add(ones), jnp.sum(ones))
+
+
+# v5e: 197 TFLOP/s over 819 GB/s is a ridge of 240 FLOP per byte.  A row
+# tile of ``tm`` rows does 2 FLOP per 2-byte weight per row, ``tm`` FLOP per
+# weight byte read, so from 256 rows its weight read hides under its matmuls.
+_MXU_BOUND_TILE = 256
+
+
+def ragged_tile(T: int, k: int, E: int) -> int:
+    """Row tile of the ragged dispatch for ``T`` rows routed top-``k`` of
+    ``E`` experts.  256 where the balanced load ``T*k/E`` fills such a tile
+    (prefill's micro-batches: each weight read then costs no more than the
+    tile's matmuls), else 128, or ``T`` rounded up to 16 when smaller
+    (decode: at ``T <= 128`` every expert's copies fit one tile, since a
+    row routes to an expert at most once)."""
+    if T * k >= _MXU_BOUND_TILE * E:
+        return _MXU_BOUND_TILE
     return min(128, -(-T // 16) * 16)
 
 
@@ -162,7 +195,7 @@ def ragged_buffer_rows(T: int, k: int, E: int, capacity: int) -> int:
     """Static row count of the ragged buffer: each expert's kept copies
     rounded up to whole tiles, at most ``T*k + E*(tm-1)`` rows and at most
     ``E`` full capacity buffers of ``min(capacity, T)`` rows."""
-    tm = ragged_tile(T)
+    tm = ragged_tile(T, k, E)
     up = lambda n: -(-n // tm) * tm
     return up(min(T * k + E * (tm - 1), E * up(min(capacity, T))))
 
@@ -172,10 +205,11 @@ def _ragged_tiles(load: jax.Array, capacity: int, tm: int) -> jax.Array:
     return -(-jnp.minimum(load, capacity) // tm)
 
 
-def ragged_rows(load: jax.Array, capacity: int, T: int) -> jax.Array:
-    """Rows the ragged decode kernel computes for one call whose (E,)
-    routed-copy histogram is ``load``: used tiles x the row tile."""
-    tm = ragged_tile(T)
+def ragged_rows(load: jax.Array, capacity: int, T: int, k: int) -> jax.Array:
+    """Rows the ragged kernel computes for one call of ``T`` rows routed
+    top-``k`` whose (E,) routed-copy histogram is ``load``: used tiles x
+    the row tile."""
+    tm = ragged_tile(T, k, load.shape[0])
     return jnp.sum(_ragged_tiles(load, capacity, tm)) * tm
 
 
@@ -187,14 +221,16 @@ def ragged_dispatch(
     wg, wu, wd,             # (E, ·, ·) expert weights
     capacity: int,
     use_kernel=None,
+    valid: Optional[jax.Array] = None,   # (T,) bool: real tokens
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """``grouped_dispatch`` with the routed copies packed by expert into
-    row tiles instead of an ``(E, capacity, D)`` buffer: decode's expert
-    stage.  Same arguments, same drops (a copy whose arrival slot is at or
-    past ``capacity``), same returns.
+    row tiles instead of an ``(E, capacity, D)`` buffer: the expert stage
+    of decode and of prefill.  Same arguments, same drops (a copy whose
+    arrival slot is at or past ``capacity``), same returns; a copy of a
+    token ``valid`` marks False takes no row.
 
     Expert ``e``'s kept copies take ``ceil(kept_e / tm)`` tiles of
-    ``tm = ragged_tile(T)`` rows at an exclusive-cumsum offset; the
+    ``tm = ragged_tile(T, k, E)`` rows at an exclusive-cumsum offset; the
     ragged kernel (``kernels.ops.ragged_expert_ffn``) computes only the
     used tiles and reads each expert's weights once per tile, so once a
     call wherever its copies fit a tile.  The tile -> expert map and the
@@ -206,17 +242,16 @@ def ragged_dispatch(
                   else use_kernel)
     if not use_kernel:
         return grouped_dispatch(cfg, xt, gates, idx, wg, wu, wd, capacity,
-                                use_kernel=False)
+                                use_kernel=False, valid=valid)
     T, D = xt.shape
     E = cfg.num_experts
     k = cfg.experts_per_token
-    tm = ragged_tile(T)
+    tm = ragged_tile(T, k, E)
     R = ragged_buffer_rows(T, k, E, capacity)
     flat_idx = idx.reshape(-1)                              # (T*k,)
     flat_gate = gates.reshape(-1)
-    slot = _arrival_slots(flat_idx, E)
-    keep = slot < capacity
-    load = jnp.zeros((E,), jnp.int32).at[flat_idx].add(1)
+    slot, keep, load, routed = _place_copies(flat_idx, E, k, capacity,
+                                             valid)
     tiles = _ragged_tiles(load, capacity, tm)
     ends = jnp.cumsum(tiles)                                # (E,)
     row = (ends - tiles)[flat_idx] * tm + slot
@@ -235,7 +270,7 @@ def ragged_dispatch(
                      back * flat_gate[:, None].astype(back.dtype), 0)
     y = jnp.zeros((T, D), xt.dtype).at[tok].add(back.astype(xt.dtype))
     kept = jnp.sum(keep.astype(jnp.int32))
-    return y, kept, jnp.int32(T * k) - kept, load
+    return y, kept, routed - kept, load
 
 
 def moe_apply_grouped(

@@ -77,12 +77,16 @@ def cache_from_prefill(
 
 
 @register_jit("kvcache.insert_latent", donated=("ckv",))
-@functools.partial(jax.jit, donate_argnames=("ckv",))
-def _insert_latent_module(ckv, rows, new):
-    """Write the decode-layout latent rows ``new`` (n, span/2, 2C) into batch
-    rows ``rows`` of the layer's cache, which is DONATED: updated in place,
-    never copied whole."""
-    return ckv.at[rows].set(new.astype(ckv.dtype))
+@functools.partial(jax.jit, static_argnames=("cfg",),
+                   donate_argnames=("ckv",))
+def _insert_latent_module(cfg, ckv, rows, new):
+    """Pack a micro-batch's per-position latent rows ``new`` (n, S, C)
+    into the decode layout (``attention.mla_pack``) and write them into
+    batch rows ``rows`` of the layer's cache, which is DONATED: updated in
+    place, never copied whole.  One launch: packing eagerly would upload
+    its slice bounds and pad value on every call."""
+    packed = attn_mod.mla_pack(cfg, new, 2 * ckv.shape[1])
+    return ckv.at[rows].set(packed.astype(ckv.dtype))
 
 
 def insert_prefill_rows(
@@ -99,10 +103,8 @@ def insert_prefill_rows(
     """
     rows = jnp.asarray(rows)
     if kind == "mla":
-        span = 2 * layer_cache["ckv"].shape[1]
         layer_cache["ckv"] = _insert_latent_module(
-            layer_cache["ckv"], rows,
-            attn_mod.mla_pack(cfg, entry["ckv"], span))
+            cfg, layer_cache["ckv"], rows, entry["ckv"])
     elif kind == "attn":
         span = layer_cache["k"].shape[1]
         nk, nv = aligned_kv(cfg, entry["k"], entry["v"], span)

@@ -238,7 +238,7 @@ class ServeReport:
     prefill_tokens: int = 0       # token-positions actually computed in prefill
     #   (full prompts on a miss, suffix only on a prefix hit — the gap vs
     #   sum(len(prompt)) is the prefill work the prefix cache skipped)
-    prefill_capacity_rows: int = 0  # grouped-prefill expert buffer rows
+    prefill_expert_rows: int = 0    # rows the prefill expert kernel computed
     prefill_routed_copies: int = 0  # routed copies of real prompt tokens
     #   in them (1 - copies/rows is prefill's expert padding)
     decode_expert_rows: int = 0   # rows the ragged decode expert kernel
@@ -519,7 +519,7 @@ class Server:
         # engine-stat totals already drained into the report
         self._seen = {"drop": 0, "htod": 0, "wait": 0.0, "kvh": 0, "kvd": 0,
                       "ph": 0, "pm": 0, "lh": 0, "a2a": 0, "cd": 0,
-                      "retr": 0, "tmo": 0, "pcr": 0, "prc": 0, "der": 0}
+                      "retr": 0, "tmo": 0, "per": 0, "prc": 0, "der": 0}
         # online capacity re-plan (replan_skew): the hottest expert's share
         # at the last (re-)plan; None until the first measurement
         self._replan_share: Optional[float] = None
@@ -703,8 +703,8 @@ class Server:
         self.report.transfer_retries += st.transfer_retries - self._seen["retr"]
         self.report.transfer_timeouts += (st.transfer_timeouts
                                           - self._seen["tmo"])
-        self.report.prefill_capacity_rows += (st.prefill_capacity_rows
-                                              - self._seen["pcr"])
+        self.report.prefill_expert_rows += (st.prefill_expert_rows
+                                            - self._seen["per"])
         self.report.prefill_routed_copies += (st.prefill_routed_copies
                                               - self._seen["prc"])
         self.report.decode_expert_rows += (st.decode_expert_rows
@@ -729,7 +729,7 @@ class Server:
                       "cd": st.collective_dispatches,
                       "retr": st.transfer_retries,
                       "tmo": st.transfer_timeouts,
-                      "pcr": st.prefill_capacity_rows,
+                      "per": st.prefill_expert_rows,
                       "prc": st.prefill_routed_copies,
                       "der": st.decode_expert_rows}
         return d_drop

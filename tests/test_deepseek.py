@@ -284,3 +284,22 @@ def test_layer_spans_carry_the_layer_kind(monkeypatch):
         ("prefill", 0, "mla", "dense"), ("prefill", 1, "mla", "moe"),
         ("prefill", 2, "mla", "moe"), ("decode", 0, "mla", "dense"),
         ("decode", 1, "mla", "moe"), ("decode", 2, "mla", "moe")]
+
+
+def test_latent_prefill_uploads_nothing_per_micro_batch():
+    """With its rows and prompt lengths on the device, a ragged MLA
+    prefill of several micro-batches uploads nothing from the host: the
+    per-micro-batch inputs are cut at static bounds and the latent cache
+    is packed inside its insert launch, so no eager slice or pad uploads
+    an index between launches (each such upload is a point where the host
+    can hold the chip idle)."""
+    cfg = tiny()
+    params = M.init_params(cfg, KEY)
+    toks = jax.random.randint(KEY, (8, 12), 0, cfg.vocab_size)
+    lengths = jnp.asarray([12, 5, 9, 3, 7, 12, 2, 8], jnp.int32)
+    eng = ModuleBatchingEngine(cfg, params, Plan(B=8, b_a=2, b_e=8,
+                                                 omega=0.0), max_seq=32)
+    want = eng.prefill(toks, lengths=lengths)           # traces every module
+    with jax.transfer_guard_host_to_device("disallow"):
+        got = eng.prefill_slots(toks, jnp.arange(8), lengths=lengths)
+    assert jnp.array_equal(got, want)

@@ -5,7 +5,8 @@ resident decode with zero steady-state retraces and zero unplanned
 transfers (prediction moves WHEN bytes move, never WHICH math runs); the
 hot-expert LRU never exceeds its byte budget and demotes cold entries;
 ``capacity_for_load`` sizes ``b_e`` from the measured routing histogram;
-grouped prefill buckets its capacity at the next pow2 over measured load;
+grouped prefill dispatches ragged over real prompt tokens, with no capacity
+readback;
 drops and routed load are accounted per MoE layer.  (The hypothesis-based
 predictor-accuracy property lives in test_properties.py, the only module
 allowed to import hypothesis.)
@@ -82,7 +83,8 @@ def test_predictive_steady_state_no_retrace_no_unplanned():
     rep = san.report()
     assert rep["steady_retraces"] == {}
     assert rep["planned_transfers"].get("expert-prefetch", 0) > 0
-    assert rep["planned_transfers"].get("prefill-capacity-probe", 0) > 0
+    # single-device prefill sizes no capacity from a readback
+    assert rep["planned_transfers"].get("prefill-capacity-probe", 0) == 0
 
 
 def test_predictor_seam_prefetch_only():
@@ -227,23 +229,41 @@ def test_per_layer_drop_and_load_accounting():
         st.expert_tokens + st.expert_tokens_dropped
 
 
-def test_grouped_prefill_pow2_capacity_zero_drop():
-    """The split grouped-prefill MoE stage sizes its dispatch buffer at the
-    pow2 bucket over MEASURED load — strictly below the token-count upper
-    bound for multi-expert configs — while keeping prefill zero-drop and
-    the logits identical to the dense-reference prefill path."""
-    cfg, params, toks = _setup()
+def _check_ragged_prefill(cfg, params, toks):
+    lengths = np.array([S, 5, 9, 3], np.int32)
     eng = _engine(cfg, params)
     with analysis.sanitize(strict=True) as san:
-        lg = eng.prefill(toks)
-    probes = san.report()["planned_transfers"].get("prefill-capacity-probe")
-    n_moe = sum(1 for _, f in eng.schema if f == "moe")
-    assert probes == n_moe * 2                  # one per layer x micro-batch
+        lg = eng.prefill(toks, lengths=lengths)
+    probes = san.report()["planned_transfers"].get("prefill-capacity-probe", 0)
+    assert probes == 0
     eng.sync_stats()
     assert eng.stats.expert_tokens_dropped == 0
+    assert eng.stats.prefill_expert_rows > 0
     ref_eng = _engine(cfg, params, grouped_prefill=False)
-    ref = ref_eng.prefill(toks)
+    ref = ref_eng.prefill(toks, lengths=lengths)
     np.testing.assert_allclose(
         np.asarray(lg, np.float32), np.asarray(ref, np.float32),
         atol=0.05 * cfg.d_model ** 0.5,
     )
+
+
+def test_grouped_prefill_pow2_capacity_zero_drop():
+    """Single-device grouped prefill dispatches ragged over the real
+    prompt tokens' routed copies at a capacity of the micro-batch's token
+    count: no capacity is measured, so prefill reads nothing back between
+    its launches (zero ``prefill-capacity-probe`` transfers), nothing
+    drops, and on right-padded ragged lengths the logits match the
+    dense-reference prefill path."""
+    _check_ragged_prefill(*_setup())
+
+
+def test_grouped_prefill_ragged_at_64_experts():
+    """The same at DeepSeek's shape: 64 experts, top-6, shared experts and
+    a leading dense layer."""
+    from test_grouped_dispatch import _tiny_deepseek_64
+
+    cfg = _tiny_deepseek_64()
+    params = M.init_params(cfg, KEY)
+    toks = jax.random.randint(jax.random.PRNGKey(2), (B, S), 0,
+                              cfg.vocab_size)
+    _check_ragged_prefill(cfg, params, toks)

@@ -10,10 +10,11 @@ Covers the PR's contract:
 * a decode step issues exactly one grouped launch per MoE layer;
 * prefill can share the same grouped implementation via
   ``ShardCtx(moe_dispatch='grouped')``;
-* decode's ragged dispatch (``moe.ragged_dispatch``, its kernel in
-  interpret mode) equals the capacity dispatch, keeps the fused chunk
-  token-identical to per-module decode at 64 experts, and counts the rows
-  its kernel computes.
+* the ragged dispatch of decode and prefill (``moe.ragged_dispatch``, its
+  kernel in interpret mode) equals the capacity dispatch, with and without
+  a mask of real tokens, keeps the fused chunk token-identical to
+  per-module decode at 64 experts, and counts the rows its kernel
+  computes.
 """
 import functools
 
@@ -204,24 +205,63 @@ def test_grouped_prefill_shares_decode_path():
     assert d < 0.05, d
 
 
-@pytest.mark.parametrize("T,cap", [(40, 40), (40, 3), (200, 200), (200, 30)])
-def test_ragged_dispatch_equals_grouped(T, cap):
+def _valid_mask(kind, T, idx):
+    """(T,) real-token mask of a right-padded batch of 20-token rows:
+    every token, the second half of each row padded, or the second half
+    padded plus a whole row of pads and every token routed to expert 0
+    (so that expert's load of real copies is zero)."""
+    if kind is None:
+        return None
+    pos = jnp.arange(T) % 20
+    if kind == "all":
+        return jnp.ones((T,), bool)
+    valid = pos < 10
+    if kind == "halves":
+        return valid
+    return valid & (jnp.arange(T) >= 20) & ~jnp.any(idx == 0, axis=-1)
+
+
+_RAGGED_CASES = [
+    (40, 40, None), (40, 3, None), (200, 200, None), (200, 30, None),
+    (40, 40, "all"), (200, 200, "halves"), (200, 30, "halves"),
+    (200, 200, "block"), (512, 512, "halves"),
+]
+
+
+@pytest.mark.parametrize(
+    "T,cap,mask", _RAGGED_CASES,
+    ids=["-".join(str(v) for v in c if v is not None) for c in _RAGGED_CASES])
+def test_ragged_dispatch_equals_grouped(T, cap, mask):
     """Same y, kept, dropped and load as the capacity dispatch, dropless
-    (capacity = T) and where copies drop; both kernels in interpret mode."""
+    (capacity = T) and where copies drop, with and without a mask of real
+    tokens (pads take no row and read zero); both kernels in interpret
+    mode.  At T 512 the dispatch takes 256-row tiles, as prefill does."""
     cfg = get_config("olmoe-1b-7b", smoke=True)
     p = moe_mod.init_moe_params(cfg, KEY)
     xt = (jax.random.normal(KEY, (T, cfg.d_model)) * 0.3).astype(jnp.bfloat16)
     gates, idx, _ = moe_mod.route(cfg, p["router"], xt)
+    valid = _valid_mask(mask, T, idx)
     args = (cfg, xt, gates, idx, p["experts_w_gate"], p["experts_w_up"],
             p["experts_w_down"], cap)
-    got = moe_mod.ragged_dispatch(*args, use_kernel=True)
-    want = moe_mod.grouped_dispatch(*args, use_kernel=True)
+    got = moe_mod.ragged_dispatch(*args, use_kernel=True, valid=valid)
+    want = moe_mod.grouped_dispatch(*args, use_kernel=True, valid=valid)
     for g, w in zip(got, want):
         assert jnp.array_equal(g, w)
-    assert (int(got[2]) > 0) == (cap < T)
+    y, kept, dropped, load = got
+    real = (jnp.ones((T,), bool) if valid is None else valid)
+    n_real = int(real.sum())
+    assert int(load.sum()) == int(kept) + int(dropped) == \
+        cfg.experts_per_token * n_real
+    assert (int(dropped) > 0) == (cap < n_real)
+    assert not jnp.any(y[~real])
+    if mask == "block":
+        assert int(load[0]) == 0
+    if T == 512:
+        assert moe_mod.ragged_tile(T, cfg.experts_per_token,
+                                   cfg.num_experts) == 256
     # off the TPU the ragged dispatch is the capacity einsum itself
-    auto = moe_mod.ragged_dispatch(*args)
-    fallback = moe_mod.grouped_dispatch(*args, use_kernel=False)
+    auto = moe_mod.ragged_dispatch(*args, valid=valid)
+    fallback = moe_mod.grouped_dispatch(*args, use_kernel=False, valid=valid)
     for g, w in zip(auto, fallback):
         assert jnp.array_equal(g, w)
 
@@ -297,5 +337,6 @@ def test_serve_report_counts_decode_expert_rows(streamed):
     assert (rep.weight_htod_bytes > 0) == streamed
     ticks = rep.decode_slot_steps // 4
     assert ticks > 0
-    assert moe_mod.ragged_tile(4) == 16
+    assert moe_mod.ragged_tile(4, cfg.experts_per_token,
+                               cfg.num_experts) == 16
     assert rep.decode_expert_rows == 2 * ticks * cfg.experts_per_token * 16

@@ -60,8 +60,9 @@ def test_expert_ffn_padding_path():
 
 # T rows routed over E experts: each case gives the copies of each expert
 # (at most T, since a row routes to an expert once).  The row tile is
-# ``moe.ragged_tile(T)`` and the buffer ``moe.ragged_buffer_rows`` rows, as
-# the decode dispatch lays them out.
+# ``moe.ragged_tile(T, k, E)`` and the buffer ``moe.ragged_buffer_rows``
+# rows, as the dispatch lays them out: 256-row tiles where the balanced
+# load fills them, as in prefill.
 RAGGED_CASES = {
     "experts-with-no-copies": (24, [24, 0, 5, 0]),
     "one-expert-over-a-tile": (200, [150, 30, 0]),
@@ -69,6 +70,7 @@ RAGGED_CASES = {
     "trailing-unused-tiles": (40, [3, 2, 1, 0]),
     "T-below-128": (100, [60, 100, 40]),
     "T-above-128": (300, [130, 300, 170, 0]),
+    "prefill-256-row-tiles": (512, [300, 512, 100, 112]),
 }
 
 
@@ -82,7 +84,8 @@ def test_ragged_expert_ffn(case):
     T, copies = RAGGED_CASES[case]
     E, D, F = len(copies), 256, 256
     k = -(-sum(copies) // T)
-    tm = moe_mod.ragged_tile(T)
+    tm = moe_mod.ragged_tile(T, k, E)
+    assert (tm == 256) == (case == "prefill-256-row-tiles")
     R = moe_mod.ragged_buffer_rows(T, k, E, T)
     tiles = [-(-c // tm) for c in copies]
     n_used = sum(tiles)

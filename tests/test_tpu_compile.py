@@ -100,7 +100,7 @@ def test_ragged_expert_ffn_compiles_at_decode_widths(one_chip, tpu_compile,
     out for a dropless batch of ``T`` rows (capacity = T)."""
     from repro.models import moe as moe_mod
 
-    tm = moe_mod.ragged_tile(T)
+    tm = moe_mod.ragged_tile(T, k, E)
     R = moe_mod.ragged_buffer_rows(T, k, E, T)
     x = _spec(one_chip, (R, D))
     tiles = _spec(one_chip, (R // tm,), jnp.int32)
@@ -111,6 +111,38 @@ def test_ragged_expert_ffn_compiles_at_decode_widths(one_chip, tpu_compile,
         lambda *a: kernel_ops.ragged_expert_ffn(*a, block_m=tm,
                                                 interpret=False)
     ).lower(x, tiles, used, wg, wg, wd).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%expert_ffn" in text
+    assert _fits(compiled)
+
+
+@pytest.mark.parametrize("arch,b_a,S", [
+    ("deepseek-v2-lite", 4, 1024),  # T 4096, E 64, k 6, D 2048, F 1408
+    ("mixtral-8x7b", 8, 1024),      # T 8192, E 8, k 2, D 4096, F 14336
+], ids=["deepseek-4096", "mixtral-8192"])
+def test_prefill_moe_ffn_module_compiles_at_window_shapes(one_chip,
+                                                          tpu_compile,
+                                                          arch, b_a, S):
+    """The prefill expert stage of the resident cells' window: a
+    micro-batch of ``b_a`` prompts padded to ``S``, dispatched ragged over
+    its real tokens at a capacity of its token count, in 256-row tiles."""
+    from repro.models import moe as moe_mod
+
+    cfg = get_config(arch)
+    T, k = b_a * S, cfg.experts_per_token
+    assert moe_mod.ragged_tile(T, k, cfg.num_experts) == 256
+    moe = jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: moe_mod.init_moe_params(
+            cfg, jax.random.PRNGKey(0))))
+    fn = registry.get("engine.prefill_moe_ffn").fn
+    compiled = fn.lower(
+        cfg, T, moe, _spec(one_chip, (b_a, S, cfg.d_model)),
+        _spec(one_chip, (T, cfg.d_model)),
+        _spec(one_chip, (T, k), jnp.float32),
+        _spec(one_chip, (T, k), jnp.int32),
+        _spec(one_chip, (b_a,), jnp.int32),
+    ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "%expert_ffn" in text
     assert _fits(compiled)
